@@ -70,8 +70,8 @@ test-fast: test
 lint:
 	-$(PYTHON) -m ruff check eth_consensus_specs_tpu/ tests/
 	$(PYTHON) scripts/speclint.py
-	$(PYTHON) scripts/jaxlint.py
-	$(PYTHON) scripts/rangelint.py
+	JAX_PLATFORMS=cpu $(PYTHON) scripts/jaxlint.py
+	JAX_PLATFORMS=cpu $(PYTHON) scripts/rangelint.py
 	$(PYTHON) scripts/gen_env_docs.py --check
 
 speclint:
@@ -81,13 +81,13 @@ speclint:
 # --chips 8 is the CLI default, so the three mesh-sharded variants are
 # analyzed on 8 virtual CPU devices even on a 1-device dev box
 jaxlint:
-	$(PYTHON) scripts/jaxlint.py
+	JAX_PLATFORMS=cpu $(PYTHON) scripts/jaxlint.py
 
 # value-range analysis: interval abstract interpretation over every
 # registered kernel's jaxpr, seeded from the registry's declared input
 # domains — proves no intermediate can wrap a u64/u32 lane
 rangelint:
-	$(PYTHON) scripts/rangelint.py
+	JAX_PLATFORMS=cpu $(PYTHON) scripts/rangelint.py
 
 reftests:
 	$(PYTHON) -m eth_consensus_specs_tpu.gen -o test_vectors -v

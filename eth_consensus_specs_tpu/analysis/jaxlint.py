@@ -17,7 +17,7 @@ jaxprs:
     alias annotations (``devices=[None]``, ALIAS semantics — what
     ``jnp.asarray`` leaves behind) are exempt: they move nothing.
 ``donation-audit``
-    Declared donate argnums are ACTUALLY donated (the pjit eqn's
+    Declared donate argnums are ACTUALLY donated (the jit eqn's
     ``donated_invars``) and usable (an output aval matches — XLA drops
     unusable donations silently); an undeclared input whose aval equals
     an output aval above ``ETH_SPECS_ANALYSIS_DONATE_MIN_BYTES`` is a
@@ -88,7 +88,7 @@ _CALLBACK_PRIMS = {
 
 _COLLECTIVE_PRIMS = {
     "psum",
-    "psum2",  # shard_map's check_rep rewrite renames psum
+    "psum_invariant",  # shard_map's check_vma rewrite renames psum
     "pmin",
     "pmax",
     "pmean",
@@ -250,12 +250,12 @@ def rule_donation_audit(spec, variant, closed) -> list[Finding]:
     in_avals = [getattr(v, "aval", None) for v in inner.invars]
     out_avals = [getattr(v, "aval", None) for v in inner.outvars]
 
-    # what the traced callable ACTUALLY donates: the top-level pjit eqn
+    # what the traced callable ACTUALLY donates: the top-level jit eqn
     donated = [False] * len(in_avals)
     for eqn in inner.eqns:
-        if eqn.primitive.name == "pjit" and "donated_invars" in eqn.params:
+        if eqn.primitive.name == "jit" and "donated_invars" in eqn.params:
             flags = eqn.params["donated_invars"]
-            # map pjit operands back to top-level invars
+            # map jit operands back to top-level invars
             positions = {id(v): i for i, v in enumerate(inner.invars)}
             for opv, flag in zip(eqn.invars, flags):
                 i = positions.get(id(opv))
@@ -362,7 +362,7 @@ def rule_collective_audit(spec, variant, closed) -> list[Finding]:
             continue
         if name not in _COLLECTIVE_PRIMS:
             continue
-        name = "psum" if name == "psum2" else name  # canonical fingerprint
+        name = "psum" if name == "psum_invariant" else name  # canonical fingerprint
         axes = _axis_names(eqn)
         if variant.mesh is None:
             findings.append(

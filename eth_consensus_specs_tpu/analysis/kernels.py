@@ -792,7 +792,7 @@ def _state_root_args(meta):
         slashed_chunk=_sds((n, 8), "uint32"),
         prev_part_flags=_sds((n,), "uint8"),
         top_chunks=_sds((1 << meta.top_depth, 8), "uint32"),
-        zerohashes=_sds((41, 8), "uint32"),
+        zerohashes=_sds((42, 8), "uint32"),  # zerohash_words(41): depths 0..41
     )
     just = JustificationState(
         current_epoch=_sds((), "uint64"),

@@ -421,7 +421,7 @@ class RangeInterp:
         try:
             from jax._src import source_info_util
 
-            for fr in source_info_util.user_frames(si):
+            for fr in source_info_util.user_frames(tb):
                 base = os.path.basename(fr.file_name)
                 frames.append(f"{base}::{fr.function_name}")
         except Exception:
@@ -1091,6 +1091,17 @@ def _h_reduce_sum(self: RangeInterp, eqn, ins, cins):
     return [self._finish_arith(eqn, iv, prim="add")], None
 
 
+def _h_cumsum(self: RangeInterp, eqn, ins, cins):
+    """Inclusive running sum along one axis: element k is a sum of k+1
+    inputs, so the whole output lies between the axis length times the
+    input's lowest (if negative) or its lowest itself, and likewise up."""
+    a = ins[0]
+    n = _shape_of(eqn.invars[0])[eqn.params["axis"]]
+    lo, hi = int(_amin(a.lo)), int(_amax(a.hi))
+    iv = Ival(min(lo, lo * n), max(hi, hi * n), a.tainted)
+    return [self._finish_arith(eqn, iv, prim="add")], None
+
+
 def _h_argminmax(self: RangeInterp, eqn, ins, cins):
     axes = tuple(eqn.params.get("axes", ()))
     in_shape = _shape_of(eqn.invars[0])
@@ -1098,7 +1109,7 @@ def _h_argminmax(self: RangeInterp, eqn, ins, cins):
     return [Ival(0, max(n - 1, 0))], None
 
 
-def _h_pjit(self: RangeInterp, eqn, ins, cins):
+def _h_jit(self: RangeInterp, eqn, ins, cins):
     sub = eqn.params["jaxpr"]
     outs = self.run(sub, [iv for iv in ins])
     return outs, None
@@ -1465,9 +1476,10 @@ _HANDLERS = {
     "reduce_max": _h_reduce_minmax_like,
     "reduce_min": _h_reduce_minmax_like,
     "reduce_sum": _h_reduce_sum,
+    "cumsum": _h_cumsum,
     "argmax": _h_argminmax,
     "argmin": _h_argminmax,
-    "pjit": _h_pjit,
+    "jit": _h_jit,
     "closed_call": _h_closed_call,
     "core_call": _h_closed_call,
     "custom_jvp_call": _h_custom_call,
@@ -1476,7 +1488,7 @@ _HANDLERS = {
     "checkpoint": _h_custom_call,
     "shard_map": _h_shard_map,
     "psum": _h_psum,
-    "psum2": _h_psum,
+    "psum_invariant": _h_psum,
     "all_gather": _h_all_gather,
     "axis_index": _h_axis_index,
     "scan": _h_scan,

@@ -1,13 +1,16 @@
 """Graceful degradation: device path -> host oracle.
 
 `degrade(site, device_fn, host_fn)` runs the device path; when it dies
-of a DEVICE-side failure (XLA compile/runtime error, OOM, or an injected
-fault) it retries once through `retrying` — transient allocator pressure
-and nth-shot injections recover here — then falls back to the host
-oracle so the run completes slower rather than not at all. Logic errors
-(anything that doesn't classify as a device failure) propagate: masking
-a real bug behind the oracle would un-couple the two legs the bench
-correctness story depends on.
+of a DEVICE-side failure (the runtime losing the device, OOM, or an
+injected fault) it retries once through `retrying` — transient allocator
+pressure and nth-shot injections recover here — then falls back to the
+host oracle so the run completes slower rather than not at all. Logic
+errors (anything that doesn't classify as a device failure) propagate:
+masking a real bug behind the oracle would un-couple the two legs the
+bench correctness story depends on. A COMPILE refusal is a logic error
+in this sense: the compiler rejecting a kernel is not a device dying
+under load, and answering from the host would hide that the device path
+does not exist on this machine.
 """
 
 from __future__ import annotations
@@ -20,18 +23,19 @@ from .retry import retrying
 from .spec import FaultInjected
 
 # substrings of RuntimeError messages that identify device-side death.
-# Deliberately NARROW (allocator/compiler failure vocabulary only): a
-# marker like "device" would also match shape/transfer logic errors
-# ("incompatible shapes when transferring to device") and silently mask
-# real kernel bugs behind the host oracle.
+# Deliberately NARROW (allocator failure vocabulary only): a marker like
+# "device" would also match shape/transfer logic errors ("incompatible
+# shapes when transferring to device") and silently mask real kernel
+# bugs behind the host oracle.
 _DEVICE_ERROR_MARKERS = (
     "resource_exhausted",
     "resource exhausted",
     "out of memory",
-    "failed to compile",
-    "compilation failure",
     "failed to allocate",
 )
+# status codes with which the XLA runtime reports that the device or its
+# connection is gone (as opposed to the program being wrong)
+_RUNTIME_LOST_STATUS = ("unavailable:", "aborted:", "data_loss:", "deadline_exceeded:")
 # "oom" needs a word boundary: plain containment would also match
 # "room"/"bloom" in unrelated error messages
 _OOM_RE = re.compile(r"\boom\b")
@@ -48,17 +52,19 @@ def is_device_failure(exc: BaseException) -> bool:
         # checkpoint refusals): degrading to the host path re-derives
         # the state instead of serving a wrong answer
         return True
+    if not isinstance(exc, RuntimeError):  # XlaRuntimeError is one
+        return False
     msg = str(exc).lower()
-    if "xla" in type(exc).__name__.lower():
-        # jaxlib.xla_extension.XlaRuntimeError et al. — but XLA also routes
-        # argument/shape LOGIC errors through the same type; those must
-        # still propagate
-        return "invalid_argument" not in msg and "invalid argument" not in msg
-    if isinstance(exc, RuntimeError):
-        return bool(_OOM_RE.search(msg)) or any(
-            marker in msg for marker in _DEVICE_ERROR_MARKERS
-        )
-    return False
+    if "compil" in msg:
+        # the compiler refused the kernel (no memory for it, a layout, an
+        # unsupported op): the same vocabulary as an allocator failure,
+        # but nothing died and a retry cannot help
+        return False
+    if "xla" in type(exc).__name__.lower() and msg.startswith(_RUNTIME_LOST_STATUS):
+        return True
+    return bool(_OOM_RE.search(msg)) or any(
+        marker in msg for marker in _DEVICE_ERROR_MARKERS
+    )
 
 
 def degrade(site: str, device_fn, host_fn, *, attempts: int = 2):

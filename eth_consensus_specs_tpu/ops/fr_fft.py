@@ -23,7 +23,7 @@ import numpy as np
 import eth_consensus_specs_tpu  # noqa: F401  (enables x64)
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .limb_field import LimbField
@@ -139,7 +139,7 @@ def _sharded_fft(mesh: Mesh, n: int, n_stages: int):
             mesh=mesh,
             in_specs=(P(BATCH_AXES),) + (P(),) * n_stages,
             out_specs=P(BATCH_AXES),
-            check_rep=False,
+            check_vma=False,
         ),
         donate_argnums=(0,),
     )

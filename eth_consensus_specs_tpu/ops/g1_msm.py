@@ -31,7 +31,7 @@ import numpy as np
 import eth_consensus_specs_tpu  # noqa: F401  (enables x64)
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from eth_consensus_specs_tpu import obs
@@ -242,7 +242,7 @@ def _sharded_fn(mesh: Mesh, kind: str):
             return _cross_shard_tree_sum(*_tree_sum(mX, mY, mZ), BATCH_AXES)
 
         fn = jax.jit(
-            shard_map(local, mesh=mesh, in_specs=spec, out_specs=P(), check_rep=False)
+            shard_map(local, mesh=mesh, in_specs=spec, out_specs=P(), check_vma=False)
         )
     elif kind == "sum":
 
@@ -250,7 +250,7 @@ def _sharded_fn(mesh: Mesh, kind: str):
             return _cross_shard_tree_sum(*_tree_sum(X, Y, Z), BATCH_AXES)
 
         fn = jax.jit(
-            shard_map(local, mesh=mesh, in_specs=spec, out_specs=P(), check_rep=False)
+            shard_map(local, mesh=mesh, in_specs=spec, out_specs=P(), check_vma=False)
         )
     elif kind == "msm_many":
         # per-item MSMs with the LANE axis (axis 1) sharded: each shard
@@ -267,7 +267,7 @@ def _sharded_fn(mesh: Mesh, kind: str):
         fn = jax.jit(
             shard_map(
                 local, mesh=mesh, in_specs=lane_spec, out_specs=P(),
-                check_rep=False,
+                check_vma=False,
             )
         )
     else:  # "sum_many": item axis sharded, no collectives
@@ -276,7 +276,7 @@ def _sharded_fn(mesh: Mesh, kind: str):
             return jax.vmap(_tree_sum)(X, Y, Z)
 
         fn = jax.jit(
-            shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec, check_rep=False)
+            shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False)
         )
     _SHARDED_FNS[key] = fn
     return fn

@@ -43,7 +43,7 @@ import eth_consensus_specs_tpu  # noqa: F401  (enables x64)
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from eth_consensus_specs_tpu import obs
@@ -150,7 +150,7 @@ def _sharded_fn(mesh: Mesh):
         return _cross_shard_fold(*_lane_fold(X, Y, Z), BATCH_AXES)
 
     fn = jax.jit(
-        shard_map(local, mesh=mesh, in_specs=spec, out_specs=P(), check_rep=False)
+        shard_map(local, mesh=mesh, in_specs=spec, out_specs=P(), check_vma=False)
     )
     _SHARDED_FNS[mesh] = fn
     return fn

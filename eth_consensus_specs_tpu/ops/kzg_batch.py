@@ -153,11 +153,15 @@ def challenge_evaluations(parsed: list, mesh=None) -> list[int]:
 # ------------------------------------------------------------- RLC fold --
 
 
-def _rlc_check(parsed: list, ys: list[int], mesh=None) -> bool:
+def _rlc_check(parsed: list, ys: list[int], mesh=None, flush_n: int | None = None) -> bool:
     """One batch verdict for a subset: the spec's Fiat-Shamir RLC
     (``crypto/kzg.verify_kzg_proof_batch`` :412) with its three G1
     lincombs folded by linearity into the two items of ONE batched
-    multi-MSM dispatch, then one pairing check."""
+    multi-MSM dispatch, then one pairing check. ``flush_n`` is the size
+    of the flush this subset was bisected out of: the subset pads into
+    the flush's own lane bucket (infinity lanes), so isolating an
+    invalid item never compiles a narrower shape of a kernel that takes
+    minutes to compile."""
     from eth_consensus_specs_tpu.ops.bls_batch import _pairing_check_routed
     from eth_consensus_specs_tpu.ops.g1_msm import msm_g1_many_device
     from eth_consensus_specs_tpu.parallel import mesh_ops
@@ -190,9 +194,12 @@ def _rlc_check(parsed: list, ys: list[int], mesh=None) -> bool:
     )
 
     shards = mesh_ops.shard_count(mesh)
-    wide = shards > 1 and buckets.route_wide("kzg", buckets.kzg_lane_bucket(n, 1), n)
+    flush_n = max(flush_n or n, n)
+    wide = shards > 1 and buckets.route_wide(
+        "kzg", buckets.kzg_lane_bucket(flush_n, 1), flush_n
+    )
     use_mesh = mesh if wide else None
-    key = buckets.kzg_msm_key(n, mesh=use_mesh)
+    key = buckets.kzg_msm_key(flush_n, mesh=use_mesh)
     obs.count("kzg.batches", 1)
     with buckets.first_dispatch(*key):
         a_pt, b_pt = msm_g1_many_device(
@@ -227,15 +234,16 @@ def verify_blob_kzg_proof_batch_device(
 # ------------------------------------------------------------ bisection --
 
 
-def _bisect(parsed: list, ys: list[int], mesh=None) -> list[bool]:
-    if _rlc_check(parsed, ys, mesh=mesh):
+def _bisect(parsed: list, ys: list[int], mesh=None, flush_n: int | None = None) -> list[bool]:
+    flush_n = flush_n or len(parsed)
+    if _rlc_check(parsed, ys, mesh=mesh, flush_n=flush_n):
         return [True] * len(parsed)
     if len(parsed) == 1:
         obs.count("kzg.isolated_invalid", 1)
         return [False]
     mid = len(parsed) // 2
-    return _bisect(parsed[:mid], ys[:mid], mesh=mesh) + _bisect(
-        parsed[mid:], ys[mid:], mesh=mesh
+    return _bisect(parsed[:mid], ys[:mid], mesh=mesh, flush_n=flush_n) + _bisect(
+        parsed[mid:], ys[mid:], mesh=mesh, flush_n=flush_n
     )
 
 

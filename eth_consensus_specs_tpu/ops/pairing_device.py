@@ -325,7 +325,7 @@ def _miller_sharded_fn(mesh, chunks_per_shard: int):
     fn = _MILLER_SHARDED.get(key)
     if fn is not None:
         return fn
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from eth_consensus_specs_tpu.parallel.mesh_ops import BATCH_AXES
@@ -359,7 +359,7 @@ def _miller_sharded_fn(mesh, chunks_per_shard: int):
             mesh=mesh,
             in_specs=(spec, spec, spec, spec),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
     )
     _MILLER_SHARDED[key] = fn

@@ -123,6 +123,17 @@ def sha256_pair_words_scan(words: jnp.ndarray) -> jnp.ndarray:
     return state.T
 
 
+def _own_fusion(digest: jnp.ndarray) -> jnp.ndarray:
+    """Keep one unrolled hash ONE fusion. Left alone XLA fuses a hash
+    into the hash that consumes it (a validator leaf is a chain of
+    three, a fold a chain of dozens) and the compile time of the fused
+    kernel grows far faster than its length: compiled for a v5e, one
+    hash of u32[4096, 16] takes 5 s, a chain of two 131 s, the
+    three-hash validator leaf 171 s. The barrier costs the digest one
+    trip through HBM, which the kernel's traffic model already counts."""
+    return jax.lax.optimization_barrier(digest)
+
+
 def sha256_pair_words_unrolled(words: jnp.ndarray) -> jnp.ndarray:
     """Unrolled batch hash: uint32[N, 16] -> uint32[N, 8]."""
     n = words.shape[0]
@@ -131,7 +142,7 @@ def sha256_pair_words_unrolled(words: jnp.ndarray) -> jnp.ndarray:
     state = _compress(state, w)
     pad = [jnp.broadcast_to(jnp.uint32(_PAD_BLOCK[i]), (n,)) for i in range(16)]
     state = _compress(state, pad)
-    return jnp.stack(state, axis=-1)
+    return _own_fusion(jnp.stack(state, axis=-1))
 
 
 def sha256_single_block(words: jnp.ndarray) -> jnp.ndarray:
@@ -142,12 +153,12 @@ def sha256_single_block(words: jnp.ndarray) -> jnp.ndarray:
     the shape of the shuffle's decision-bit hashes (33/37-byte messages,
     specs/phase0/beacon-chain.md:816-836)."""
     n = words.shape[0]
-    if jax.default_backend() == "cpu":
+    if _round_scan(n):
         state = jnp.broadcast_to(jnp.asarray(_IV)[:, None], (8, n))
         return _compress_scan(state, words.T).T
     w = [words[:, i] for i in range(16)]
     state = [jnp.broadcast_to(jnp.uint32(_IV[i]), (n,)) for i in range(8)]
-    return jnp.stack(_compress(state, w), axis=-1)
+    return _own_fusion(jnp.stack(_compress(state, w), axis=-1))
 
 
 def sha256_pair_words(words: jnp.ndarray) -> jnp.ndarray:
@@ -159,9 +170,23 @@ def sha256_pair_words(words: jnp.ndarray) -> jnp.ndarray:
     accelerators (XLA fuses the whole chain; scan carries round-trip HBM),
     round-scan on CPU (the unrolled graph takes minutes in XLA:CPU).
     """
-    if jax.default_backend() == "cpu":
+    if _round_scan(words.shape[0]):
         return sha256_pair_words_scan(words)
     return sha256_pair_words_unrolled(words)
+
+
+# Up to this many messages a call, an accelerator takes the round scan
+# too. Unrolling buys bandwidth: the scan's carry makes a round trip a
+# round, which for a batch this small is a few KB. What it costs is the
+# same whatever the batch: ~5 s of compile for each call site, compiled
+# for a v5e, against 0.1 to 0.4 s for the scan body (PERF.md, PR 22) —
+# and a state root is mostly SMALL hashes by count: the zero-hash fold
+# chains, the length mixes, three checkpoints, the top combine.
+SMALL_BATCH = 64
+
+
+def _round_scan(n_messages: int) -> bool:
+    return jax.default_backend() == "cpu" or n_messages <= SMALL_BATCH
 
 
 _kernel = jax.jit(sha256_pair_words)

@@ -66,6 +66,19 @@ from eth_consensus_specs_tpu import obs
 # altair participation bits: TIMELY_SOURCE | TIMELY_TARGET | TIMELY_HEAD
 FLAG_MASK = 0b111
 
+# The (fork, preset) every slot world is built under — the serving owner,
+# the resident owner, the bench and the tests all read it from here. The
+# preset fixes the epoch quotients and the shapes of the state's static
+# subtrees, so a registry of deployment size under another preset is not
+# a state any user holds.
+SLOT_SPEC = ("altair", "mainnet")
+
+
+def slot_spec():
+    from eth_consensus_specs_tpu.forks import get_spec
+
+    return get_spec(*SLOT_SPEC)
+
 
 def sync_reward_gwei() -> int:
     """Per-participant balance credit of a valid sync aggregate (the
@@ -414,7 +427,10 @@ def device_aggregate(
 ) -> tuple:
     """The aggregation leg on device: every subnet's valid signatures
     in ONE batched G2 many-sum dispatch (the PR 13 kernel, the same
-    LIVE ``g2_agg`` compile key the serve tier buckets by)."""
+    LIVE ``g2_agg`` compile key the serve tier buckets by). The key
+    buckets the REQUEST's subnets and attestations a subnet, valid or
+    not (the :func:`request_capacity` rule): a refused attestation
+    leaves an infinity lane, never a narrower compile."""
     from eth_consensus_specs_tpu.crypto.curve import g2_to_bytes
     from eth_consensus_specs_tpu.crypto.signature import _load_sig
     from eth_consensus_specs_tpu.ops.g2_aggregate import sum_g2_many_device
@@ -433,11 +449,14 @@ def device_aggregate(
                 p = _load_sig(req.attestations[i].sig)
             row.append(p)
         lists.append(row)
-    max_lanes = max(len(row) for row in lists)
+    per_subnet: dict[int, int] = {}
+    for att in req.attestations:
+        per_subnet[int(att.subnet)] = per_subnet.get(int(att.subnet), 0) + 1
+    cap_items, cap_lanes = len(per_subnet), max(per_subnet.values())
     sharded = mesh is not None and buckets.route_wide(
-        "agg", buckets.pow2_bucket(max_lanes), len(lists)
+        "agg", buckets.pow2_bucket(cap_lanes), cap_items
     )
-    key = buckets.g2_agg_key(len(lists), max_lanes, mesh=mesh if sharded else None)
+    key = buckets.g2_agg_key(cap_items, cap_lanes, mesh=mesh if sharded else None)
     with buckets.first_dispatch(*key):
         sums = sum_g2_many_device(
             lists, mesh=mesh if sharded else None, pad_shape=(key[1], key[2])

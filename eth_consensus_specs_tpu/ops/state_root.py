@@ -30,6 +30,7 @@ ssz.hash_tree_root on the equivalently-updated object state.
 
 from __future__ import annotations
 
+from functools import lru_cache
 from typing import NamedTuple
 
 import numpy as np
@@ -151,11 +152,30 @@ def _validator_leaf_rows(
     E = H(A, B), root = H(E, F)). ONE implementation: the full path
     applies it to whole columns, the incremental path to the gathered
     dirty rows — editing the Validator leaf derivation in one place
-    cannot break full-vs-incremental root parity."""
+    cannot break full-vs-incremental root parity.
+
+    The chain runs as ONE compression body in a three-step scan (step i
+    hashes its two operands, each either a column or the step before's
+    digest), not three bodies: compiled for a v5e a body at these widths
+    is ~10 s, and the leaf sits in four programs of the served slot,
+    twice in two of them (PERF.md, PR 22)."""
     eb_chunk = _u64_chunk_words(effective_balance)
-    node_b = _hash_rows(eb_chunk, slashed_chunk)
-    node_e = _hash_rows(node_a, node_b)
-    return _hash_rows(node_e, node_f)
+    zero = jnp.zeros_like(eb_chunk)
+    lefts = jnp.stack([eb_chunk, node_a, zero])
+    rights = jnp.stack([slashed_chunk, zero, node_f])
+    # where the running digest goes: nowhere, right (H(A, B)), left (H(E, F))
+    carry_left = jnp.asarray([False, False, True])
+    carry_right = jnp.asarray([False, True, False])
+
+    def step(digest, operands):
+        left, right, use_left, use_right = operands
+        digest = _hash_rows(
+            jnp.where(use_left, digest, left), jnp.where(use_right, digest, right)
+        )
+        return digest, None
+
+    root, _ = lax.scan(step, zero, (lefts, rights, carry_left, carry_right))
+    return root
 
 
 def validator_registry_root(
@@ -368,14 +388,10 @@ def build_static(
     return arrays, meta
 
 
-def synthetic_static(spec, n: int, seed: int = 0) -> tuple[StateRootArrays, StateRootMeta]:
-    """Bench/demo static content WITHOUT building an n-validator object
-    state: random static nodes, zero small-field chunks — the exact same
-    device hash count and tree shape as build_static, minus the one-time
-    host harvest. Roots are not meaningful; timings are."""
-    import jax
-
-    rng = np.random.default_rng(seed)
+def synthetic_meta(spec, n: int) -> StateRootMeta:
+    """The StateRootMeta :func:`synthetic_static` pairs its arrays with —
+    the container shape of ``spec.BeaconState`` at registry size n, no
+    array built (the compile rehearsals need only this)."""
     fields = list(spec.BeaconState.fields())
     top_depth = max(len(fields) - 1, 0).bit_length()
     dynamic_names = {
@@ -392,6 +408,21 @@ def synthetic_static(spec, n: int, seed: int = 0) -> tuple[StateRootArrays, Stat
     dynamic_slots = tuple(
         (i, name) for i, name in enumerate(fields) if name in dynamic_names
     )
+    return StateRootMeta(
+        dynamic_slots=dynamic_slots, n_validators=n, top_depth=top_depth
+    )
+
+
+def synthetic_static(spec, n: int, seed: int = 0) -> tuple[StateRootArrays, StateRootMeta]:
+    """Bench/demo static content WITHOUT building an n-validator object
+    state: random static nodes, zero small-field chunks — the exact same
+    device hash count and tree shape as build_static, minus the one-time
+    host harvest. Roots are not meaningful; timings are."""
+    import jax
+
+    rng = np.random.default_rng(seed)
+    meta = synthetic_meta(spec, n)
+    top_depth = meta.top_depth
 
     def rnd(shape):
         return jax.device_put(
@@ -421,9 +452,7 @@ def synthetic_static(spec, n: int, seed: int = 0) -> tuple[StateRootArrays, Stat
         )
     except Exception:
         pass
-    return arrays, StateRootMeta(
-        dynamic_slots=dynamic_slots, n_validators=n, top_depth=top_depth
-    )
+    return arrays, meta
 
 
 def state_root_real_hashes(meta: StateRootMeta) -> int:
@@ -481,8 +510,8 @@ def post_epoch_state_root(
         with obs.span(
             "state_root.post_epoch", work_bytes=96 * real, n_validators=meta.n_validators
         ) as sp:
-            sp.result = out = _post_epoch_state_root_impl(
-                arrays, meta, balances, effective_balance, inactivity_scores, just
+            sp.result = out = _compiled_state_root(meta)(
+                arrays, balances, effective_balance, inactivity_scores, just
             )
         return out
 
@@ -498,6 +527,22 @@ def post_epoch_state_root(
     obs.count("state_root.roots", 1)
     obs.count("state_root.real_hashes", real)
     return out
+
+
+@lru_cache(maxsize=None)
+def _compiled_state_root(meta: StateRootMeta):
+    """One executable per registry/container shape. Run eagerly the
+    graph is one dispatch per primitive — on an accelerator, where the
+    sha rounds are unrolled, some hundred thousand of them a root."""
+    import jax
+
+    @jax.jit
+    def run(arrays, balances, effective_balance, inactivity_scores, just):
+        return _post_epoch_state_root_impl(
+            arrays, meta, balances, effective_balance, inactivity_scores, just
+        )
+
+    return run
 
 
 def state_root_compile_key(meta: StateRootMeta) -> tuple:

@@ -30,7 +30,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from eth_consensus_specs_tpu.ops.altair_epoch import (
@@ -69,15 +69,38 @@ class MeshReductions:
             sid = sid * self.mesh.shape[a] + lax.axis_index(a)
         return sid
 
+    def _psum(self, x: jnp.ndarray) -> jnp.ndarray:
+        """``lax.psum`` that the chip's compiler accepts for u64 lanes.
+
+        XLA:TPU has no 64-bit all-reduce (compiled for a described v5e
+        mesh the plain psum is refused: "Supported lowering only of Sum
+        all reduce" on a u64 add). So a u64 operand crosses the mesh as
+        four 16-bit limbs in u32 lanes, one fused all-reduce: a limb sum
+        stays below 2^32 for up to 2^16 shards, and the limbs recombine
+        in u64 with the carries the wrapping adds give — bit-identical
+        to the u64 sum mod 2^64."""
+        if x.dtype != jnp.uint64:
+            return lax.psum(x, self.axes)
+        assert self.n_shards <= 1 << 16
+        limbs = tuple(
+            ((x >> jnp.uint64(16 * k)) & jnp.uint64(0xFFFF)).astype(jnp.uint32)
+            for k in range(4)
+        )
+        out = jnp.zeros_like(x)
+        for k, limb in enumerate(lax.psum(limbs, self.axes)):
+            out = out + (limb.astype(jnp.uint64) << jnp.uint64(16 * k))
+        return out
+
     def sum(self, x: jnp.ndarray) -> jnp.ndarray:
-        return lax.psum(jnp.sum(x), self.axes)
+        return self._psum(jnp.sum(x))
 
     def scatter_add(self, idx: jnp.ndarray, amounts: jnp.ndarray, local_n: int) -> jnp.ndarray:
         """Cross-shard scatter-add via one dense global-length psum.
 
         NOTE: this is deliberately an O(n_validators) collective — the one
         reduction in the epoch kernel that is not a 32-byte scalar. At 1M
-        validators it all-reduces 8 MB per epoch, which at ICI bandwidth
+        validators it all-reduces 8 MB per epoch (16 MB as u32 limbs, see
+        :meth:`_psum`), which at ICI bandwidth
         (~100 GB/s/link) is ~0.1 ms — far below the epoch kernel's compute
         time, so the simple dense form wins until profiles say otherwise.
         The sparse alternative (ragged all_to_all of (index, amount) pairs
@@ -90,7 +113,7 @@ class MeshReductions:
             .at[jnp.clip(idx, 0, global_n - 1)]
             .add(amounts)
         )
-        dense = lax.psum(dense, self.axes)
+        dense = self._psum(dense)
         start = (self._shard_id() * local_n).astype(jnp.int32)
         return lax.dynamic_slice(dense, (start,), (local_n,))
 
@@ -132,7 +155,7 @@ def sharded_epoch_fn(mesh: Mesh, params: EpochParams):
         mesh=mesh,
         in_specs=(cols_spec, just_spec),
         out_specs=res_spec,
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -179,7 +202,7 @@ def sharded_altair_epoch_fn(
         mesh=mesh,
         in_specs=(cols_spec, just_spec),
         out_specs=res_spec,
-        check_rep=False,
+        check_vma=False,
     )
 
 

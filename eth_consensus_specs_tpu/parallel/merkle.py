@@ -16,7 +16,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from eth_consensus_specs_tpu.ops.merkle import tree_root_words
 
@@ -43,7 +43,7 @@ def tree_root_sharded_fn(mesh: Mesh, depth: int, axis: str = SP_AXIS):
         mesh=mesh,
         in_specs=P(axis),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
 

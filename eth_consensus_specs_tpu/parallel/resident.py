@@ -318,7 +318,11 @@ def _compiled_runner(params, n_epochs: int, mode: str, n: int, depth: int, meta,
             def body(_, carry):
                 cols, just, acc, forest = carry
                 old = (cols.balance, cols.effective_balance, cols.inactivity_scores)
-                cols, just = _advance(cols, just)
+                # the barrier keeps the accounting arithmetic OUT of the
+                # hashing fusions that consume its columns (each consumer
+                # would re-derive them in its own prologue, and a sha
+                # fusion with a u64 prologue compiles for minutes)
+                cols, just = lax.optimization_barrier(_advance(cols, just))
                 forest, root = post_epoch_state_root_inc(
                     arrays,
                     meta,

@@ -93,7 +93,7 @@ def replica_chips_env(n: int, environ=None) -> dict[str, str]:
     hardware and the flag is left alone (``mesh_chips`` caps the mesh
     instead)."""
     environ = os.environ if environ is None else environ
-    if environ.get("JAX_PLATFORMS", "cpu") != "cpu" or n <= 0:
+    if environ.get("JAX_PLATFORMS") != "cpu" or n <= 0:
         return {}
     return {"XLA_FLAGS": chips_xla_flags(n, environ.get("XLA_FLAGS", ""))}
 
@@ -105,8 +105,9 @@ def force_virtual_chips(
     then ``default``) and force that many virtual CPU devices via
     ``XLA_FLAGS`` — only on the cpu platform, only when the flag is not
     already set (an operator-set flag wins), and only for N > 1.
-    Defaults ``JAX_PLATFORMS`` to cpu (real-accelerator hosts override
-    it and are left alone). Returns the resolved chip count."""
+    The platform is the caller's to name: virtual devices exist only
+    under ``JAX_PLATFORMS=cpu``, and with it unset JAX picks the
+    machine's accelerator. Returns the resolved chip count."""
     n = parse_chips()
     if n <= 0 and env_var:
         try:
@@ -115,7 +116,6 @@ def force_virtual_chips(
             n = 0
     if n <= 0:
         n = default
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     flags = os.environ.get("XLA_FLAGS", "")
     if (
         n > 1

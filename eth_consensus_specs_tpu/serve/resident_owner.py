@@ -96,10 +96,10 @@ class ResidentOwner:
         import jax
 
         import __graft_entry__ as graft
-        from eth_consensus_specs_tpu.forks import get_spec
+        from eth_consensus_specs_tpu.ops.slot_pipeline import slot_spec
         from eth_consensus_specs_tpu.ops.state_root import synthetic_static
 
-        self._spec = get_spec("altair", "minimal")
+        self._spec = slot_spec()
         cols, just = graft._example_altair_inputs(self.cfg.resident_validators)
         self._static = synthetic_static(self._spec, self.cfg.resident_validators)
         return jax.device_put(cols), jax.device_put(just)

@@ -90,6 +90,13 @@ class VerifyService:
     def __init__(self, config: ServeConfig | None = None, name: str = "serve"):
         self.config = config or ServeConfig.from_env()
         self.name = name
+        # every kernel this service dispatches compiles through the
+        # persistent cache (utils/cache.py: JAX_COMPILATION_CACHE_DIR or
+        # <checkout>/.jax_cache) — without it a service on an accelerator
+        # pays minutes of limb-kernel compiles in every process
+        from eth_consensus_specs_tpu.utils.cache import enable_persistent_cache
+
+        enable_persistent_cache()
         self.admission = AdmissionController(self.config.max_queue, self.config.max_bytes)
         self._batcher = MicroBatcher()
         # depth-2 hand-off: batch N+1's host prep overlaps batch N's

@@ -115,10 +115,9 @@ class SlotWorld:
         import jax
 
         import __graft_entry__ as graft
-        from eth_consensus_specs_tpu.forks import get_spec
         from eth_consensus_specs_tpu.ops.state_root import synthetic_static
 
-        self._spec = get_spec("altair", "minimal")
+        self._spec = slot_pipeline.slot_spec()
         cols, just = graft._example_altair_inputs(self.n_validators)
         self._static = synthetic_static(self._spec, self.n_validators)
         return jax.device_put(cols), jax.device_put(just)
@@ -242,9 +241,11 @@ class SlotWorld:
 
     def _prewarm(self) -> None:
         """Compile the epoch-boundary chain + root gate on a throwaway
-        forest COPY (run_epochs donates), and AOT-compile the smallest
-        slot_apply bucket — after boot, slot serving's fixed-shape
-        kernels never cold-compile."""
+        forest COPY (run_epochs donates), and AOT-compile the slot_apply
+        bucket a FULL slot of this registry lands in (every validator
+        attests once an epoch, so a slot sets n / SLOTS_PER_EPOCH flags;
+        a whole sync committee is credited) — after boot, slot serving's
+        fixed-shape kernels never cold-compile."""
         import jax
         import numpy as np
 
@@ -265,9 +266,16 @@ class SlotWorld:
             forest=forest_copy,
         )
         snapshot.state_root_bytes(self._static, self._plan, warm.forest, warm.just)
+        from eth_consensus_specs_tpu.serve import buckets
+
+        n = self.n_validators
         precompile_key(
-            ("slot_apply", self.n_validators, 1, 1)
-            + (int(self._plan.cap_val), int(self._plan.cap_bal))
+            buckets.slot_key(
+                n,
+                n // int(self._spec.SLOTS_PER_EPOCH),
+                min(int(self._spec.SYNC_COMMITTEE_SIZE), n),
+                self._plan,
+            )
         )
 
     def _ensure_booted(self) -> None:
@@ -283,6 +291,18 @@ class SlotWorld:
     @property
     def epoch(self) -> int:
         return self._epoch
+
+    def resident_arrays(self) -> list:
+        """Every device array the booted world holds between slots: the
+        static tree content, the columns, the justification state and
+        the forest. Where they live is what says whether the world is
+        resident on the accelerator."""
+        import jax
+
+        self._ensure_booted()
+        return jax.tree_util.tree_leaves(
+            (self._static[0], self._carry.cols, self._carry.just, self._carry.forest)
+        )
 
     def status(self) -> dict:
         out = {
@@ -489,10 +509,9 @@ class SlotWorld:
 
 @lru_cache(maxsize=None)
 def _warm_static(n_validators: int):
-    from eth_consensus_specs_tpu.forks import get_spec
     from eth_consensus_specs_tpu.ops.state_root import synthetic_static
 
-    return synthetic_static(get_spec("altair", "minimal"), n_validators)
+    return synthetic_static(slot_pipeline.slot_spec(), n_validators)
 
 
 def precompile_key(key: tuple, mesh=None) -> bool:

@@ -78,7 +78,6 @@ def main() -> None:
     args = ap.parse_args()
 
     sys.path.insert(0, REPO)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     from eth_consensus_specs_tpu import obs
     from eth_consensus_specs_tpu.obs import flight

@@ -171,10 +171,9 @@ def run_oracle(args, reqs):
     import jax
 
     import __graft_entry__ as graft
-    from eth_consensus_specs_tpu.forks import get_spec
     from eth_consensus_specs_tpu.ops.state_root import synthetic_static
 
-    spec = get_spec("altair", "minimal")
+    spec = sp.slot_spec()
     static = synthetic_static(spec, args.validators)
     cols, just = graft._example_altair_inputs(args.validators)
     cols, just = jax.device_put(cols), jax.device_put(just)
@@ -191,10 +190,9 @@ def slot_warm_keys(args, reqs) -> list[tuple]:
     bucket the schedule's request-derived capacities will hit (the LIVE
     key fn — router, dispatch, and warmup can never disagree), plus the
     blob-verification lane buckets the sidecar distribution needs."""
-    from eth_consensus_specs_tpu.forks import get_spec
     from eth_consensus_specs_tpu.ops.state_root import forest_plan, synthetic_static
 
-    _, meta = synthetic_static(get_spec("altair", "minimal"), args.validators)
+    _, meta = synthetic_static(sp.slot_spec(), args.validators)
     plan = forest_plan(meta)
     keys = {serve_buckets.slot_key(args.validators, 1, 1, plan)}
     blob_counts = set()

@@ -120,7 +120,7 @@ def test_degrade_falls_back_on_device_failure_only():
     before = obs.snapshot()["counters"].get("fault.degraded", 0)
 
     def dead_device():
-        raise RuntimeError("RESOURCE_EXHAUSTED: out of memory while compiling")
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of memory allocating 1GB")
 
     assert fault.degrade("probe.degrade", dead_device, lambda: "host") == "host"
     assert obs.snapshot()["counters"]["fault.degraded"] - before == 1
@@ -151,6 +151,32 @@ def test_is_device_failure_classification():
     assert fault.is_device_failure(RuntimeError("INTERNAL: failed to allocate 1GB"))
     assert not fault.is_device_failure(ValueError("shape mismatch"))
     assert not fault.is_device_failure(AssertionError("spec violated"))
+
+
+class XlaRuntimeError(RuntimeError):
+    """Stand-in with the runtime's type name (classification reads it)."""
+
+
+@pytest.mark.parametrize(
+    "exc,degradable",
+    [
+        (XlaRuntimeError("UNAVAILABLE: TPU worker lost"), True),
+        (XlaRuntimeError("RESOURCE_EXHAUSTED: out of memory allocating 2GB"), True),
+        (XlaRuntimeError("INTERNAL: Mosaic failed to compile TPU kernel"), False),
+        (XlaRuntimeError("RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. "
+                         "Ran out of memory in memory space hbm"), False),
+        (XlaRuntimeError("UNIMPLEMENTED: 64-bit dot is not supported"), False),
+        (XlaRuntimeError("INVALID_ARGUMENT: shapes do not match"), False),
+        (RuntimeError("compilation failure: kernel too large"), False),
+    ],
+)
+def test_compile_refusal_is_not_a_device_death(exc, degradable):
+    """A compiler that refuses a kernel is not a device dying under load:
+    the error reaches the caller instead of the host oracle answering."""
+    assert fault.is_device_failure(exc) is degradable
+    if not degradable:
+        with pytest.raises(type(exc)):
+            fault.degrade("probe.compile", lambda: (_ for _ in ()).throw(exc), lambda: "host")
 
 
 # ------------------------------------------------ multihost guards --

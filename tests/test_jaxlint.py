@@ -114,6 +114,22 @@ def test_donation_audit_opportunity_waiver_and_declared():
     assert f.symbol == "declared:arg0:not-donated"
 
 
+def test_donation_audit_reads_the_jit_equation():
+    """The audit learns what a callable donates from its top-level ``jit``
+    equation (the primitive JAX 0.9 names ``jit``; keyed on the older
+    ``pjit`` the rule saw no donation anywhere and could only fail closed
+    on declared ones). Shown on the registry's own donating kernel: every
+    forest buffer slot_apply declares is found donated AND aliased."""
+    fn = jax.jit(lambda x: x + 1, donate_argnums=(0,))
+    [eqn] = jax.make_jaxpr(fn)(_sds((8,), jnp.float32)).jaxpr.eqns
+    assert eqn.primitive.name == "jit" and eqn.params["donated_invars"] == (True,)
+
+    spec = kernels.by_name()["slot_apply"]
+    assert spec.donate == (6, 7, 8, 9)
+    findings, _ = jaxlint.analyze(rules={"donation-audit"}, registry=(spec,))
+    assert [f.symbol for f in findings if f.symbol.startswith("declared:")] == []
+
+
 def test_donation_audit_unusable_donation_flagged():
     # donated input whose aval matches no output: XLA drops it silently
     def shrinks(x):
@@ -132,7 +148,7 @@ def test_donation_audit_unusable_donation_flagged():
 
 
 def test_collective_audit_single_device_collective_fires():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh1 = Mesh(np.array(jax.devices()[:1]), ("m",))
@@ -150,7 +166,7 @@ def test_collective_audit_single_device_collective_fires():
 
 
 def test_collective_audit_unbound_axis_and_alien_mesh():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from eth_consensus_specs_tpu.parallel.mesh_ops import serve_mesh

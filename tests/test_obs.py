@@ -61,7 +61,7 @@ def test_span_roofline_verdict_attached():
     agg = reg.snapshot()["spans"]["k.fast"]
     assert agg["roofline_ok"] is False
     assert agg["roofline_violations"] == 1
-    assert agg["implied_gbps"] > gates.ACCEL_ROOFLINE_BYTES_S / 1e9
+    assert agg["implied_gbps"] > gates.roofline_bytes_s() / 1e9
     # a later clean timing cannot launder the aggregate verdict
     with reg.span("k.fast", work_bytes=96):
         pass
@@ -118,6 +118,13 @@ def test_gates_roofline_verdict():
     assert not bad["roofline_ok"]
 
 
+def test_gates_roofline_is_keyed_by_device_kind_and_unknown_is_an_error():
+    # v5e: 819 GB/s HBM a chip (Google Cloud documentation, "TPU v5e")
+    assert gates.roofline_bytes_s("TPU v5 lite") == 2 * 819e9
+    with pytest.raises(KeyError, match="TPU v9"):
+        gates.roofline_bytes_s("TPU v9")
+
+
 def test_gates_apply_gates_matches_bench_semantics(capsys):
     frag = {"work_bytes": int(1e15), "unit_s": 0.001}
     gates.apply_gates("tree", frag, "unit_s")
@@ -142,7 +149,7 @@ def test_bench_imports_gate_logic_from_obs():
     assert bench._apply_gates is gates.apply_gates
     assert bench._digest is gates.digest
     assert bench._UNIT_KEY is gates.UNIT_KEY
-    assert bench.ACCEL_ROOFLINE_BYTES_S == gates.ACCEL_ROOFLINE_BYTES_S
+    assert not hasattr(bench, "ACCEL_ROOFLINE_BYTES_S")  # the ceiling is per device kind
 
 
 # ------------------------------------------------------------------ watchdog --

@@ -159,13 +159,3 @@ def test_quarantined_das_lkg_can_only_be_replaced_by_parity_coupled_run():
         "sections": {"das": {"correctness_coupled": 1.0}},
     }
     assert perf_track.reearn_violations(sloppy) == ["das"]
-
-
-def test_current_repo_lkg_passes_reearn_rule():
-    """The committed BENCH_LKG.json (das et al. quarantined, usable
-    sections empty) must satisfy the rule perf_track now gates on."""
-    repo = os.path.join(os.path.dirname(__file__), "..")
-    lkg = perf_track.load_lkg(repo)
-    assert lkg["present"]
-    assert "das" in lkg["quarantined"]
-    assert perf_track.reearn_violations(lkg) == []

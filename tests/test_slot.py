@@ -22,7 +22,6 @@ import pytest
 import __graft_entry__ as graft
 import jax
 from eth_consensus_specs_tpu import fault
-from eth_consensus_specs_tpu.forks import get_spec
 from eth_consensus_specs_tpu.ops import slot_pipeline as sp
 from eth_consensus_specs_tpu.ops.state_root import synthetic_static
 from eth_consensus_specs_tpu.serve import buckets
@@ -101,7 +100,7 @@ def dummy_req(slot=0, bits=((1, 1, 0, 1),), sync=4):
 
 
 def host_oracle(reqs, n=N):
-    spec = get_spec("altair", "minimal")
+    spec = sp.slot_spec()
     cols, just = graft._example_altair_inputs(n)
     static = synthetic_static(spec, n)
     cols, just = jax.device_put(cols), jax.device_put(just)
@@ -128,7 +127,7 @@ def test_request_capacity_is_pre_verdict_shape_only():
 def test_slot_key_buckets_capacities_pow2():
     from eth_consensus_specs_tpu.ops.state_root import forest_plan
 
-    _, meta = synthetic_static(get_spec("altair", "minimal"), N)
+    _, meta = synthetic_static(sp.slot_spec(), N)
     plan = forest_plan(meta)
     k5 = buckets.slot_key(N, 5, 3, plan)
     k8 = buckets.slot_key(N, 8, 4, plan)

@@ -1,0 +1,134 @@
+"""The served path's kernels, compiled for a DESCRIBED v5e at the widths a
+mainnet slot dispatches them — the part of the compile inventory
+(scripts/tpu_compile_inventory.py, analysis/chip_programs.py) that takes
+seconds, kept as tests so that every later PR is held to "the chip's
+compiler accepts this" at no chip time. No device is attached and nothing
+runs: a pass says the program lowers for the TPU (64-bit limb lanes, the
+unrolled sha rounds, the forest's dynamic level offsets) and fits its
+memory, nothing about answers or speed.
+
+The topology is described inside a module-scoped fixture, which skips
+where it cannot be; only the xdist worker that is handed this file loads
+the chip's library, and the programs compile in the test's own process.
+Keep these tests in this one file.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+import chip_smoke
+from eth_consensus_specs_tpu.analysis import chip_programs
+
+HBM_BYTES = 16 << 30  # one v5e chip (Google Cloud documentation, "TPU v5e")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no compiler for the chip here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=False)
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one: keep the cache off around them."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _program(name: str) -> chip_programs.Program:
+    return next(
+        p for p in chip_programs.slot_programs(*chip_smoke.MAINNET) if p.name == name
+    )
+
+
+def _check_row(row: dict) -> None:
+    assert row["code_bytes"] > 0
+    on_device = row["argument_bytes"] + row["output_bytes"] + row["temp_bytes"]
+    assert on_device - row["alias_bytes"] < HBM_BYTES, row
+
+
+@pytest.mark.parametrize("name", ["sha256:tile65536", "sha256:tile2048", "fr_fft"])
+def test_slot_program_compiles_for_one_v5e(one_chip, no_compile_cache, name):
+    """Programs of the mainnet slot that compile in seconds, at exactly the
+    shapes chip_smoke.py dispatches (the same list the inventory walks)."""
+    _check_row(chip_programs.compile_for(one_chip, _program(name)))
+
+
+def _u32(shape, sharding):
+    return jax.ShapeDtypeStruct(shape, jnp.uint32, sharding=sharding)
+
+
+def test_forest_dense_build_at_2_20_is_one_compression_body(one_chip, no_compile_cache):
+    """The resident forest's dense (re)build over 2^20 leaves: ONE sha body
+    in a loop over the levels — a body a level was twenty several-second
+    compiles a tree, three trees a forest."""
+    from eth_consensus_specs_tpu.ops import merkle_inc
+
+    n = chip_smoke.MAINNET.validators
+    prog = chip_programs.Program(
+        "forest_dense_build",
+        lambda: (jax.jit(merkle_inc.build_levels), (_u32((1, n, 8), None),)),
+    )
+    row = chip_programs.compile_for(one_chip, prog)
+    _check_row(row)
+    # all levels out: 2^21 - 1 nodes of 32 B
+    assert row["output_bytes"] >= (2 * n - 1) * 32
+
+
+def test_forest_path_update_at_2_20_compiles(one_chip, no_compile_cache):
+    """The incremental re-root's sparse leg at the registry's depth and the
+    forest plan's dirty capacity: gather, one [cap, 16] sha body, scatter."""
+    from eth_consensus_specs_tpu.analysis.chip_programs import slot_world_shapes
+    from eth_consensus_specs_tpu.ops import merkle_inc
+
+    n = chip_smoke.MAINNET.validators
+    plan = slot_world_shapes(n)[3]
+    cap = int(plan.cap_val)
+    args = (
+        _u32((2 * n - 1, 8), None),
+        jax.ShapeDtypeStruct((cap,), jnp.int32),
+        _u32((cap, 8), None),
+    )
+    prog = chip_programs.Program(
+        "forest_path_update", lambda: (jax.jit(merkle_inc.path_update), args)
+    )
+    _check_row(chip_programs.compile_for(one_chip, prog))
+
+
+def test_sharded_tree_compiles_for_four_described_chips(topo, no_compile_cache):
+    """``chip_smoke.py --chips 4``'s served flush: the tree axis of a
+    merkle_many dispatch split over a (dp, sp) mesh of the four described
+    chips, no collectives — a quarter of the trees a device."""
+    from eth_consensus_specs_tpu.ops import merkle
+    from eth_consensus_specs_tpu.parallel import make_mesh
+    from eth_consensus_specs_tpu.parallel.mesh_ops import BATCH_AXES
+
+    mesh = make_mesh(devices=list(topo.devices))
+    assert mesh.devices.size == 4
+    trees, depth = chip_smoke.MESH_TREES, chip_smoke.MESH_TREE_DEPTH
+    sds = _u32((trees, 1 << depth, 8), NamedSharding(mesh, P(BATCH_AXES)))
+    with chip_programs.as_accelerator():
+        compiled = merkle._many_tree_root_sharded(mesh, depth).lower(sds).compile()
+    mem = compiled.memory_analysis()
+    # argument bytes are per device: its quarter of the flush
+    assert mem.argument_size_in_bytes == trees * (1 << depth) * 32 // 4
