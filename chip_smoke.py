@@ -142,27 +142,32 @@ class CompileLog:
 # ------------------------------------------------------------------- boot --
 
 
-def host_world(sizes: Sizes):
+class HostWorld(NamedTuple):
     """The slot world's deterministic recipe (serve/slot.py), built again
-    here: what the host oracles fold over."""
-    import __graft_entry__ as graft
-    from eth_consensus_specs_tpu.ops.slot_pipeline import slot_spec
-    from eth_consensus_specs_tpu.ops.state_root import synthetic_static
+    here: what the host oracles fold over, and its root by the host."""
 
-    spec = slot_spec()
-    cols, just = graft._example_altair_inputs(sizes.validators)
-    return spec, synthetic_static(spec, sizes.validators), cols, just
+    spec: object
+    static: tuple  # (StateRootArrays, StateRootMeta)
+    cols: object
+    just: object
+    root: bytes
 
 
-def host_root(static, cols, just) -> bytes:
+def host_world(sizes: Sizes) -> HostWorld:
     import jax
     import numpy as np
 
-    from eth_consensus_specs_tpu.ops.slot_pipeline import _root_bytes
-    from eth_consensus_specs_tpu.ops.state_root import post_epoch_state_root_host
+    import __graft_entry__ as graft
+    from eth_consensus_specs_tpu.ops.slot_pipeline import _root_bytes, slot_spec
+    from eth_consensus_specs_tpu.ops.state_root import (
+        post_epoch_state_root_host,
+        synthetic_static,
+    )
 
-    arrays, meta = static
-    return _root_bytes(
+    spec = slot_spec()
+    cols, just = graft._example_altair_inputs(sizes.validators)
+    arrays, meta = static = synthetic_static(spec, sizes.validators)
+    root = _root_bytes(
         post_epoch_state_root_host(
             arrays, meta,
             np.asarray(cols.balance), np.asarray(cols.effective_balance),
@@ -170,16 +175,16 @@ def host_root(static, cols, just) -> bytes:
             jax.tree_util.tree_map(np.asarray, just),
         )
     )
+    return HostWorld(spec, static, cols, just, root)
 
 
-def phase_boot(svc, sizes: Sizes, world_h) -> None:
+def phase_boot(svc, sizes: Sizes, world_h: HostWorld) -> None:
     t0 = time.perf_counter()
     from eth_consensus_specs_tpu.ops.slot_pipeline import SLOT_SPEC
 
     world = svc.slot_world()
     world.boot()  # cold ingest, forest built on the device, prewarm
-    _, static, cols, just = world_h
-    want = host_root(static, cols, just)
+    want = world_h.root
     check(world.root == want, f"boot root {world.root.hex()} != host recompute {want.hex()}")
     check(world.status()["lineage"]["verdict"] == "cold", "boot was not a cold ingest")
     emit("boot", t0, validators=sizes.validators, fork=SLOT_SPEC[0], preset=SLOT_SPEC[1],
@@ -189,7 +194,7 @@ def phase_boot(svc, sizes: Sizes, world_h) -> None:
 # -------------------------------------------------------------- stateless --
 
 
-def phase_stateless(svc, sizes: Sizes, world_h, seed: int) -> None:
+def phase_stateless(svc, sizes: Sizes, world_h: HostWorld, seed: int) -> None:
     """The two verbs that need no limb kernel, through the same service;
     answers equal to the oracles of its degrade leg, called directly."""
     t0 = time.perf_counter()
@@ -197,6 +202,7 @@ def phase_stateless(svc, sizes: Sizes, world_h, seed: int) -> None:
 
     from eth_consensus_specs_tpu.obs.watchdog import host_tree_root_words
     from eth_consensus_specs_tpu.ops.merkle import _chunks_to_words
+    from eth_consensus_specs_tpu.ops.slot_pipeline import _root_bytes
 
     rng = np.random.default_rng(seed)
     trees = [
@@ -208,14 +214,12 @@ def phase_stateless(svc, sizes: Sizes, world_h, seed: int) -> None:
     want = [host_tree_root_words(_chunks_to_words(t, 1 << sizes.htr_depth)) for t in trees]
     check(got == want, "submit_hash_tree_root differs from the host tree")
 
-    _, static, cols, just = world_h
-    arrays, meta = static
-    words = svc.submit_state_root(
-        arrays, meta, cols.balance, cols.effective_balance, cols.inactivity_scores, just
-    ).result(timeout=1100)
-    root = np.asarray(words, np.uint32).astype(">u4").tobytes()
-    want_root = host_root(static, cols, just)
-    check(root == want_root, f"submit_state_root {root.hex()} != host {want_root.hex()}")
+    (arrays, meta), cols = world_h.static, world_h.cols
+    root = _root_bytes(svc.submit_state_root(
+        arrays, meta, cols.balance, cols.effective_balance, cols.inactivity_scores,
+        world_h.just,
+    ).result(timeout=1100))
+    check(root == world_h.root, f"submit_state_root {root.hex()} != host {world_h.root.hex()}")
     emit("stateless", t0, htr_trees=len(trees), htr_depth=sizes.htr_depth,
          state_root_validators=sizes.validators, state_root=root.hex())
 
@@ -307,7 +311,7 @@ def build_slots(sizes: Sizes, seed: int) -> list:
     return reqs
 
 
-def phase_slots(svc, sizes: Sizes, world_h, seed: int) -> None:
+def phase_slots(svc, sizes: Sizes, world_h: HostWorld, seed: int) -> None:
     t0 = time.perf_counter()
     import jax
 
@@ -315,8 +319,8 @@ def phase_slots(svc, sizes: Sizes, world_h, seed: int) -> None:
 
     reqs = build_slots(sizes, seed)
     built_s = round(time.perf_counter() - t0, 3)
-    spec, static, cols, just = world_h
-    cols, just = jax.device_put(cols), jax.device_put(just)
+    spec, static = world_h.spec, world_h.static
+    cols, just = jax.device_put(world_h.cols), jax.device_put(world_h.just)
     epoch, walls, refused = 0, [], {"attestations": 0, "blobs": 0}
     for req in reqs:
         t1 = time.perf_counter()

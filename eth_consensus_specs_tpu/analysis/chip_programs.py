@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import time
 from contextlib import contextmanager
+from functools import lru_cache
 from typing import Callable, NamedTuple
 
 
@@ -27,6 +28,7 @@ class Program(NamedTuple):
     limb: bool = False  # a curve-arithmetic graph: minutes to compile, not seconds
 
 
+@lru_cache(maxsize=None)
 def slot_world_shapes(n_validators: int):
     """(spec, static-array shapes, meta, forest plan, column shapes,
     justification shapes) of the slot world serve/slot.py boots at this
@@ -56,16 +58,14 @@ def slot_programs(
 ) -> list[Program]:
     """Every program one mainnet-shaped slot, the boot before it and the
     two stateless verbs dispatch, in the order the cheap ones come first."""
+    import jax
+
     from eth_consensus_specs_tpu.analysis import kernels
     from eth_consensus_specs_tpu.serve import buckets
     from eth_consensus_specs_tpu.serve.config import ServeConfig
 
-    world = {}
-
     def w():
-        if not world:
-            world["v"] = slot_world_shapes(n_validators)
-        return world["v"]
+        return slot_world_shapes(n_validators)
 
     def sha_tile(tile):
         def build():
@@ -79,8 +79,8 @@ def slot_programs(
         from eth_consensus_specs_tpu.ops import merkle
 
         key = buckets.merkle_many_key(htr_trees, htr_depth, ServeConfig().buckets)
-        fn = lambda words: merkle._many_tree_root_fused(words, htr_depth)  # noqa: E731
-        return _jit(fn), kernels._merkle_many_args(key[1], htr_depth)
+        fn = jax.jit(lambda words: merkle._many_tree_root_fused(words, htr_depth))
+        return fn, kernels._merkle_many_args(key[1], htr_depth)
 
     def forest_build():
         from eth_consensus_specs_tpu.parallel import resident
@@ -178,10 +178,47 @@ def slot_programs(
     ]
 
 
-def _jit(fn):
+def mesh_programs(
+    devices, trees: int, tree_depth: int, validators: int, step_depth: int
+) -> list[Program]:
+    """The two programs of ``chip_smoke.py --chips 4`` over a (dp, sp)
+    mesh of ``devices`` (four described chips in the sandbox): the served
+    merkle_many flush with its tree axis sharded, and the sharded altair
+    epoch + sharded tree step of ``__graft_entry__``. Each argument
+    carries the NamedSharding its program gives it (compile with
+    ``compile_for(None, ...)``)."""
     import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
-    return jax.jit(fn)
+    import __graft_entry__ as graft
+    from eth_consensus_specs_tpu.ops import merkle
+    from eth_consensus_specs_tpu.parallel import make_mesh
+    from eth_consensus_specs_tpu.parallel.mesh_ops import BATCH_AXES
+
+    mesh = make_mesh(devices=list(devices))
+
+    def placed(tree, shardings):
+        return jax.tree_util.tree_map(
+            lambda s, h: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=h), tree, shardings
+        )
+
+    def served_flush():
+        sds = jax.ShapeDtypeStruct(
+            (trees, 1 << tree_depth, 8), jnp.uint32,
+            sharding=NamedSharding(mesh, P(BATCH_AXES)),
+        )
+        return merkle._many_tree_root_sharded(mesh, tree_depth), (sds,)
+
+    def sharded_step():
+        _, stepped, (cols_sh, just_sh, leaves_sh) = graft.sharded_step(mesh, step_depth)
+        cols, just = jax.eval_shape(
+            lambda: graft._example_altair_inputs(validators, electra=True)
+        )
+        leaves = jax.ShapeDtypeStruct((1 << step_depth, 8), jnp.uint32, sharding=leaves_sh)
+        return stepped, (placed(cols, cols_sh), placed(just, just_sh), leaves)
+
+    return [Program("mesh:merkle_many", served_flush), Program("mesh:epoch+tree", sharded_step)]
 
 
 @contextmanager

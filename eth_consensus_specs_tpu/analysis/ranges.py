@@ -423,7 +423,10 @@ class RangeInterp:
 
             for fr in source_info_util.user_frames(tb):
                 base = os.path.basename(fr.file_name)
-                frames.append(f"{base}::{fr.function_name}")
+                # the bare name: a nested function's frame carries its
+                # qualified one (outer.<locals>.inner), sites are declared
+                # by the function that holds the line
+                frames.append(f"{base}::{fr.function_name.rsplit('.', 1)[-1]}")
         except Exception:
             pass
         out = tuple(frames)

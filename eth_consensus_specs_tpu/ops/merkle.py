@@ -39,7 +39,7 @@ def tree_real_hashes(depth: int) -> int:
     """Compressions tree_root_words actually executes at `depth` — the
     honest work count for bench roofline/throughput accounting: every
     level hashes the fixed 2^(d-1)-row buffer."""
-    return depth << max(depth - 1, 0) if depth else 0
+    return depth << (depth - 1) if depth else 0
 
 
 def tree_root_words(leaves: jnp.ndarray, depth: int) -> jnp.ndarray:
@@ -49,14 +49,14 @@ def tree_root_words(leaves: jnp.ndarray, depth: int) -> jnp.ndarray:
     fixed-width [2^(d-1), 16] buffer whose live rows halve each level
     (the spent tail hashes garbage that never reaches a live node). That
     is d*2^(d-1) compressions for a tree of 2^d - 1: d/2 times the exact
-    work, ~10x at depth 20, still milliseconds there. The widest levels
-    used to be unrolled at exact widths (six of them, 1.09x exact at
-    depth 20); every unrolled level is one more compression body for the
-    chip's compiler, 5 to 10 s each compiled for a v5e, in EVERY program
-    that roots a deep tree — the full state root has four such trees and
-    spent ~190 s of its cold start on them (PERF.md, PR 22). What the
-    extra hashing costs at run time is not measured on the chip; the
-    benchmark has to show it before levels are unrolled again.
+    work, ~10x at depth 20, still milliseconds there. Unrolling the
+    widest levels at exact widths buys that work back (six levels: 1.09x
+    exact at depth 20), but every unrolled level is one more compression
+    body for the chip's compiler, 5 to 10 s each compiled for a v5e, in
+    EVERY program that roots a deep tree — the full state root has four
+    such trees, ~190 s of cold start at six levels (PERF.md, PR 22). What
+    the extra hashing costs at run time is not measured on the chip; the
+    benchmark has to show it before a level is unrolled.
 
     Plain function so it composes under outer jits / shard_map (the
     sharded tree in parallel/merkle.py reduces local subtrees with this,

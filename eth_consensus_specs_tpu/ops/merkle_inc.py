@@ -118,9 +118,10 @@ def build_levels(leaves: jnp.ndarray) -> jnp.ndarray:
     the next level's block, which starts exactly where the live rows end
     (and the last block's tail falls in a pad that is sliced off). That
     is d*2^(d-1) compressions for a tree of 2^d-1 — d/2 times the exact
-    work, milliseconds at depth 20 — against one unrolled sha graph per
-    level before: on an accelerator every such graph is its own several
-    seconds of compile, and a state forest has three trees of ~20."""
+    work, milliseconds at depth 20. A compression body a level at exact
+    widths does the exact work, but on an accelerator every such body is
+    its own several seconds of compile, and a state forest has three
+    trees of ~20 levels."""
     n = leaves.shape[-2]
     depth = n.bit_length() - 1
     if depth == 0:
@@ -153,10 +154,10 @@ _PREFIX_ROW = 1024
 
 def _prefix_sum_i32(x: jnp.ndarray) -> jnp.ndarray:
     """Inclusive prefix sum of i32[n] in two levels: within rows of 1024,
-    then over the row totals. Compiled for a v5e at n = 2^20 the
-    log-depth ``lax.associative_scan`` this replaces took 96 s (its
-    strided slices and interleaves unroll twenty levels deep), one flat
-    ``lax.cumsum`` 17 s, this 1 s (PERF.md, PR 22)."""
+    then over the row totals. Compiled for a v5e at n = 2^20 a
+    log-depth ``lax.associative_scan`` takes 96 s (its strided slices
+    and interleaves unroll twenty levels deep), one flat ``lax.cumsum``
+    17 s, this 1 s (PERF.md, PR 22)."""
     n = x.shape[-1]
     if n % _PREFIX_ROW:
         return lax.cumsum(x, axis=0)

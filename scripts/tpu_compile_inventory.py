@@ -54,9 +54,13 @@ def main(argv: list[str] | None = None) -> int:
     sizes = chip_smoke.MAINNET._replace(validators=args.validators)
     total = 0.0
     failed = 0
-    programs = (
-        mesh_programs(topo, chip_smoke) if args.mesh else chip_programs.slot_programs(*sizes)
-    )
+    if args.mesh:
+        programs = chip_programs.mesh_programs(
+            topo.devices, chip_smoke.MESH_TREES, chip_smoke.MESH_TREE_DEPTH,
+            chip_smoke.MESH_VALIDATORS, chip_smoke.MESH_STEP_DEPTH,
+        )
+    else:
+        programs = chip_programs.slot_programs(*sizes)
     for prog in programs:
         if args.only is not None and prog.name not in args.only:
             continue
@@ -76,42 +80,6 @@ def main(argv: list[str] | None = None) -> int:
                 fh.write(line + "\n")
     print(json.dumps({"total_lower_plus_compile_s": round(total, 1), "refused": failed}))
     return 1 if failed else 0
-
-
-def mesh_programs(topo, chip_smoke) -> list:
-    """The sharded served flush and the sharded epoch + tree step of
-    ``chip_smoke.py --chips 4``, over a (dp, sp) mesh of the described
-    chips; each argument carries the NamedSharding the program gives it."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    import __graft_entry__ as graft
-    from eth_consensus_specs_tpu.analysis.chip_programs import Program
-    from eth_consensus_specs_tpu.ops import merkle
-    from eth_consensus_specs_tpu.parallel import make_mesh
-    from eth_consensus_specs_tpu.parallel.mesh_ops import BATCH_AXES
-
-    mesh = make_mesh(devices=list(topo.devices))
-
-    def served_flush():
-        trees, depth = chip_smoke.MESH_TREES, chip_smoke.MESH_TREE_DEPTH
-        sds = jax.ShapeDtypeStruct(
-            (trees, 1 << depth, 8), jnp.uint32, sharding=NamedSharding(mesh, P(BATCH_AXES))
-        )
-        return merkle._many_tree_root_sharded(mesh, depth), (sds,)
-
-    def sharded_step():
-        n, depth = chip_smoke.MESH_VALIDATORS, chip_smoke.MESH_STEP_DEPTH
-        _, stepped, (cols_sh, just_sh, leaves_sh) = graft.sharded_step(mesh, depth)
-        cols, just = jax.eval_shape(lambda: graft._example_altair_inputs(n, electra=True))
-        place = lambda tree, sh: jax.tree_util.tree_map(  # noqa: E731
-            lambda s, h: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=h), tree, sh
-        )
-        leaves = jax.ShapeDtypeStruct((1 << depth, 8), jnp.uint32, sharding=leaves_sh)
-        return stepped, (place(cols, cols_sh), place(just, just_sh), leaves)
-
-    return [Program("mesh:merkle_many", served_flush), Program("mesh:epoch+tree", sharded_step)]
 
 
 if __name__ == "__main__":

@@ -116,9 +116,9 @@ def test_donation_audit_opportunity_waiver_and_declared():
 
 def test_donation_audit_reads_the_jit_equation():
     """The audit learns what a callable donates from its top-level ``jit``
-    equation (the primitive JAX 0.9 names ``jit``; keyed on the older
-    ``pjit`` the rule saw no donation anywhere and could only fail closed
-    on declared ones). Shown on the registry's own donating kernel: every
+    equation (the primitive is named ``jit``; under any other name the
+    rule sees no donation anywhere). Shown on the registry's own donating
+    kernel: every
     forest buffer slot_apply declares is found donated AND aliased."""
     fn = jax.jit(lambda x: x + 1, donate_argnums=(0,))
     [eqn] = jax.make_jaxpr(fn)(_sds((8,), jnp.float32)).jaxpr.eqns

@@ -18,7 +18,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+from jax.sharding import SingleDeviceSharding
 
 import chip_smoke
 from eth_consensus_specs_tpu.analysis import chip_programs
@@ -41,7 +41,7 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(scope="module", autouse=False)
+@pytest.fixture(scope="module")
 def no_compile_cache():
     """A compile for a described chip is written to the persistent cache
     but cannot be read back without one: keep the cache off around them."""
@@ -67,7 +67,9 @@ def _check_row(row: dict) -> None:
     assert on_device - row["alias_bytes"] < HBM_BYTES, row
 
 
-@pytest.mark.parametrize("name", ["sha256:tile65536", "sha256:tile2048", "fr_fft"])
+@pytest.mark.parametrize(
+    "name", ["sha256:tile65536", "sha256:tile2048", "merkle_many", "resident_root", "fr_fft"]
+)
 def test_slot_program_compiles_for_one_v5e(one_chip, no_compile_cache, name):
     """Programs of the mainnet slot that compile in seconds, at exactly the
     shapes chip_smoke.py dispatches (the same list the inventory walks)."""
@@ -115,20 +117,36 @@ def test_forest_path_update_at_2_20_compiles(one_chip, no_compile_cache):
     _check_row(chip_programs.compile_for(one_chip, prog))
 
 
-def test_sharded_tree_compiles_for_four_described_chips(topo, no_compile_cache):
-    """``chip_smoke.py --chips 4``'s served flush: the tree axis of a
-    merkle_many dispatch split over a (dp, sp) mesh of the four described
-    chips, no collectives — a quarter of the trees a device."""
-    from eth_consensus_specs_tpu.ops import merkle
-    from eth_consensus_specs_tpu.parallel import make_mesh
-    from eth_consensus_specs_tpu.parallel.mesh_ops import BATCH_AXES
+@pytest.fixture(scope="module")
+def four_chip_programs(topo):
+    """``chip_smoke.py --chips 4``'s two programs over a (dp, sp) mesh of
+    the four described chips; the epoch step at a registry small enough
+    to compile in half a minute (what the chip's compiler refuses in it
+    does not depend on the size)."""
+    progs = chip_programs.mesh_programs(
+        topo.devices, chip_smoke.MESH_TREES, chip_smoke.MESH_TREE_DEPTH,
+        validators=1 << 14, step_depth=12,
+    )
+    return {p.name: p for p in progs}
 
-    mesh = make_mesh(devices=list(topo.devices))
-    assert mesh.devices.size == 4
-    trees, depth = chip_smoke.MESH_TREES, chip_smoke.MESH_TREE_DEPTH
-    sds = _u32((trees, 1 << depth, 8), NamedSharding(mesh, P(BATCH_AXES)))
-    with chip_programs.as_accelerator():
-        compiled = merkle._many_tree_root_sharded(mesh, depth).lower(sds).compile()
-    mem = compiled.memory_analysis()
+
+def test_sharded_tree_flush_compiles_for_four_described_chips(
+    four_chip_programs, no_compile_cache
+):
+    """The served flush: the tree axis of a merkle_many dispatch split over
+    the mesh, no collectives — a quarter of the trees a device."""
+    row = chip_programs.compile_for(None, four_chip_programs["mesh:merkle_many"])
     # argument bytes are per device: its quarter of the flush
-    assert mem.argument_size_in_bytes == trees * (1 << depth) * 32 // 4
+    flush = chip_smoke.MESH_TREES * (1 << chip_smoke.MESH_TREE_DEPTH) * 32
+    assert row["argument_bytes"] == flush // 4
+
+
+def test_sharded_epoch_step_compiles_for_four_described_chips(
+    four_chip_programs, no_compile_cache
+):
+    """The sharded altair epoch + sharded tree step (``__graft_entry__``).
+    Its reductions sum u64 balances across the mesh, and XLA:TPU has no
+    64-bit all-reduce: a plain ``lax.psum`` of u64 is refused here
+    ("Supported lowering only of Sum all reduce") though it passes on any
+    number of virtual CPU devices. parallel/epoch.py sends 16-bit limbs."""
+    _check_row(chip_programs.compile_for(None, four_chip_programs["mesh:epoch+tree"]))
