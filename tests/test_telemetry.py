@@ -118,6 +118,10 @@ def test_sampler_owns_cursor_and_counts():
     obs.count("tsdb_test.marker", 1)
     s = sampler.sample(t=100.0)
     assert s.counters.get("tsdb_test.marker") == 1
+    # the marker is in no catalog: take it out of the process-wide
+    # registry again, or whichever file this worker runs next that
+    # validates an exposition (tests/test_obs_export.py) refuses it
+    obs.get_registry().counters.pop("tsdb_test.marker", None)
     # the sampler's own tsdb.samples bump lands in the NEXT window, and
     # a separate consumer's cursor still sees it (no window stealing)
     assert ship.delta()["counters"].get("tsdb.samples") == 1

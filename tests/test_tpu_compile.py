@@ -43,14 +43,23 @@ def one_chip(topo):
 
 @pytest.fixture(scope="module")
 def no_compile_cache():
-    """A compile for a described chip is written to the persistent cache
-    but cannot be read back without one: keep the cache off around them."""
+    """Two caches are kept out of these tests, both ways. A compile for a
+    described chip is written to the persistent cache but cannot be read
+    back without one: it is off in here. And JAX caches a function's TRACE
+    by its argument shapes, not by which branch the kernel took when it
+    asked for the backend (``chip_programs.as_accelerator``): a shape an
+    earlier test of this worker traced on the CPU would compile its CPU
+    graph here, and a shape traced here would hand a later CPU test the
+    unrolled accelerator graph, minutes of XLA:CPU compile. So every
+    trace is dropped before the first test and after the last."""
     from jax.experimental.compilation_cache import compilation_cache
 
     before = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
+    jax.clear_caches()
     yield
+    jax.clear_caches()
     jax.config.update("jax_enable_compilation_cache", before)
     compilation_cache.reset_cache()
 
