@@ -8,7 +8,7 @@ else is first-party Python/C++.
 
 Layout:
   ssz/        SSZ type system: serialization, merkleization, proofs
-  ops/        device kernels (JAX/Pallas): sha256, shuffle, bls limb math, fft
+  ops/        device kernels (JAX/XLA): sha256, shuffle, bls limb math, fft
   parallel/   mesh + sharding helpers, distributed batch primitives
   utils/      bls backend switch, hash, kzg setup tooling, merkle helpers
   config/     two-tier preset (compile-time sizes) / config (runtime) system

@@ -30,7 +30,9 @@ import numpy as np
 # another chip refuses nothing it should and passes what it should not.
 PEAK_BYTES_S = {
     # Google Cloud documentation, "TPU v5e": 16 GB HBM2e at 819 GB/s a chip
+    # (JAX reports the chip as "TPU v5 lite"; "TPU v5e" is the same part)
     "TPU v5 lite": 819e9,
+    "TPU v5e": 819e9,
     # No published figure applies to XLA:CPU. Host runs check answers,
     # never rates, and sit orders of magnitude below any accelerator
     # bound; they are held to the v5e row only so that a span timed

@@ -1,4 +1,4 @@
-"""Device kernels (JAX/XLA/Pallas): the TPU compute path.
+"""Device kernels (JAX, compiled by XLA): the TPU compute path.
 
 Modules:
   sha256        vectorized SHA-256 compression (merkle node hashing)

@@ -108,6 +108,19 @@ def test_device_phase_refuses_a_cpu_backend(capsys):
     assert '"ok"' not in capsys.readouterr().out
 
 
+def test_four_chip_phases_rehearsed_on_virtual_devices(capsys):
+    """``--chips 4`` on four of the suite's virtual CPU devices at a small
+    registry: the served flush shards (and the one-chip service's does
+    not), sharded == unsharded == host, every sharded array on 4 devices."""
+    chip_smoke.phase_mesh(seed=0, chips=4, validators=256, step_depth=10)
+    serve, step = (json.loads(line) for line in capsys.readouterr().out.splitlines())
+    assert serve["phase"] == "mesh_serve" and serve["sharded_dispatches"] >= 1
+    assert ["merkle_many", chip_smoke.MESH_TREES, chip_smoke.MESH_TREE_DEPTH,
+            "cpu2x2"] in serve["keys"]
+    assert step["phase"] == "mesh_step"
+    assert set(step["shard_devices"].values()) == {4}
+
+
 # ------------------------------------------------------- compile cache --
 
 
