@@ -56,6 +56,7 @@ accumulated).
 from __future__ import annotations
 
 import ast
+import itertools
 import json
 import os
 import re
@@ -882,9 +883,16 @@ def _literal_names(node: ast.AST) -> list[str]:
 # serve.compile_ms.<op> histograms (serve/buckets.py) — before this scan
 # those call sites were invisible to the catalog check (a PR 5 gap: the
 # metric literal lives in the helper, the FAMILY key at the call site)
+# waterfall.leg(name) is a span of that name whose milliseconds land in
+# serve.stage_ms.device.<name>, and names xla.compile_ms.<name>
 _DERIVED_EMITTERS = {
-    "observe_compile_ms": ("histogram", "serve.compile_ms.{}"),
-    "first_dispatch": ("histogram", "serve.compile_ms.{}"),
+    "observe_compile_ms": (("histogram", "serve.compile_ms.{}"),),
+    "first_dispatch": (("histogram", "serve.compile_ms.{}"),),
+    "leg": (
+        ("span", "{}"),
+        ("histogram", "serve.stage_ms.device.{}"),
+        ("histogram", "xla.compile_ms.{}"),
+    ),
 }
 
 
@@ -903,8 +911,8 @@ def rule_obs_discipline(mi: ModuleInfo, catalog) -> list[Finding]:
         if attr in _DERIVED_EMITTERS and mi.modname != "serve.buckets":
             # serve.buckets itself is the helper's home: its internal
             # obs.observe(...) literals are scanned by the branch below
-            kind, template = _DERIVED_EMITTERS[attr]
-            for op in _literal_names(node.args[0]) if node.args else []:
+            names = _literal_names(node.args[0]) if node.args else []
+            for (kind, template), op in itertools.product(_DERIVED_EMITTERS[attr], names):
                 name = template.format(op)
                 if not _METRIC_GRAMMAR_RE.match(name):
                     findings.append(
@@ -927,9 +935,9 @@ def rule_obs_discipline(mi: ModuleInfo, catalog) -> list[Finding]:
                             node.lineno,
                             f"undeclared:{name}",
                             f"{kind} {name!r} (emitted through {attr}) is not "
-                            "declared in obs/catalog.py — compile-timing "
-                            "families added at dispatch sites must be "
-                            "visible to exposition consumers too",
+                            "declared in obs/catalog.py — families keyed at "
+                            "the call site must be visible to exposition "
+                            "consumers too",
                         )
                     )
             continue

@@ -76,18 +76,6 @@ ENV_VARS: tuple[EnvVar, ...] = (
     _v("ETH_SPECS_OBS_XPROF_TOL", "0.25",
        "cost-model rel-err tolerance before `xprof.cost_model_mismatch` fires",
        "observability.md#compile--memory-attribution-xprof"),
-    _v("ETH_SPECS_OBS_DEVPROF", "0",
-       "`1` enables sampled `jax.profiler` trace windows around instrumented "
-       "dispatches (the wall-clock `device.exec_ms` capture is always on "
-       "under obs)", "observability.md#device-time-profiling-devprof"),
-    _v("ETH_SPECS_OBS_DEVPROF_WINDOWS", "2",
-       "profiler trace windows captured per kernel per process before the "
-       "sampler stops paying the trace overhead",
-       "observability.md#device-time-profiling-devprof"),
-    _v("ETH_SPECS_OBS_DEVPROF_DIR", "devprof_traces",
-       "directory the profiler trace windows are written under (one "
-       "subdirectory per kernel/window)",
-       "observability.md#device-time-profiling-devprof"),
     _v("ETH_SPECS_SLO_WAIT_P99_MS", "250",
        "`serve_wait_p99` SLO bound, milliseconds", "observability.md#slos"),
     _v("ETH_SPECS_SLO_DEGRADED_RATE", "0.01",

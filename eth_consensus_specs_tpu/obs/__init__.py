@@ -62,7 +62,7 @@ Waterfall layer (obs/waterfall.py + obs/devprof.py + obs/ledger.py):
     fleet-wide p99 by stage (docs/observability.md).
   * ``obs.devprof`` — measured device execution time per dispatch
     (``device.exec_ms.<kernel>``) with roofline verdicts from MEASURED
-    seconds, plus env-gated sampled ``jax.profiler`` trace windows.
+    seconds.
   * ``obs.ledger`` — the HBM residency ledger: long-lived device
     buffers register bytes per owner (``hbm.resident_bytes.<owner>``
     gauges, high-water via gauge max), embedded in every postmortem
@@ -83,9 +83,6 @@ Environment:
                                  a ring entry (default 65536)
     ETH_SPECS_OBS_XPROF=1        enable ambient XLA attribution capture
     ETH_SPECS_OBS_XPROF_TOL=<f>  cost-model mismatch tolerance (0.25)
-    ETH_SPECS_OBS_DEVPROF=1      enable sampled jax.profiler trace windows
-    ETH_SPECS_OBS_DEVPROF_WINDOWS=<n>  trace windows per process (default 2)
-    ETH_SPECS_OBS_DEVPROF_DIR=<dir>    profiler trace destination
     ETH_SPECS_SLO_WAIT_P99_MS    serve wait p99 SLO bound (default 250)
     ETH_SPECS_SLO_DEGRADED_RATE  degraded-per-request SLO bound (0.01)
 """

@@ -68,6 +68,8 @@ CATALOG: tuple[Metric, ...] = (
     _c("state_root.roots", "post-epoch state roots computed"),
     _c("state_root.traces", "state-root kernel (re)traces"),
     _s("state_root.post_epoch", "device post-epoch state root"),
+    _s("state_root.launch", "leg: the state-root program's call until it returns"),
+    _s("state_root.wait", "leg: blocking on the state root"),
     _s("state_root.post_epoch_host", "host-oracle post-epoch state root"),
     _c("state_root.inc_roots", "incremental (forest) post-epoch state roots"),
     _c("state_root.inc_real_hashes",
@@ -127,6 +129,16 @@ CATALOG: tuple[Metric, ...] = (
     _c("kzg.fft_rows", "blob polynomials through the batched device inverse FFT"),
     _c("kzg.isolated_invalid", "invalid blobs isolated by RLC bisection"),
     _s("kzg.verify_many", "batched blob KZG verification with bisection"),
+    _s("kzg.brp", "leg: bit reversal of a flush's rows, roots of unity"),
+    _s("kzg.horner", "leg: Horner over a flush's monomial coefficients"),
+    _s("kzg.rlc_fold", "leg: Fiat-Shamir hash, powers and lane lists of one RLC check"),
+    _s("kzg.pairing", "leg: the routed pairing check of one RLC check"),
+    _s("fr_fft.pack", "leg: integers to Montgomery limbs"),
+    _s("fr_fft.call", "leg: host clock round the synced batched-FFT device call"),
+    _s("fr_fft.unpack", "leg: Montgomery limbs back to integers"),
+    _s("g1_msm.pack", "leg: points and scalars to limbs and bits"),
+    _s("g1_msm.call", "leg: host clock round the synced multi-MSM device call"),
+    _s("g1_msm.unpack", "leg: Jacobian results to affine points"),
     # ---------------------------------------------------------------- das --
     _g("das.blobs", "blobs in the live DAS bench flush"),
     _c("das.flushes", "DAS bench blob-verification flushes"),
@@ -176,16 +188,17 @@ CATALOG: tuple[Metric, ...] = (
     _h("serve.wait_ms", "request wait from submit to flush, ms"),
     _h("serve.stage_ms.*",
        "per-request waterfall stage ms (admit/queue/prep/handoff/dispatch_wait/"
-       "device/resolve/other/total, plus the front door's wire residual)"),
+       "device/resolve/other/total, plus the front door's wire residual); "
+       "device.<leg> and device.other split the device stage (waterfall.leg)"),
     _s("serve.dispatch", "one batched device dispatch"),
+    _s("serve.batch_wait", "the batch thread waiting for a request or its deadline"),
+    _s("serve.prep", "host prep of one flush on the batch thread"),
     # ------------------------------------------------------------- device --
     _h("device.exec_ms", "measured device execution ms per dispatch (devprof)"),
     _h("device.exec_ms.*", "measured device execution ms per kernel"),
     _c("device.roofline_violations",
        "measured device timings implying impossible bandwidth"),
     _c("device.roofline_violations.*", "measured-roofline violations per kernel"),
-    _c("device.devprof.windows", "jax.profiler trace windows captured"),
-    _c("device.devprof.unavailable", "profiler trace attempts that degraded"),
     # ---------------------------------------------------------------- hbm --
     _g("hbm.resident_bytes.*", "ledger-registered device bytes per owner"),
     _g("hbm.resident_bytes_total", "ledger-registered device bytes, all owners"),
@@ -267,6 +280,11 @@ CATALOG: tuple[Metric, ...] = (
     _g("xprof.*.*", "per-kernel XLA cost/memory attribution (flops, bytes_accessed, peak_bytes, ...)"),
     _h("xprof.compile_ms", "AOT compile wall ms"),
     _h("xprof.compile_ms.*", "AOT compile wall ms per kernel"),
+    # each sample also emits an `xla.compile` event (fun_name, leg, ms,
+    # cache_hit) into the ring and the JSONL
+    _h("xla.compile_ms.*",
+       "XLA backend-compile events (persistent-cache hits among them), ms, by "
+       "the waterfall leg open on the compiling thread (none outside one)"),
     # ------------------------------------------------------------ flight --
     _c("flight.dumps", "postmortem bundles written"),
     # ---------------------------------------------------------- lockwatch --

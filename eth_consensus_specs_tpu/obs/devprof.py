@@ -1,6 +1,5 @@
 """Device-time capture: measured execution seconds per kernel dispatch,
-with roofline verdicts computed from MEASURED time, plus sampled
-``jax.profiler`` trace windows.
+with roofline verdicts computed from MEASURED time.
 
 obs/xprof.py times *compiles* and audits the hand byte model against
 what XLA emitted; nothing in the repo times actual device execution.
@@ -18,24 +17,18 @@ latency all billed to the device. This module closes that gap:
     accelerator roofline bumps ``device.roofline_violations``
     (+ per-kernel) and emits an event; the CI obs-report discipline
     treats violations as a measurement bug, not a fast kernel.
-  * :func:`trace_window` — an env-gated (``ETH_SPECS_OBS_DEVPROF=1``,
-    off by default like xprof) sampled ``jax.profiler`` trace: the
-    first ``ETH_SPECS_OBS_DEVPROF_WINDOWS`` (default 2) windows per
-    process write a profile under ``devprof_traces/`` for offline
-    inspection, then the sampler goes quiet. Backends or versions
-    without the profiler degrade to a counted no-op
-    (``device.devprof.unavailable``).
 
-:func:`measure` itself is NOT gated by ``ETH_SPECS_OBS_DEVPROF`` — it
-is a cheap ``perf_counter`` pair, active whenever obs is on, because
-the serve_bench waterfall section gates on ``device.exec_ms`` being
-populated on every platform including CPU CI. With ``ETH_SPECS_OBS=0``
-nothing records. Never raises.
+:func:`measure` is a cheap ``perf_counter`` pair, active whenever obs
+is on, because the serve_bench waterfall section gates on
+``device.exec_ms`` being populated on every platform including CPU CI.
+With ``ETH_SPECS_OBS=0`` nothing records. Never raises. A profiler
+trace is the caller's to take (``utils/profiling.trace``, or the
+benchmark's ``--trace 1``): every ``obs.span`` and ``waterfall.leg``
+is on its clock.
 """
 
 from __future__ import annotations
 
-import contextlib
 import os
 import threading
 import time
@@ -43,12 +36,8 @@ import time
 from . import gates
 from .registry import get_registry, obs_enabled
 
-_DEFAULT_WINDOWS = 2
-_DEFAULT_TRACE_DIR = "devprof_traces"
-
 _SEEN_LOCK = threading.Lock()
 _SEEN: set[str] = set()
-_WINDOWS_TAKEN = 0
 
 
 def _reinit_lock_after_fork_in_child() -> None:
@@ -61,26 +50,9 @@ def _reinit_lock_after_fork_in_child() -> None:
 os.register_at_fork(after_in_child=_reinit_lock_after_fork_in_child)
 
 
-def profiler_enabled() -> bool:
-    """Trace-window gate (the histogram capture only needs obs)."""
-    return obs_enabled() and os.environ.get("ETH_SPECS_OBS_DEVPROF", "0") not in (
-        "0", "false", "",
-    )
-
-
-def _max_windows() -> int:
-    raw = os.environ.get("ETH_SPECS_OBS_DEVPROF_WINDOWS", "")
-    try:
-        return int(raw) if raw else _DEFAULT_WINDOWS
-    except ValueError:
-        return _DEFAULT_WINDOWS
-
-
 def reset_for_tests() -> None:
-    global _WINDOWS_TAKEN
     with _SEEN_LOCK:
         _SEEN.clear()
-        _WINDOWS_TAKEN = 0
 
 
 # ----------------------------------------------------------------- measure --
@@ -152,48 +124,3 @@ def measure(kernel: str, work_bytes: float | None = None) -> _Measure:
     delta is launch latency, not execution). A body that raises records
     nothing: a degraded dispatch's timing would poison the histogram."""
     return _Measure(kernel, work_bytes)
-
-
-# ------------------------------------------------------------ trace window --
-
-
-@contextlib.contextmanager
-def trace_window(kernel: str):
-    """Sampled ``jax.profiler`` window around one dispatch; yields True
-    when a profile is actually being captured. Off by default; bounded
-    per process; degrades to a counted no-op without the profiler."""
-    global _WINDOWS_TAKEN
-    if not profiler_enabled():
-        yield False
-        return
-    with _SEEN_LOCK:
-        if _WINDOWS_TAKEN >= _max_windows():
-            yield False
-            return
-        _WINDOWS_TAKEN += 1
-        n = _WINDOWS_TAKEN
-    out_dir = os.environ.get("ETH_SPECS_OBS_DEVPROF_DIR") or _DEFAULT_TRACE_DIR
-    reg = get_registry()
-    try:
-        import jax.profiler as profiler
-
-        os.makedirs(out_dir, exist_ok=True)
-        profiler.start_trace(out_dir)
-    except Exception:  # noqa: BLE001 — profiler missing/broken: degrade, keep serving
-        reg.count("device.devprof.unavailable", 1)
-        yield False
-        return
-    try:
-        yield True
-    finally:
-        try:
-            profiler.stop_trace()
-            reg.count("device.devprof.windows", 1)
-            reg.emit({
-                "kind": "device.devprof.window",
-                "kernel": kernel,
-                "n": n,
-                "dir": out_dir,
-            })
-        except Exception:  # noqa: BLE001
-            reg.count("device.devprof.unavailable", 1)

@@ -65,13 +65,14 @@ class _SpanHandle:
     so the exit path can block on it (dispatch-acknowledged-but-not-
     executed work then shows up as time, not as a suspiciously free op)."""
 
-    __slots__ = ("name", "attrs", "t0", "parent", "depth", "result", "_annotation",
-                 "_registry", "_trace")
+    __slots__ = ("name", "attrs", "t0", "parent", "depth", "result", "seconds",
+                 "_annotation", "_registry", "_trace")
 
     def __init__(self, registry: "Registry", name: str, attrs: dict):
         self.name = name
         self.attrs = attrs
         self.result = None
+        self.seconds = 0.0  # set at exit, raised body or not (waterfall.leg reads it)
         self._registry = registry
         self._annotation = None
 
@@ -91,7 +92,7 @@ class _SpanHandle:
     def __exit__(self, exc_type, exc, tb):
         if self.result is not None:
             _block_until_ready(self.result)
-        seconds = time.perf_counter() - self.t0
+        seconds = self.seconds = time.perf_counter() - self.t0
         if self._annotation is not None:
             try:
                 self._annotation.__exit__(exc_type, exc, tb)

@@ -31,6 +31,23 @@ replica's own stage histograms reach the parent via the obs delta
 (obs/delta.py) like every other metric — re-observing the shipped
 durations client-side would double count.
 
+**Legs.** The ``device`` stage is one host clock round the whole of a
+flush's ``_execute``: transfer, launch, device time and every piece of
+host arithmetic between the device calls. :func:`leg` splits it where
+the work happens: a leg is an ``obs.span`` (registry aggregate, parent,
+trace ids, and the ``TraceAnnotation`` that puts it on the profiler's
+clock) whose milliseconds are ALSO added to the ledger of the flush the
+calling thread is executing (:func:`open_flush` /
+:func:`close_flush`, the dispatch thread's). At resolve the flush's
+legs ride every member request into ``serve.stage_ms.device.<leg>``,
+and what they do not cover is ``serve.stage_ms.device.other``, as
+``other`` is for the request. Legs are flush-granular (never one an
+item, a lane or a coefficient) and do not nest: a leg opened inside
+another is a plain span and the outer one bills the flush, so the legs
+and ``device.other`` add up to the device stage. Outside a flush a leg
+is a plain span. :func:`current_leg` names the innermost leg open on
+the calling thread, for the compile listener (obs/xprof.py).
+
 Everything here is allocation-light and never raises; with
 ``ETH_SPECS_OBS=0`` the histogram writes are no-ops (marks still cost
 one ``time.monotonic`` — the serve layer is not jit-reachable).
@@ -142,6 +159,18 @@ def stage_durations_ms(t0: float, stamps: dict | None) -> dict:
     return out
 
 
+def add_legs(durations: dict, legs: dict | None) -> None:
+    """Split a resolved request's device stage by the ledger of the flush
+    that served it: each leg as ``device.<leg>``, the rest of the stage
+    as ``device.other``, clamped at 0. No device stage (an error path),
+    no split."""
+    if legs is None or "device" not in durations:
+        return
+    for name, ms in legs.items():
+        durations[f"device.{name}"] = ms
+    durations["device.other"] = max(durations["device"] - sum(legs.values()), 0.0)
+
+
 def observe(durations: dict) -> None:
     """Record one request's stage durations into the
     ``serve.stage_ms.<stage>`` histograms. No-op when obs is disabled
@@ -155,6 +184,73 @@ def observe(durations: dict) -> None:
     reg = get_registry()
     for stage, ms in durations.items():
         reg.observe(f"serve.stage_ms.{stage}", ms)
+
+
+# -------------------------------------------------------------------- legs --
+
+# per thread: `flush` is the ledger of the flush this thread is executing
+# (None outside one), `open` the names of the legs open on it
+_TLS = threading.local()
+
+
+def open_flush() -> dict:
+    """Start the ledger of the flush the calling thread is about to
+    execute and return it: leg name -> milliseconds, summed over the
+    flush (a block that bisects runs its RLC legs eleven times)."""
+    _TLS.flush = ledger = {}
+    return ledger
+
+
+def close_flush() -> None:
+    _TLS.flush = None
+
+
+def _open_legs() -> list:
+    legs = getattr(_TLS, "open", None)
+    if legs is None:
+        legs = _TLS.open = []
+    return legs
+
+
+def current_leg() -> str | None:
+    """The innermost leg open on the calling thread."""
+    legs = _open_legs()
+    return legs[-1] if legs else None
+
+
+class _Leg:
+    __slots__ = ("_span",)
+
+    def __init__(self, span):
+        self._span = span
+
+    def __enter__(self):
+        _open_legs().append(self._span.name)
+        # the span itself: `.result` assigned in the body makes the exit
+        # block on the device, as for any obs.span
+        return self._span.__enter__()
+
+    def __exit__(self, exc_type, exc, tb):
+        try:
+            return self._span.__exit__(exc_type, exc, tb)
+        finally:
+            legs = _open_legs()
+            legs.pop()
+            ledger = getattr(_TLS, "flush", None)
+            if ledger is not None and not legs:
+                name = self._span.name
+                ledger[name] = ledger.get(name, 0.0) + self._span.seconds * 1e3
+
+
+def leg(name: str, **attrs):
+    """A named piece of a flush's device stage, around the code that
+    does the work (see the module doc). ``with leg("x") as sp`` yields
+    the span, so ``sp.result = value`` blocks the exit on the device."""
+    from .registry import get_registry, obs_enabled
+
+    span = get_registry().span(name, **attrs)
+    # ETH_SPECS_OBS=0: the registry's _NullSpan, no ledger, no stack
+    return _Leg(span) if obs_enabled() else span
 
 
 # ------------------------------------------------------------------- stash --

@@ -36,6 +36,19 @@ the floor; the TPU unrolled form sits near 1×). So:
     ``xprof.cost_model_mismatch`` (+ per-kernel) and emits an event.
     The CI obs-report job asserts this counter is zero on a clean run.
 
+**What XLA compiled, where** (:func:`install_compile_listener`). The
+above times compiles this module asks for. What the process compiles on
+its own (a first dispatch, an eager op traced anew in every flush) JAX
+reports through ``jax.monitoring``, with the function's name: the
+listener files each backend-compile event's milliseconds under
+``xla.compile_ms.<leg>``, the innermost ``waterfall.leg`` open on the
+compiling thread (``none`` outside one), and emits an ``xla.compile``
+event (``fun_name``, ``leg``, ``ms``, ``cache_hit``). JAX's event spans
+the persistent cache's lookup, so a hit counts as an event and says so.
+``serve.compiles`` (serve/buckets.py) counts the FIRST SIGHTING of a
+shape key, what the program believes it compiled; these count XLA's own
+events.
+
 Ambient capture is **opt-in** (``ETH_SPECS_OBS_XPROF=1``): an AOT
 ``lower().compile()`` does not populate the jit call cache, so ambient
 analysis roughly doubles per-shape compile cost — fine for benches,
@@ -51,10 +64,15 @@ import os
 import threading
 import time
 
+from . import waterfall
 from .registry import get_registry, obs_enabled
 
 _SEEN_LOCK = threading.Lock()
 _SEEN: set[tuple] = set()
+_LISTENING = False
+# JAX reports a persistent-cache hit as an event of its own, on the
+# compiling thread, before the backend-compile event that spans it
+_COMPILING = threading.local()
 
 _DEFAULT_TOL = 0.25
 
@@ -88,6 +106,41 @@ def tolerance() -> float:
 def reset_for_tests() -> None:
     with _SEEN_LOCK:
         _SEEN.clear()
+
+
+# ------------------------------------------------------- compile listener --
+
+
+def _on_event(name: str, **_) -> None:
+    if name.endswith("compilation_cache/cache_hits"):
+        _COMPILING.cache_hit = True
+
+
+def _on_duration(name: str, seconds: float, fun_name: str = "", **_) -> None:
+    if not name.endswith("backend_compile_duration"):
+        return
+    cache_hit = getattr(_COMPILING, "cache_hit", False)
+    _COMPILING.cache_hit = False
+    leg = waterfall.current_leg() or "none"
+    ms = float(seconds) * 1e3
+    reg = get_registry()
+    reg.observe(f"xla.compile_ms.{leg}", ms)
+    reg.emit({"kind": "xla.compile", "fun_name": str(fun_name), "leg": leg,
+              "ms": round(ms, 3), "cache_hit": cache_hit})
+
+
+def install_compile_listener() -> None:
+    """Once a process (the service installs it at start-up; not
+    env-gated: a dict update an event, nothing with ``ETH_SPECS_OBS=0``)."""
+    global _LISTENING
+    with _SEEN_LOCK:
+        if _LISTENING:
+            return
+        _LISTENING = True
+    import jax.monitoring as mon
+
+    mon.register_event_listener(_on_event)
+    mon.register_event_duration_secs_listener(_on_duration)
 
 
 # --------------------------------------------------------------- analyses --

@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from eth_consensus_specs_tpu.obs import waterfall
+
 from .limb_field import LimbField
 
 # BLS12-381 scalar field (the polynomial / erasure-coding field)
@@ -196,20 +198,26 @@ def batch_fft_field(
     roots = tuple(int(r) for r in roots_of_unity)
     n = len(roots)
     b = len(batches)
-    rows = [[int(x) % BLS_MODULUS for x in row] for row in batches]
-    if pad_batch is not None:
-        assert pad_batch >= b
-        rows += [[0] * n] * (pad_batch - b)
-    arr = FR.ints_to_mont_batch(rows)
-    if inv:
-        inv_roots = (roots[0],) + roots[:0:-1]
-        out = batch_fft_mont(jnp.asarray(arr), inv_roots, mesh=mesh)
-        invlen_mont = jnp.asarray(FR.to_mont(pow(n, BLS_MODULUS - 2, BLS_MODULUS)))
-        out = FR.mont_mul(out, invlen_mont)
-    else:
-        out = batch_fft_mont(jnp.asarray(arr), roots, mesh=mesh)
-    flat = FR.mont_batch_to_ints(np.asarray(out)[:b])
-    return [flat[i * n : (i + 1) * n] for i in range(b)]
+    with waterfall.leg("fr_fft.pack"):
+        rows = [[int(x) % BLS_MODULUS for x in row] for row in batches]
+        if pad_batch is not None:
+            assert pad_batch >= b
+            rows += [[0] * n] * (pad_batch - b)
+        arr = FR.ints_to_mont_batch(rows)
+    # host clock round a synced device call: transfer in, the FFT program,
+    # the eager inverse scaling, transfer out
+    with waterfall.leg("fr_fft.call"):
+        if inv:
+            inv_roots = (roots[0],) + roots[:0:-1]
+            out = batch_fft_mont(jnp.asarray(arr), inv_roots, mesh=mesh)
+            invlen_mont = jnp.asarray(FR.to_mont(pow(n, BLS_MODULUS - 2, BLS_MODULUS)))
+            out = FR.mont_mul(out, invlen_mont)
+        else:
+            out = batch_fft_mont(jnp.asarray(arr), roots, mesh=mesh)
+        out = np.asarray(out)
+    with waterfall.leg("fr_fft.unpack"):
+        flat = FR.mont_batch_to_ints(out[:b])
+        return [flat[i * n : (i + 1) * n] for i in range(b)]
 
 
 def fft_field_device(vals, roots_of_unity, inv: bool = False) -> list[int]:

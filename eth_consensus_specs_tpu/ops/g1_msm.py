@@ -35,6 +35,7 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from eth_consensus_specs_tpu import obs
+from eth_consensus_specs_tpu.obs import waterfall
 from eth_consensus_specs_tpu.crypto.curve import Point, B1, g1_infinity
 from eth_consensus_specs_tpu.crypto.fields import Fq, P as P_INT
 
@@ -430,26 +431,31 @@ def msm_g1_many_device(
     max_lanes = max(len(p) for p in point_lists)
     item_pad, lane_pad = pad_shape or (n, mesh_lane_pad(max_lanes, shards))
     assert item_pad >= n and lane_pad >= max_lanes
-    bits = np.zeros((item_pad, lane_pad, SCALAR_BITS), np.uint64)
-    X = np.zeros((item_pad, lane_pad, N_LIMBS), np.uint64)
-    Y = np.zeros((item_pad, lane_pad, N_LIMBS), np.uint64)
-    Z = np.zeros((item_pad, lane_pad, N_LIMBS), np.uint64)
-    for i, (points, scalars) in enumerate(zip(point_lists, scalar_lists)):
-        assert len(points) == len(scalars)
-        if points:
-            X[i, : len(points)], Y[i, : len(points)], Z[i, : len(points)] = (
-                _points_to_limbs(points)
-            )
-            bits[i, : len(points)] = _scalars_to_bits([int(s) for s in scalars])
-    args = (jnp.asarray(bits), jnp.asarray(X), jnp.asarray(Y), jnp.asarray(Z))
-    if mesh is not None:
-        obs.count("mesh.dispatches", 1)
-        obs.count("mesh.sharded_items", n)
-        rX, rY, rZ = _sharded_fn(mesh, "msm_many")(*args)
-    else:
-        rX, rY, rZ = msm_many_kernel(*args)
-    rX, rY, rZ = np.asarray(rX), np.asarray(rY), np.asarray(rZ)
-    return [_jacobian_to_point(rX[i], rY[i], rZ[i]) for i in range(n)]
+    with waterfall.leg("g1_msm.pack"):
+        bits = np.zeros((item_pad, lane_pad, SCALAR_BITS), np.uint64)
+        X = np.zeros((item_pad, lane_pad, N_LIMBS), np.uint64)
+        Y = np.zeros((item_pad, lane_pad, N_LIMBS), np.uint64)
+        Z = np.zeros((item_pad, lane_pad, N_LIMBS), np.uint64)
+        for i, (points, scalars) in enumerate(zip(point_lists, scalar_lists)):
+            assert len(points) == len(scalars)
+            if points:
+                X[i, : len(points)], Y[i, : len(points)], Z[i, : len(points)] = (
+                    _points_to_limbs(points)
+                )
+                bits[i, : len(points)] = _scalars_to_bits([int(s) for s in scalars])
+        args = (jnp.asarray(bits), jnp.asarray(X), jnp.asarray(Y), jnp.asarray(Z))
+    # host clock round a synced device call: launch, the MSM program,
+    # transfer out
+    with waterfall.leg("g1_msm.call"):
+        if mesh is not None:
+            obs.count("mesh.dispatches", 1)
+            obs.count("mesh.sharded_items", n)
+            rX, rY, rZ = _sharded_fn(mesh, "msm_many")(*args)
+        else:
+            rX, rY, rZ = msm_many_kernel(*args)
+        rX, rY, rZ = np.asarray(rX), np.asarray(rY), np.asarray(rZ)
+    with waterfall.leg("g1_msm.unpack"):
+        return [_jacobian_to_point(rX[i], rY[i], rZ[i]) for i in range(n)]
 
 
 def sum_g1_many_device(

@@ -47,7 +47,7 @@ from eth_consensus_specs_tpu import obs
 from eth_consensus_specs_tpu.crypto import kzg
 from eth_consensus_specs_tpu.crypto.curve import g1_generator, g2_generator
 from eth_consensus_specs_tpu.crypto.fields import R as BLS_MODULUS
-from eth_consensus_specs_tpu.obs import watchdog
+from eth_consensus_specs_tpu.obs import watchdog, waterfall
 
 BYTES_PER_BLOB = kzg.BYTES_PER_BLOB
 BYTES_PER_COMMITMENT = kzg.BYTES_PER_COMMITMENT
@@ -134,8 +134,9 @@ def challenge_evaluations(parsed: list, mesh=None) -> list[int]:
 
     # blobs carry brp(evaluation) order; natural-order rows IFFT to the
     # monomial coefficients (brp is an involution)
-    rows = [kzg.bit_reversal_permutation(poly) for _, _, _, poly, z, _, _ in parsed]
-    roots = kzg.compute_roots_of_unity(N_BLOB)
+    with waterfall.leg("kzg.brp"):
+        rows = [kzg.bit_reversal_permutation(poly) for _, _, _, poly, z, _, _ in parsed]
+        roots = kzg.compute_roots_of_unity(N_BLOB)
     shards = mesh_ops.shard_count(mesh)
     use_mesh = mesh if shards > 1 and len(rows) >= mesh_ops.min_items() else None
     key = buckets.fr_fft_key(len(rows), N_BLOB, mesh=use_mesh)
@@ -144,10 +145,11 @@ def challenge_evaluations(parsed: list, mesh=None) -> list[int]:
         coeff_rows = batch_fft_field(
             rows, roots, inv=True, mesh=use_mesh, pad_batch=key[1]
         )
-    return [
-        _eval_coeffs(coeffs, z)
-        for coeffs, (_, _, _, _, z, _, _) in zip(coeff_rows, parsed)
-    ]
+    with waterfall.leg("kzg.horner"):
+        return [
+            _eval_coeffs(coeffs, z)
+            for coeffs, (_, _, _, _, z, _, _) in zip(coeff_rows, parsed)
+        ]
 
 
 # ------------------------------------------------------------- RLC fold --
@@ -167,49 +169,51 @@ def _rlc_check(parsed: list, ys: list[int], mesh=None, flush_n: int | None = Non
     from eth_consensus_specs_tpu.parallel import mesh_ops
     from eth_consensus_specs_tpu.serve import buckets
 
-    n = len(parsed)
-    degree_poly = N_BLOB.to_bytes(8, kzg.KZG_ENDIANNESS)
-    data = kzg.RANDOM_CHALLENGE_KZG_BATCH_DOMAIN + degree_poly + n.to_bytes(
-        8, kzg.KZG_ENDIANNESS
-    )
-    for (_, commitment_bytes, _, _, z, proof_bytes, _), y in zip(parsed, ys):
-        data += (
-            commitment_bytes
-            + kzg.bls_field_to_bytes(z)
-            + kzg.bls_field_to_bytes(y)
-            + proof_bytes
+    with waterfall.leg("kzg.rlc_fold"):
+        n = len(parsed)
+        degree_poly = N_BLOB.to_bytes(8, kzg.KZG_ENDIANNESS)
+        data = kzg.RANDOM_CHALLENGE_KZG_BATCH_DOMAIN + degree_poly + n.to_bytes(
+            8, kzg.KZG_ENDIANNESS
         )
-    r_powers = kzg.compute_powers(kzg.hash_to_bls_field(data), n)
+        for (_, commitment_bytes, _, _, z, proof_bytes, _), y in zip(parsed, ys):
+            data += (
+                commitment_bytes
+                + kzg.bls_field_to_bytes(z)
+                + kzg.bls_field_to_bytes(y)
+                + proof_bytes
+            )
+        r_powers = kzg.compute_powers(kzg.hash_to_bls_field(data), n)
 
-    proof_pts = [p for _, _, _, _, _, _, p in parsed]
-    c_pts = [c for _, _, c, _, _, _, _ in parsed]
-    zs = [z for _, _, _, _, z, _, _ in parsed]
-    neg_ry = (-sum(rp * y for rp, y in zip(r_powers, ys))) % BLS_MODULUS
-    a_lanes = (proof_pts, list(r_powers))
-    b_lanes = (
-        c_pts + proof_pts + [g1_generator()],
-        list(r_powers)
-        + [z * rp % BLS_MODULUS for z, rp in zip(zs, r_powers)]
-        + [neg_ry],
-    )
+        proof_pts = [p for _, _, _, _, _, _, p in parsed]
+        c_pts = [c for _, _, c, _, _, _, _ in parsed]
+        zs = [z for _, _, _, _, z, _, _ in parsed]
+        neg_ry = (-sum(rp * y for rp, y in zip(r_powers, ys))) % BLS_MODULUS
+        a_lanes = (proof_pts, list(r_powers))
+        b_lanes = (
+            c_pts + proof_pts + [g1_generator()],
+            list(r_powers)
+            + [z * rp % BLS_MODULUS for z, rp in zip(zs, r_powers)]
+            + [neg_ry],
+        )
 
-    shards = mesh_ops.shard_count(mesh)
-    flush_n = max(flush_n or n, n)
-    wide = shards > 1 and buckets.route_wide(
-        "kzg", buckets.kzg_lane_bucket(flush_n, 1), flush_n
-    )
-    use_mesh = mesh if wide else None
-    key = buckets.kzg_msm_key(flush_n, mesh=use_mesh)
-    obs.count("kzg.batches", 1)
+        shards = mesh_ops.shard_count(mesh)
+        flush_n = max(flush_n or n, n)
+        wide = shards > 1 and buckets.route_wide(
+            "kzg", buckets.kzg_lane_bucket(flush_n, 1), flush_n
+        )
+        use_mesh = mesh if wide else None
+        key = buckets.kzg_msm_key(flush_n, mesh=use_mesh)
+        obs.count("kzg.batches", 1)
     with buckets.first_dispatch(*key):
         a_pt, b_pt = msm_g1_many_device(
             [a_lanes[0], b_lanes[0]], [a_lanes[1], b_lanes[1]],
             mesh=use_mesh, pad_shape=(2, key[1]),
         )
-    setup = kzg.get_setup()
-    return _pairing_check_routed(
-        [(a_pt, -setup.g2_monomial[1]), (b_pt, g2_generator())], mesh=use_mesh
-    )
+    with waterfall.leg("kzg.pairing"):
+        setup = kzg.get_setup()
+        return _pairing_check_routed(
+            [(a_pt, -setup.g2_monomial[1]), (b_pt, g2_generator())], mesh=use_mesh
+        )
 
 
 def verify_blob_kzg_proof_batch_device(

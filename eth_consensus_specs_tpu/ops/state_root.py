@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from eth_consensus_specs_tpu import fault, obs
+from eth_consensus_specs_tpu.obs import waterfall
 from eth_consensus_specs_tpu.ops.merkle import tree_root_words
 from eth_consensus_specs_tpu.ops.sha256 import sha256_pair_words
 
@@ -510,9 +511,16 @@ def post_epoch_state_root(
         with obs.span(
             "state_root.post_epoch", work_bytes=96 * real, n_validators=meta.n_validators
         ) as sp:
-            sp.result = out = _compiled_state_root(meta)(
-                arrays, balances, effective_balance, inactivity_scores, just
-            )
+            # the call until it returns: argument transfer and enqueue
+            with waterfall.leg("state_root.launch"):
+                out = _compiled_state_root(meta)(
+                    arrays, balances, effective_balance, inactivity_scores, just
+                )
+            # no body: the leg's exit blocks on the root, where the span
+            # round both blocks anyway (with obs off neither does)
+            with waterfall.leg("state_root.wait") as wait:
+                wait.result = out
+            sp.result = out
         return out
 
     # device-side death (compile/OOM/injected) degrades to the host

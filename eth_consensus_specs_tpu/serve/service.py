@@ -67,7 +67,7 @@ import numpy as np
 
 from eth_consensus_specs_tpu import fault, obs
 from eth_consensus_specs_tpu.analysis import lockwatch
-from eth_consensus_specs_tpu.obs import devprof, trace, waterfall
+from eth_consensus_specs_tpu.obs import devprof, trace, waterfall, xprof
 from eth_consensus_specs_tpu.obs.histogram import Histogram
 from eth_consensus_specs_tpu.parallel import mesh_ops
 
@@ -97,6 +97,9 @@ class VerifyService:
         from eth_consensus_specs_tpu.utils.cache import enable_persistent_cache
 
         enable_persistent_cache()
+        # what XLA compiles from here on is filed under the leg it
+        # compiled in (xla.compile_ms.<leg>, the xla.compile event)
+        xprof.install_compile_listener()
         self.admission = AdmissionController(self.config.max_queue, self.config.max_bytes)
         self._batcher = MicroBatcher()
         # depth-2 hand-off: batch N+1's host prep overlaps batch N's
@@ -262,12 +265,17 @@ class VerifyService:
     def _batch_loop(self) -> None:
         _SERVICE_TLS.active = True
         while True:
-            flush = self._batcher.next_flush(
-                self.config.max_batch,
-                self.config.max_wait_s,
-                self._pressure,
-                self._idle if self.config.idle_flush else None,
-            )
+            # the batch thread's two states as spans, one a flush each: the
+            # queue and prep stages time them already, the spans put them on
+            # the profiler's clock, where a device-idle gap can be laid
+            # under the batcher's deadline or under host prep
+            with obs.span("serve.batch_wait"):
+                flush = self._batcher.next_flush(
+                    self.config.max_batch,
+                    self.config.max_wait_s,
+                    self._pressure,
+                    self._idle if self.config.idle_flush else None,
+                )
             if flush is None:
                 break
             reqs, reason = flush
@@ -302,7 +310,8 @@ class VerifyService:
                 # to this flush and its dispatch span
                 flows=[trace.to_wire(r.trace) for r in reqs if r.trace],
             )
-            self._prep(reqs)
+            with obs.span("serve.prep", batch=len(reqs)):
+                self._prep(reqs)
             waterfall.mark_all(reqs, "prepped")
             self._dispatch_q.put(reqs)  # blocks at pipeline depth 2
             # stamped AFTER the put so the handoff stage bills the
@@ -380,6 +389,9 @@ class VerifyService:
             t0 = time.monotonic()
             self._dispatch_busy = True
             waterfall.mark_all(live, "device_start")
+            # every waterfall.leg this thread runs until close_flush adds
+            # its milliseconds here; a degraded flush adds both attempts
+            legs = waterfall.open_flush()
             try:
                 # the dispatch span can't BELONG to the N requests it
                 # serves, so it runs under its own context and LINKS
@@ -392,25 +404,22 @@ class VerifyService:
                             trace.to_wire(r.trace) for r in live if r.trace
                         ),
                     ):
-                        # sampled jax.profiler window (off by default;
-                        # ETH_SPECS_OBS_DEVPROF=1 captures the first few
-                        # dispatches of the process)
-                        with devprof.trace_window("serve.dispatch"):
-                            results = fault.degrade(
-                                "serve.dispatch",
-                                lambda: self._execute(live, device=True),
-                                lambda: self._execute(live, device=False),
-                            )
+                        results = fault.degrade(
+                            "serve.dispatch",
+                            lambda: self._execute(live, device=True),
+                            lambda: self._execute(live, device=False),
+                        )
             except BaseException as exc:  # noqa: BLE001 — futures carry the error
                 for r in live:
                     self._resolve(r, exc=exc)
                 continue
             finally:
+                waterfall.close_flush()
                 self._dispatch_busy = False
             waterfall.mark_all(live, "device_done")
             per_req_s = (time.monotonic() - t0) / len(live)
             for r in live:
-                self._resolve(r, value=results[id(r)], service_s=per_req_s)
+                self._resolve(r, value=results[id(r)], service_s=per_req_s, legs=legs)
 
     def _execute(self, reqs: list[Request], device: bool) -> dict[int, object]:
         """Run one flush. ``device=True`` is the bucket-padded batched
@@ -629,7 +638,7 @@ class VerifyService:
 
     def _resolve(
         self, req: Request, value=None, exc: BaseException | None = None,
-        service_s: float | None = None,
+        service_s: float | None = None, legs: dict | None = None,
     ) -> None:
         self._release_once(req, service_s)
         waterfall.mark(req.stamps, "resolved")
@@ -640,6 +649,8 @@ class VerifyService:
         # blocked on fut.result() pops by trace id the instant it wakes,
         # and a pop that beats the stash ships the reply without stages
         durations = waterfall.stage_durations_ms(req.t_submit, req.stamps)
+        # the legs of the flush that served it split its device stage
+        waterfall.add_legs(durations, legs)
         # the slot pipeline's three phase walls (slot.verify /
         # slot.aggregate / slot.reroot) ride the SAME stage histograms
         # and the same per-trace stash the replica wire ships

@@ -438,6 +438,38 @@ def test_obs_discipline_compile_ms_undeclared(tmp_path):
     )
 
 
+def test_obs_discipline_leg_call_sites(tmp_path):
+    """waterfall.leg(name) is a span of that name and keys two histogram
+    families at the call site: a leg the catalog lacks a span row for is
+    a finding, a declared one is none."""
+    from eth_consensus_specs_tpu.obs import catalog
+
+    findings = _lint(
+        tmp_path,
+        {
+            "mod.py": """\
+            from eth_consensus_specs_tpu.obs import waterfall
+
+            def f(x):
+                with waterfall.leg("kzg.brp"):
+                    pass
+                with waterfall.leg("kzg.no_such_leg"):
+                    pass
+                with waterfall.leg("Bad-Leg"):
+                    pass
+            """,
+        },
+        {"obs-discipline"},
+        catalog=catalog,  # the program's own: kzg.brp is declared there
+    )
+    assert sorted(f.symbol for f in findings) == [
+        "grammar:Bad-Leg",
+        "grammar:serve.stage_ms.device.Bad-Leg",
+        "grammar:xla.compile_ms.Bad-Leg",
+        "undeclared:kzg.no_such_leg",
+    ]
+
+
 # ------------------------------------------------------------ env-registry --
 
 

@@ -1,4 +1,5 @@
-"""Request waterfall (obs/waterfall.py), device-time profiling
+"""Request waterfall (obs/waterfall.py) with its legs and the compile
+listener that files under them (obs/xprof.py), device-time profiling
 (obs/devprof.py), and the HBM residency ledger (obs/ledger.py).
 
 The tier-1 acceptance story: stamp vectors stay monotone through a real
@@ -7,7 +8,9 @@ durations tile the e2e wall with unattributed time as a first-class
 ``other`` stage, the cross-process stash reconstructs one waterfall per
 trace id on the client side, the ledger's books match live buffer sizes
 through register/donate/delete, and everything is a safe no-op under
-``ETH_SPECS_OBS=0``.
+``ETH_SPECS_OBS=0``. Legs: the legs of a flush plus ``device.other`` equal
+its device stage, a leg outside a flush is a plain span, ledgers are the
+dispatch thread's own, and what XLA compiles is filed under the open leg.
 """
 
 from __future__ import annotations
@@ -265,8 +268,6 @@ def test_devprof_noop_when_obs_disabled(monkeypatch):
         with devprof.measure("merkle_many", work_bytes=10**15):
             pass
         assert devprof.record("merkle_many", 1.0, work_bytes=10**15) is None
-        with devprof.trace_window("merkle_many") as active:
-            assert active is False
         reg = registry_mod.get_registry()
         assert reg.counters == {} and reg.histograms == {}
         # the ledger's internal books stay live (tests rely on exact
@@ -279,21 +280,188 @@ def test_devprof_noop_when_obs_disabled(monkeypatch):
         assert registry_mod.refresh_enabled() is True
 
 
-def test_devprof_trace_window_gating(monkeypatch, tmp_path):
-    # off by default — no env, no window
-    with devprof.trace_window("merkle_many") as active:
-        assert active is False
-    # enabled: bounded by ETH_SPECS_OBS_DEVPROF_WINDOWS per process
-    monkeypatch.setenv("ETH_SPECS_OBS_DEVPROF", "1")
-    monkeypatch.setenv("ETH_SPECS_OBS_DEVPROF_WINDOWS", "1")
-    monkeypatch.setenv("ETH_SPECS_OBS_DEVPROF_DIR", str(tmp_path / "traces"))
-    with devprof.trace_window("merkle_many") as first:
-        pass
-    with devprof.trace_window("merkle_many") as second:
-        assert second is False  # budget spent
+# -------------------------------------------------------------------- legs --
+
+
+class _StubbedService(serve.VerifyService):
+    """A service whose `_execute` is the test's: legs round sleeps, no
+    kernel and so no compile. Everything round it (batch thread, dispatch
+    loop, ledger, resolve) is the program's own."""
+
+    def __init__(self, body, name="serve"):
+        self._body = body
+        super().__init__(ServeConfig.from_env(max_batch=4, max_wait_ms=5), name=name)
+
+    def _execute(self, reqs, device):
+        self._body(self, device)
+        return {id(r): b"\x00" * 32 for r in reqs}
+
+
+def _submit_traced(svc, chunks):
+    """One request under a trace context of its own: (result, the stage
+    durations `_resolve` stashed for it)."""
+    ctx = trace.new_trace()
+    with trace.activate(ctx):
+        fut = svc.submit_hash_tree_root(chunks)
+    fut.result(timeout=30)
+    return waterfall.pop(ctx.trace_id)
+
+
+def _sleep_ms(ms):
+    import time
+
+    time.sleep(ms / 1e3)
+
+
+def test_legs_and_device_other_tile_the_device_stage(trees):
+    def body(svc, device):
+        with waterfall.leg("t.first"):
+            _sleep_ms(20)
+        for _ in range(3):  # a leg entered again adds up over the flush
+            with waterfall.leg("t.again"):
+                _sleep_ms(5)
+        _sleep_ms(10)  # under no leg: device.other
+
+    with _StubbedService(body) as svc:
+        stages = _submit_traced(svc, trees[0])
+    assert stages["device.t.first"] >= 20 and stages["device.t.again"] >= 15
+    assert stages["device.other"] >= 10
+    legs = [v for k, v in stages.items() if k.startswith("device.")]
+    assert sum(legs) == pytest.approx(stages["device"])
+    hists = obs.snapshot()["histograms"]
+    for name in ("device.t.first", "device.t.again", "device.other"):
+        assert hists[f"serve.stage_ms.{name}"]["count"] == 1
+        assert hists[f"serve.stage_ms.{name}"]["sum"] == pytest.approx(stages[name])
+    spans = obs.snapshot()["spans"]
+    assert spans["t.again"]["count"] == 3 and spans["t.again"]["parent"] == "serve.dispatch"
+    # the batch thread's two spans, one a flush each (and the wait that
+    # close ends)
+    assert spans["serve.prep"]["count"] == 1 and spans["serve.batch_wait"]["count"] >= 1
+
+
+def test_a_flush_without_legs_is_all_device_other(trees):
+    with _StubbedService(lambda svc, device: _sleep_ms(5)) as svc:
+        stages = _submit_traced(svc, trees[0])
+    assert [k for k in stages if k.startswith("device.")] == ["device.other"]
+    assert stages["device.other"] == pytest.approx(stages["device"])
+
+
+def test_leg_outside_a_flush_is_a_plain_span():
+    with waterfall.leg("t.solo", items=3) as sp:
+        assert waterfall.current_leg() == "t.solo"
+        sp.result = None
+    assert waterfall.current_leg() is None
     snap = obs.snapshot()
-    if first:
-        assert snap["counters"].get("device.devprof.windows", 0) == 1
-    else:
-        # backend without a working profiler: counted no-op, never a raise
-        assert snap["counters"].get("device.devprof.unavailable", 0) >= 1
+    assert snap["spans"]["t.solo"]["count"] == 1
+    assert not [h for h in snap["histograms"] if h.startswith("serve.stage_ms.device")]
+
+
+def test_a_leg_inside_a_leg_is_a_plain_span():
+    ledger = waterfall.open_flush()
+    try:
+        with waterfall.leg("t.outer"):
+            with waterfall.leg("t.inner"):
+                assert waterfall.current_leg() == "t.inner"
+                _sleep_ms(2)
+    finally:
+        waterfall.close_flush()
+    assert list(ledger) == ["t.outer"] and ledger["t.outer"] >= 2
+    assert obs.snapshot()["spans"]["t.inner"]["parent"] == "t.outer"
+
+
+def test_leg_noop_when_obs_disabled(monkeypatch):
+    from eth_consensus_specs_tpu.obs import registry as registry_mod
+
+    monkeypatch.setenv("ETH_SPECS_OBS", "0")
+    assert registry_mod.refresh_enabled() is False
+    ledger = waterfall.open_flush()
+    try:
+        with waterfall.leg("t.off") as sp:
+            sp.result = 1
+            assert waterfall.current_leg() is None
+        assert isinstance(sp, registry_mod._NullSpan) and ledger == {}
+        reg = registry_mod.get_registry()
+        assert reg.spans == {} and reg.histograms == {} and reg.events == []
+    finally:
+        waterfall.close_flush()
+        monkeypatch.setenv("ETH_SPECS_OBS", "1")
+        assert registry_mod.refresh_enabled() is True
+
+
+def test_two_services_keep_separate_ledgers(trees):
+    """Both dispatch threads are inside a leg at the same moment (the
+    barrier), and each request's stages hold its own service's leg alone."""
+    import threading
+
+    both_inside = threading.Barrier(2, timeout=20)
+
+    def body(svc, device):
+        with waterfall.leg(f"t.{svc.name}"):
+            both_inside.wait()
+            _sleep_ms(5)
+
+    with _StubbedService(body, name="one") as one, _StubbedService(body, name="two") as two:
+        got: dict = {}
+        threads = [
+            threading.Thread(target=lambda s=s: got.__setitem__(s.name, _submit_traced(s, trees[0])))
+            for s in (one, two)
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+            assert not t.is_alive()
+    for mine, other in (("one", "two"), ("two", "one")):
+        assert got[mine][f"device.t.{mine}"] >= 5
+        assert f"device.t.{other}" not in got[mine]
+        assert got[mine][f"device.t.{mine}"] + got[mine]["device.other"] == pytest.approx(
+            got[mine]["device"])
+
+
+def test_a_degraded_flush_adds_every_attempt(trees):
+    """`fault.degrade` runs the device path twice (one retry) and then the
+    host path: one ledger, the three attempts added."""
+    calls = []
+
+    def body(svc, device):
+        calls.append(device)
+        with waterfall.leg("t.attempt"):
+            _sleep_ms(10)
+            if device:
+                raise MemoryError("the device gave out")
+
+    with _StubbedService(body) as svc:
+        stages = _submit_traced(svc, trees[0])
+    assert calls == [True, True, False]
+    assert stages["device.t.attempt"] >= 30
+    assert stages["device.t.attempt"] + stages["device.other"] == pytest.approx(stages["device"])
+    # a leg whose body raised is in the ledger (the time was spent) though
+    # not among the span aggregates, as for any span
+    assert obs.snapshot()["spans"]["t.attempt"]["count"] == 1
+
+
+def test_compile_listener_files_a_compile_under_the_open_leg():
+    import jax
+
+    from eth_consensus_specs_tpu.obs import xprof
+
+    xprof.install_compile_listener()
+    xprof.install_compile_listener()  # once a process: no second listener
+    x = np.arange(7.0)  # a host array: making it compiles nothing
+
+    def fresh_under_leg(v):
+        return v * 3.0 + 0.125
+
+    def fresh_outside(v):
+        return v * 5.0 - 0.375
+
+    with waterfall.leg("t.compiling"):
+        jax.jit(fresh_under_leg)(x).block_until_ready()
+    jax.jit(fresh_outside)(x).block_until_ready()
+    hists = obs.snapshot()["histograms"]
+    assert hists["xla.compile_ms.t.compiling"]["count"] == 1
+    assert hists["xla.compile_ms.none"]["count"] == 1
+    events = [e for e in obs.get_registry().events if e["kind"] == "xla.compile"]
+    assert [(e["leg"], "fresh_under_leg" in e["fun_name"], "fresh_outside" in e["fun_name"])
+            for e in events] == [("t.compiling", True, False), ("none", False, True)]
+    assert all(e["ms"] > 0 and e["cache_hit"] is False for e in events)
