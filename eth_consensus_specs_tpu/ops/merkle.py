@@ -1,12 +1,15 @@
 """Whole-subtree SSZ merkleization on device — ONE dispatch per tree.
 
-A tree's hashes take milliseconds on the device, so the fixed cost of a
-dispatch is what a level-per-call reduction would pay ~35 times over (not
-measured on the chip for today's code). So the whole binary reduction runs
-as a single jitted call: a `lax.fori_loop` over the levels carrying a
-fixed-width node buffer (see tree_root_words — d/2 times the exact
-tree's work, bought for a 35x drop in dispatch count and ONE compression
-body in the graph; rounds unrolled on TPU, see ops/sha256.py).
+The whole binary reduction runs as a single jitted call: a
+`lax.fori_loop` over the levels of an in-place node buffer with ONE
+compression body in the graph (rounds unrolled on TPU, see
+ops/sha256.py), where a level-per-call reduction would pay a dispatch,
+and an unrolled one a several-second compile, at every level. A deep
+tree's hashes are no small matter on the chip: hashed at the first
+level's width every level, the three deep trees of a 2^20 state root
+took 677 ms of its 850 on one v5e (ledger, PR 25). So a tree wider than
+a tile hashes each level at its live width, tile by tile (see
+tree_root_words: 1.04x the exact tree's work at depth 20, not d/2 x).
 
 Hot state lives device-resident between calls (ops/state_columns.py); the
 host-chunk entry below is for one-shot roots.
@@ -35,54 +38,96 @@ from eth_consensus_specs_tpu.obs import watchdog, xprof
 from .sha256 import sha256_pair_words
 
 
-def tree_real_hashes(depth: int) -> int:
-    """Compressions tree_root_words actually executes at `depth` — the
-    honest work count for bench roofline/throughput accounting: every
-    level hashes the fixed 2^(d-1)-row buffer."""
-    return depth << (depth - 1) if depth else 0
+# Rows a tile of the level loop hashes (tree_root_words). A power of two,
+# at least 128 so that the tile's sha keeps its unrolled rounds
+# (sha256.SMALL_BATCH). PERF.md section 6, PR 26, has the sweep on one v5e.
+TILE_ROWS = 1 << 12
 
 
-def tree_root_words(leaves: jnp.ndarray, depth: int) -> jnp.ndarray:
+def tree_real_hashes(depth: int, tile_rows: int = TILE_ROWS) -> int:
+    """Compressions tree_root_words executes at `depth`: what the span's
+    work count and `state_root.real_hashes` report (what the algorithm
+    NEEDS is benchmark/needed.py's count). A tree of at most `tile_rows`
+    pairs hashes its first level's width at every level, d * 2^(d-1); a
+    wider one hashes each level's live width, never less than one tile."""
+    if depth == 0:
+        return 0
+    w = 1 << (depth - 1)
+    if w <= tile_rows:
+        return depth * w
+    return sum(max(w >> level, tile_rows) for level in range(depth))
+
+
+def tree_root_words(
+    leaves: jnp.ndarray, depth: int, tile_rows: int = TILE_ROWS
+) -> jnp.ndarray:
     """Traceable tree reduction: uint32[2**depth, 8] -> uint32[8] root.
 
-    ONE compression body: a ``fori_loop`` over the levels hashes a
-    fixed-width [2^(d-1), 16] buffer whose live rows halve each level
-    (the spent tail hashes garbage that never reaches a live node). That
-    is d*2^(d-1) compressions for a tree of 2^d - 1: d/2 times the exact
-    work, ~10x at depth 20, still milliseconds there. Unrolling the
-    widest levels at exact widths buys that work back (six levels: 1.09x
-    exact at depth 20), but every unrolled level is one more compression
-    body for the chip's compiler, 5 to 10 s each compiled for a v5e, in
-    EVERY program that roots a deep tree — the full state root has four
-    such trees, ~190 s of cold start at six levels (PERF.md, PR 22). What
-    the extra hashing costs at run time is not measured on the chip; the
-    benchmark has to show it before a level is unrolled.
+    ONE compression body in a ``fori_loop`` over the levels of an
+    in-place [2^d, 8] node buffer whose live rows halve each level and
+    stay at its front (the spent tail holds garbage that never reaches a
+    live node). Which loop is chosen from the shape alone:
 
-    Plain function so it composes under outer jits / shard_map (the
-    sharded tree in parallel/merkle.py reduces local subtrees with this,
-    then all-gathers the per-device roots)."""
+    * at most `tile_rows` pairs at the first level: every level hashes
+      the whole [2^(d-1), 16] buffer, d * 2^(d-1) compressions for a tree
+      of 2^d - 1;
+    * wider: level l hashes its live 2^(d-1-l) pairs in tiles of
+      `tile_rows`, one tile where fewer are live. Tile j reads rows
+      [2jT, 2jT + 2T) and writes rows [jT, jT + T); every earlier tile
+      wrote below row jT, so in place is safe. 1.04x the exact tree at
+      depth 20 where the whole-width loop is 10x.
+
+    On one v5e the whole-width loop cost the 2^20 state root 677 of its
+    850 ms (511 ms the registry tree, 83 ms each 2^18-chunk tree; ledger,
+    PR 25); the tile loop takes 21.6 and 6.1 ms there, a tile's rows
+    and its sha staying in the chip's fast memory (PERF.md, PR 26).
+    Unrolling levels at exact widths would buy the same work back at
+    one more compression body a level for the chip's compiler, 5 to
+    10 s each compiled for a v5e, in EVERY program that roots a deep
+    tree (PERF.md, PR 22); the tile loop keeps the one body.
+
+    Plain function so it composes under outer jits / vmap / shard_map
+    (the trip counts depend on the level alone; the sharded tree in
+    parallel/merkle.py reduces local subtrees with this, then
+    all-gathers the per-device roots)."""
     if depth == 0:
         return leaves[0]
     w = leaves.shape[0] // 2
+    # i32 loop bounds and indices: python-int bounds widen the counter to
+    # i64 under the package-wide x64 flag — the jaxlint x64-drift rule
+    # keeps this kernel's jaxpr pure 32-bit
+    zero = jnp.int32(0)
+    if w <= tile_rows:
 
-    def level(_, b):
-        h = sha256_pair_words(b.reshape(w, 16))
-        return jnp.concatenate([h, jnp.zeros_like(h)], axis=0)
+        def level(_, b):
+            h = sha256_pair_words(b.reshape(w, 16))
+            return jnp.concatenate([h, jnp.zeros_like(h)], axis=0)
 
-    # i32 loop bounds: python-int bounds widen the counter to i64 under
-    # the package-wide x64 flag — the jaxlint x64-drift rule keeps this
-    # kernel's jaxpr pure 32-bit
-    return lax.fori_loop(jnp.int32(0), jnp.int32(depth), level, leaves)[0]
+    else:
+        t = jnp.int32(tile_rows)
+
+        def tile(j, b):
+            pairs = lax.dynamic_slice(b, (2 * j * t, zero), (2 * tile_rows, 8))
+            h = sha256_pair_words(pairs.reshape(tile_rows, 16))
+            return lax.dynamic_update_slice(b, h, (j * t, zero))
+
+        def level(lvl, b):
+            tiles = jnp.maximum((jnp.int32(w) >> lvl) // t, jnp.int32(1))
+            return lax.fori_loop(zero, tiles, tile, b)
+
+    return lax.fori_loop(zero, jnp.int32(depth), level, leaves)[0]
 
 
 _tree_root_fused = partial(jax.jit, static_argnums=(1,))(tree_root_words)
 
 
-def many_tree_root_words(leaves: jnp.ndarray, depth: int) -> jnp.ndarray:
+def many_tree_root_words(
+    leaves: jnp.ndarray, depth: int, tile_rows: int = TILE_ROWS
+) -> jnp.ndarray:
     """Batched tree reduction: uint32[B, 2**depth, 8] -> uint32[B, 8]
     roots, ONE dispatch for B independent subtrees (the serving layer's
     bucket-padded flush shape — compiles once per (B, depth))."""
-    return jax.vmap(lambda level: tree_root_words(level, depth))(leaves)
+    return jax.vmap(lambda level: tree_root_words(level, depth, tile_rows))(leaves)
 
 
 _many_tree_root_fused = partial(jax.jit, static_argnums=(1,))(many_tree_root_words)

@@ -106,6 +106,13 @@ def inc_update_hashes(depth: int, cap: int, leaf_hashes: int = 0) -> int:
     return cap * (depth + leaf_hashes)
 
 
+def build_levels_hashes(depth: int) -> int:
+    """Compressions build_levels executes at `depth`: every level at the
+    width of the first (unlike ops/merkle.tree_root_words on a deep tree;
+    no benchmark cell prices the forest builder yet)."""
+    return depth << (depth - 1) if depth else 0
+
+
 def build_levels(leaves: jnp.ndarray) -> jnp.ndarray:
     """u32[..., 2^d, 8] leaves -> u32[..., 2^(d+1)-1, 8] all levels,
     leaves first, root last (traceable, batched over leading dims; the

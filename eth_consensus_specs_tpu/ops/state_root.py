@@ -458,19 +458,19 @@ def synthetic_static(spec, n: int, seed: int = 0) -> tuple[StateRootArrays, Stat
 
 def state_root_real_hashes(meta: StateRootMeta) -> int:
     """Compressions one post_epoch_state_root evaluation executes — the
-    honest work count for the span's roofline verdict (mirrors bench.py's
-    resident accounting: validator nodes + full-width column trees)."""
-    from eth_consensus_specs_tpu.ops.merkle import tree_real_hashes as fullwidth
+    honest work count for the span's roofline verdict: validator nodes +
+    each column tree as merkle.tree_root_words walks it."""
+    from eth_consensus_specs_tpu.ops.merkle import tree_real_hashes
 
     n = meta.n_validators
     names = {name for _, name in meta.dynamic_slots}
-    hashes = 3 * n + fullwidth(max(n - 1, 0).bit_length())  # validator subtrees + registry
+    hashes = 3 * n + tree_real_hashes(max(n - 1, 0).bit_length())  # validator subtrees + registry
     d_bal = (max(n // 4, 1) - 1).bit_length()
-    hashes += fullwidth(d_bal)  # balances
+    hashes += tree_real_hashes(d_bal)  # balances
     if "inactivity_scores" in names:
-        hashes += fullwidth(d_bal)
+        hashes += tree_real_hashes(d_bal)
     if "previous_epoch_participation" in names:
-        hashes += fullwidth((max(n // 32, 1) - 1).bit_length())
+        hashes += tree_real_hashes((max(n // 32, 1) - 1).bit_length())
     return hashes + (1 << meta.top_depth)
 
 
@@ -479,11 +479,11 @@ def slot_root_real_hashes(n: int, top_depth: int) -> int:
     participation columns + the top tree) — ONE accounting shared by the
     block_epoch span instrumentation and bench.py's block_epoch section,
     so their roofline verdicts can never disagree on the same timing."""
-    from eth_consensus_specs_tpu.ops.merkle import tree_real_hashes as fullwidth
+    from eth_consensus_specs_tpu.ops.merkle import tree_real_hashes
 
     return (
-        fullwidth((max(n // 4, 1) - 1).bit_length())
-        + 2 * fullwidth((max(n // 32, 1) - 1).bit_length())
+        tree_real_hashes((max(n // 4, 1) - 1).bit_length())
+        + 2 * tree_real_hashes((max(n // 32, 1) - 1).bit_length())
         + (1 << top_depth)
     )
 
@@ -831,7 +831,6 @@ def state_root_inc_real_hashes(meta: StateRootMeta, plan: ForestPlan) -> int:
     work). Folds, length mixes, checkpoints, and the top combine are
     counted exactly like state_root_real_hashes."""
     from eth_consensus_specs_tpu.ops import merkle_inc
-    from eth_consensus_specs_tpu.ops.merkle import tree_real_hashes as fullwidth
 
     n = meta.n_validators
     s = plan.shards
@@ -839,7 +838,7 @@ def state_root_inc_real_hashes(meta: StateRootMeta, plan: ForestPlan) -> int:
 
     def tree_cost(depth: int, cap: int, leaf_hashes: int, dense_leaf_total: int) -> int:
         sparse = s * merkle_inc.inc_update_hashes(depth - slog2, cap, leaf_hashes)
-        dense = fullwidth(depth - slog2) * s + dense_leaf_total
+        dense = merkle_inc.build_levels_hashes(depth - slog2) * s + dense_leaf_total
         return min(sparse, dense) + max(s - 1, 0)  # + the top combine
 
     hashes = tree_cost(plan.depth_val, plan.cap_val, 3, 3 * n)

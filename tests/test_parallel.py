@@ -95,9 +95,9 @@ def test_sharded_altair_epoch_bit_exact():
     )
 
 
-def test_sharded_tree_root_matches_fused():
+@pytest.mark.parametrize("depth", [12, 16])  # 16: local subtrees wider than a tile
+def test_sharded_tree_root_matches_fused(depth):
     mesh = _mesh()
-    depth = 12
     rng = np.random.default_rng(3)
     leaves = jnp.asarray(
         rng.integers(0, 2**32, (1 << depth, 8), dtype=np.uint64).astype(np.uint32)

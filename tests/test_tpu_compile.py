@@ -106,6 +106,24 @@ def test_forest_dense_build_at_2_20_is_one_compression_body(one_chip, no_compile
     assert row["output_bytes"] >= (2 * n - 1) * 32
 
 
+def test_tree_root_at_depth_20_compiles_with_its_tile_loop(one_chip, no_compile_cache):
+    """The registry tree of a 2^20 state root: the level loop with the tile
+    loop inside it, dynamic row offsets into the in-place node buffer, one
+    unrolled sha body of merkle.TILE_ROWS rows."""
+    from eth_consensus_specs_tpu.ops import merkle
+
+    depth = 20
+    assert (1 << (depth - 1)) > merkle.TILE_ROWS
+    prog = chip_programs.Program(
+        "tree_root_2_20",
+        lambda: (
+            jax.jit(lambda leaves: merkle.tree_root_words(leaves, depth)),
+            (_u32((1 << depth, 8), None),),
+        ),
+    )
+    _check_row(chip_programs.compile_for(one_chip, prog))
+
+
 def test_forest_path_update_at_2_20_compiles(one_chip, no_compile_cache):
     """The incremental re-root's sparse leg at the registry's depth and the
     forest plan's dirty capacity: gather, one [cap, 16] sha body, scatter."""
