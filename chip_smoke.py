@@ -354,7 +354,12 @@ def phase_slots(svc, sizes: Sizes, world_h: HostWorld, seed: int) -> None:
 
 def expected_compile_keys(sizes: Sizes, svc) -> set:
     """The serve compile keys the phases above may first-dispatch: one a
-    family (scripts/tpu_compile_inventory.py compiles exactly these)."""
+    family (scripts/tpu_compile_inventory.py compiles exactly these). The
+    BLS legs add none: the slot world hands `verify_many` its keys as bytes
+    and no registry, so the served routing (ops/bls_batch.py) sums the
+    committees in the C core; a service that was handed its registry
+    dispatches ("bls_keysum", items, lanes, keys) for a bucket that
+    `precompile` has warmed (benchmark cell block_atts_128.verify)."""
     from eth_consensus_specs_tpu.ops.kzg_batch import N_BLOB
     from eth_consensus_specs_tpu.ops.slot_pipeline import slot_spec
     from eth_consensus_specs_tpu.ops.state_root import (

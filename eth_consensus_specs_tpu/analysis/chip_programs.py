@@ -155,11 +155,20 @@ def slot_programs(
         key = buckets.g2_agg_key(1, committees)
         return g2_aggregate.g2_sum_many_kernel, kernels._g2_agg_args(key[1], key[2])
 
-    def bls_msm():
+    def bls_keysum():
+        # the served committee sums (ops/bls_batch._served_pubkey_terms):
+        # registry indices gathered from the resident key table and summed,
+        # at the bucket a full block's 2 x committees aggregates land in
         from eth_consensus_specs_tpu.ops import g1_msm
 
-        key = buckets.bls_msm_key(committees + 1, max(committee_size, sync_size))
-        return g1_msm.sum_many_kernel, kernels._bls_msm_args(key[1], key[2])
+        items, lanes = g1_msm.many_sum_shape(2 * committees, committee_size)
+        table = kernels._sds((n_validators, g1_msm.N_LIMBS), "uint64")
+        fn = jax.jit(
+            lambda tx, ty, index: g1_msm.sum_indexed_kernel(
+                tx, ty, index, strip=g1_msm.KEY_SUM_STRIP
+            )
+        )
+        return fn, (table, table, kernels._sds((items, lanes), "int32"))
 
     from eth_consensus_specs_tpu.ops import sha256
 
@@ -174,7 +183,7 @@ def slot_programs(
         Program("fr_fft", fr_fft),
         Program("kzg", kzg_msm, limb=True),
         Program("g2_agg", g2_agg, limb=True),
-        Program("bls_msm", bls_msm, limb=True),
+        Program("bls_keysum", bls_keysum, limb=True),
     ]
 
 

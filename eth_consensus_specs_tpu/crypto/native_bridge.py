@@ -146,6 +146,42 @@ def g1_aggregate(points) -> tuple[int, int] | None:
     return _g1_out(out, inf)
 
 
+def g1_aggregate_affine(points: bytes) -> tuple[int, int] | None:
+    """Sum of affine points already in the core's own form (96 bytes
+    each, none at infinity), as a resident key table keeps them."""
+    n = len(points) // 96
+    out = (ctypes.c_uint8 * 96)()
+    inf = ctypes.c_uint8()
+    get_bls_lib().bls_g1_aggregate(n, _buf(points), _buf(bytes(n)), out, ctypes.byref(inf))
+    return _g1_out(out, inf)
+
+
+def g1_key_validate_many(keys: bytes) -> tuple[bytes, int]:
+    """KeyValidate of the 48-byte compressed public keys laid end to end:
+    (their 96-byte affine points end to end, the place of the first key
+    that fails or the number of keys when none does). A registry's worth
+    is shared out over threads (the core runs without the GIL)."""
+    import os
+    from concurrent.futures import ThreadPoolExecutor
+
+    lib = get_bls_lib()
+    n = len(keys) // 48
+    out = ctypes.create_string_buffer(96 * n)
+    src = ctypes.create_string_buffer(keys, len(keys))
+    base_in, base_out = ctypes.addressof(src), ctypes.addressof(out)
+    step = max(-(-n // (os.cpu_count() or 1)), 256)
+    starts = range(0, n, step)
+
+    def run(at: int) -> int:
+        count = min(step, n - at)
+        return at + lib.bls_g1_key_validate_many(count, base_in + 48 * at, base_out + 96 * at)
+
+    with ThreadPoolExecutor(max_workers=len(starts) or 1) as pool:
+        ends = list(pool.map(run, starts))
+    bad = min((e for at, e in zip(starts, ends) if e < min(at + step, n)), default=n)
+    return out.raw, bad
+
+
 def g2_aggregate(points):
     lib = get_bls_lib()
     n = len(points)

@@ -9,6 +9,8 @@ SkToPk. Byte formats are the standard 48/96-byte compressed encodings.
 
 from __future__ import annotations
 
+import threading
+
 from .curve import (
     Point,
     g1_from_bytes,
@@ -50,9 +52,22 @@ def key_validate(pk_bytes: bytes) -> bool:
 # cost of every verification, and validator pubkeys repeat constantly —
 # the reference leans on milagro doing this in C; we add a bounded cache on
 # top of the native path (same effect as the reference's LRU-cached
-# committee pipelines keeping pk objects alive).
+# committee pipelines keeping pk objects alive). The bound holds a mainnet
+# registry twice over, and a full cache drops its OLDEST key: a block
+# carries 2^16 keys of a registry that cycles in 32 blocks, so a cache
+# that held 2^16 and cleared itself when full decompressed every block's
+# keys again. (A service that was handed its registry does not come here:
+# ops/key_table.py.)
 _PK_CACHE: dict[bytes, Point | None] = {}
-_PK_CACHE_MAX = 1 << 16
+_PK_CACHE_MAX = 1 << 21
+# keys the calling thread has decompressed so far (cache misses), in
+# `.count`: read before and after a batch by a caller that wants to know
+# whether the batch decoded any
+_PK_DECODES = threading.local()
+
+
+def pk_decodes() -> int:
+    return getattr(_PK_DECODES, "count", 0)
 
 
 def _load_pk(pk_bytes: bytes) -> Point | None:
@@ -67,6 +82,7 @@ def _load_pk(pk_bytes: bytes) -> Point | None:
         hit = _PK_CACHE.get(key, False)
         if hit is not False:
             return hit
+    _PK_DECODES.count = pk_decodes() + 1
     try:
         p = g1_from_bytes(key)
     except ValueError:
@@ -75,7 +91,7 @@ def _load_pk(pk_bytes: bytes) -> Point | None:
         p = None
     if use_cache:
         if len(_PK_CACHE) >= _PK_CACHE_MAX:
-            _PK_CACHE.clear()
+            del _PK_CACHE[next(iter(_PK_CACHE))]
         _PK_CACHE[key] = p
     return p
 

@@ -1480,6 +1480,49 @@ int bls_g1_in_subgroup(const uint8_t in[96]) {
     return g1_is_inf(&r);
 }
 
+/* KeyValidate of n compressed public keys, as crypto/curve.g1_from_bytes
+ * decides it (compressed flag, x < p, on the curve, the sign flag, in the
+ * subgroup) with infinity refused besides: out takes the 96-byte affine
+ * points. Returns the place of the first key that fails, n when none
+ * does. One call a slice of a registry, so that threads share the work. */
+uint64_t bls_g1_key_validate_many(uint64_t n, const uint8_t *in, uint8_t *out) {
+    ensure_init();
+    uint8_t pbe[48], halfbe[48];
+    uint64_t half[6];
+    for (int i = 0; i < 6; i++) {
+        half[i] = FP_P[i] >> 1;
+        if (i < 5) half[i] |= FP_P[i + 1] << 63;
+    }
+    for (int i = 0; i < 6; i++)
+        for (int j = 0; j < 8; j++) {
+            pbe[48 - 1 - (8 * i + j)] = (uint8_t)(FP_P[i] >> (8 * j));
+            halfbe[48 - 1 - (8 * i + j)] = (uint8_t)(half[i] >> (8 * j));
+        }
+    for (uint64_t k = 0; k < n; k++) {
+        const uint8_t *key = in + 48 * k;
+        int flags = key[0];
+        if (!(flags & 0x80) || (flags & 0x40)) return k;
+        uint8_t xb[48], yb[48];
+        memcpy(xb, key, 48);
+        xb[0] &= 0x1F;
+        if (memcmp(xb, pbe, 48) >= 0) return k;
+        fp x, y, y2, four;
+        fp_from_be(&x, xb);
+        fp_sqr(&y2, &x);
+        fp_mul(&y2, &y2, &x);
+        fp_one(&four);
+        fp_add(&four, &four, &four);
+        fp_add(&four, &four, &four);
+        fp_add(&y2, &y2, &four);
+        if (!fp_sqrt(&y, &y2)) return k;
+        fp_to_be(yb, &y);
+        if ((memcmp(yb, halfbe, 48) > 0) != ((flags & 0x20) ? 1 : 0)) fp_neg(&y, &y);
+        g1_store(out + 96 * k, &x, &y);
+        if (!bls_g1_in_subgroup(out + 96 * k)) return k;
+    }
+    return n;
+}
+
 /* psi(x, y) = (conj(x) * PSI_X, conj(y) * PSI_Y) on E'(Fp2). */
 static void g2_psi(fp2 *rx, fp2 *ry, const fp2 *x, const fp2 *y) {
     fp2 cx, cy;

@@ -114,6 +114,15 @@ CATALOG: tuple[Metric, ...] = (
     _s("bls.batch_verify", "batched RLC aggregate verification"),
     _s("bls.fast_aggregate_verify", "single FastAggregateVerify"),
     _s("bls.verify_many", "multi-item verify_many with bisection"),
+    _h("bls.rlc_check_ms", "one random-linear-combination pairing check, ms (a sample a check)"),
+    _h("bls.key_decode_ms",
+       "the parse of a served flush in which a public key was decompressed, ms"),
+    _s("bls.keys", "leg: a flush's signers to registry indices or points, signatures decompressed"),
+    _s("bls.g1_sum.call", "leg: host clock round the committee sums (synced device call or C core)"),
+    _s("bls.g1_sum.unpack", "leg: sums to affine, the 64-bit RLC multiply of each"),
+    _s("bls.h2c", "leg: hash-to-G2 of an RLC check's distinct messages"),
+    _s("bls.g2_fold", "leg: sum of r_i * sig_i of one RLC check"),
+    _s("bls.pairing", "leg: the pairing check of one RLC check"),
     # ---------------------------------------------------------------- agg --
     _c("agg.committees", "committee contributions aggregated (tier 0)"),
     _c("agg.signatures", "member signatures through the committee tree"),

@@ -31,6 +31,7 @@ context the per-span overhead is one thread-local read.
 from __future__ import annotations
 
 import os
+import random
 import threading
 from dataclasses import dataclass
 
@@ -44,8 +45,16 @@ class TraceContext:
     parent_id: str | None = None  # the parent span's span_id
 
 
+# ids name spans, they guard nothing: a generator seeded from the OS once
+# (and again in a forked child, as `random` does for its own) and no
+# system call an id. `os.urandom` here released the GIL twice in every
+# submit, to whichever service thread was waiting for it.
+_ids = random.Random()
+os.register_at_fork(after_in_child=_ids.seed)
+
+
 def _new_id(nbytes: int) -> str:
-    return os.urandom(nbytes).hex()
+    return f"{_ids.getrandbits(8 * nbytes):0{2 * nbytes}x}"
 
 
 def _stack() -> list:

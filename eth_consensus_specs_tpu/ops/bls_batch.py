@@ -23,13 +23,18 @@ attestation surfaces at the exact spec assertion.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import secrets
 import threading
+import time
+
+import numpy as np
 
 from eth_consensus_specs_tpu import obs
 from eth_consensus_specs_tpu.analysis import lockwatch
 from eth_consensus_specs_tpu.crypto.curve import (
+    B1,
     Point,
     g1_generator,
     g1_infinity,
@@ -37,7 +42,7 @@ from eth_consensus_specs_tpu.crypto.curve import (
 )
 from eth_consensus_specs_tpu.crypto.hash_to_curve import DST_G2, hash_to_g2
 from eth_consensus_specs_tpu.crypto.pairing import pairing_check
-from eth_consensus_specs_tpu.obs import watchdog
+from eth_consensus_specs_tpu.obs import watchdog, waterfall
 
 
 def _use_device() -> bool:
@@ -194,30 +199,72 @@ def batch_verify_aggregates(
     return ok
 
 
-def _parse_item(item: tuple[list[bytes], bytes, bytes]):
-    """(pubkeys, message, signature) -> (points, msg, sig, r) or None on
-    any malformed/empty input — the exact accept/reject rules of the
-    inline parse this was extracted from."""
-    from eth_consensus_specs_tpu.crypto.signature import _load_pk
-
+def _parse_item(item: tuple, keys=None):
+    """(signers, message, signature) -> (signers, msg, sig, r), or None on
+    any malformed/empty input. The signers come as 48-byte public keys
+    or as an array of registry indices; with the registry's key table
+    (``ops/key_table.KeyTable``) both resolve to an index array, without
+    one the keys are decoded to points (cached: ``signature._load_pk``
+    refuses malformed AND infinity keys)."""
     pks, msg, sig_b = item
     if len(pks) == 0:
         return None
-    # _load_pk rejects malformed AND infinity keys (same outcome as the
-    # previous inline parse) and caches decompression — registry keys
-    # repeat every block, so steady-state parsing is dict lookups
+    signers = keys.resolve(pks) if keys is not None else None
+    if signers is None:
+        if isinstance(pks, np.ndarray):
+            return None  # indices into a registry this caller was not given
+        signers = _decode_keys(pks)
+        if signers is None:
+            return None
+    try:
+        sig = g2_from_bytes(bytes(sig_b))
+    except ValueError:
+        return None
+    r = secrets.randbits(64) | 1
+    return (signers, bytes(msg), sig, r)
+
+
+def _decode_keys(pks: list) -> list | None:
+    from eth_consensus_specs_tpu.crypto.signature import _load_pk
+
     points = []
     for pk in pks:
         p = _load_pk(bytes(pk))
         if p is None:
             return None
         points.append(p)
-    try:
-        sig = g2_from_bytes(bytes(sig_b))
-    except ValueError:
-        return None
-    r = secrets.randbits(64) | 1
-    return (points, bytes(msg), sig, r)
+    return points
+
+
+@contextlib.contextmanager
+def _decode_clock():
+    """One sample of ``bls.key_decode_ms`` where the block decompressed a
+    key on this thread."""
+    from eth_consensus_specs_tpu.crypto import signature
+
+    decodes, t0 = signature.pk_decodes(), time.perf_counter()
+    yield
+    if signature.pk_decodes() != decodes:
+        obs.observe("bls.key_decode_ms", (time.perf_counter() - t0) * 1e3)
+
+
+def warm_keys(items: list) -> None:
+    """Decode the byte-form keys of a flush into ``signature._load_pk``'s
+    cache ahead of its dispatch: a service without a key table does it in
+    host prep, overlapped with the flush before."""
+    with _decode_clock():
+        for pks, _, _ in items:
+            if not isinstance(pks, np.ndarray):
+                _decode_keys(pks)
+
+
+def _parse_flush(items: list, keys=None) -> list:
+    with _decode_clock():
+        return [_parse_item(it, keys) for it in items]
+
+
+def _signer_points(signers, keys) -> list:
+    return keys.points(signers) if isinstance(signers, np.ndarray) else signers
 
 
 def _batch_verify_impl(
@@ -292,14 +339,139 @@ def _rlc_pubkey_terms(parsed: list, mesh=None) -> list:
     return rpk
 
 
-def _rlc_pairing_check(parsed: list, rpk: list, mesh=None) -> bool:
-    g1 = g1_generator()
-    # merge same-message items into one pairing input (block attestations
-    # often share AttestationData): k items with m distinct messages ->
-    # m+1 pairs, one hash-to-curve per distinct message
+# == the served path: each leg where what the code observes puts it =========
+#
+# `verify_many` reads no backend switch and no environment variable. What
+# it observes: whether the caller handed over a key table and a mesh,
+# whether the flush's (items, lanes) bucket has its program compiled, and
+# whether it runs on a service's thread. Measured on one v5e at a mainnet
+# block's 128 aggregates x 512 keys (PERF.md section 5), the committee
+# sums are the one leg the device does faster than the C core (68 ms
+# against 105 ms, the keys resident on both sides), and its program takes
+# ~200 s to compile. So the sums go to the device, one or the mesh handed
+# in (item axis sharded), for a bucket that `serve/buckets.precompile` or
+# an earlier dispatch has compiled. A bucket not yet compiled goes through
+# the core on a service's thread, where a compile would hold every request
+# behind it for minutes; off it, a caller who handed a mesh asked for the
+# devices and waits for the compile itself. Hash-to-G2 and the pairing
+# have device programs that lose at a block's shape (or cannot be built on
+# the chip's host) and the G2 fold has none: those legs take the core.
+
+
+def _takes_device(key: tuple, mesh) -> bool:
+    from eth_consensus_specs_tpu.serve import buckets
+    from eth_consensus_specs_tpu.serve.service import on_service_thread
+
+    return buckets.is_compiled(*key) or (mesh is not None and not on_service_thread())
+
+
+def _host_sum(signers, keys) -> Point:
+    """A committee's sum on the host: straight from the table's affine
+    rows in the C core where the signers are registry indices."""
+    from eth_consensus_specs_tpu.crypto import native_bridge as nb
+    from eth_consensus_specs_tpu.crypto.fields import Fq
+    from eth_consensus_specs_tpu.crypto.signature import _sum_g1
+
+    if isinstance(signers, np.ndarray) and nb.enabled():
+        raw = nb.g1_aggregate_affine(keys.affine[signers].tobytes())
+        return g1_infinity() if raw is None else Point(Fq(raw[0]), Fq(raw[1]), B1)
+    return _sum_g1(_signer_points(signers, keys))
+
+
+def _served_pubkey_terms(parsed: list, keys=None, mesh=None) -> list:
+    """Per-item r_i * aggpk_i of a served flush. Signers the key table
+    resolved to registry indices are gathered and summed from the table's
+    limbs, signers that came as points of their own are packed and summed
+    over a mesh only; on the device where `_takes_device` says so, through
+    the C core otherwise."""
+    from eth_consensus_specs_tpu.ops import g1_msm
+    from eth_consensus_specs_tpu.parallel.mesh_ops import shard_count
+    from eth_consensus_specs_tpu.serve import buckets
+
+    if shard_count(mesh) <= 1:
+        mesh = None
+    indexed = [i for i, p in enumerate(parsed) if isinstance(p[0], np.ndarray)]
+    loose = [i for i, p in enumerate(parsed) if not isinstance(p[0], np.ndarray)]
+    sums: list = [None] * len(parsed)
+    jacobian = None
+    with waterfall.leg("bls.g1_sum.call"):
+        if indexed:
+            rows = [parsed[i][0] for i in indexed]
+            key = buckets.bls_keysum_key(len(rows), max(map(len, rows)), len(keys), mesh=mesh)
+            if _takes_device(key, mesh):
+                with buckets.first_dispatch(*key):
+                    jacobian = g1_msm.sum_indexed_device(
+                        keys.device_limbs(mesh), rows, key[1:3], mesh=mesh
+                    )
+            else:
+                for i in indexed:
+                    sums[i] = _host_sum(parsed[i][0], keys)
+        lists = [parsed[i][0] for i in loose]
+        key = None
+        if lists and mesh is not None:
+            key = buckets.bls_msm_key(len(lists), max(map(len, lists)), mesh=mesh)
+        if key is not None and _takes_device(key, mesh):
+            with buckets.first_dispatch(*key):
+                device_sums = g1_msm.sum_g1_many_device(lists, mesh=mesh, pad_shape=key[1:3])
+            for i, s in zip(loose, device_sums):
+                sums[i] = s
+        else:
+            for i in loose:
+                sums[i] = _host_sum(parsed[i][0], keys)
+    with waterfall.leg("bls.g1_sum.unpack"):
+        if jacobian is not None:
+            for i, point in zip(indexed, g1_msm._jacobian_to_points(*jacobian)):
+                sums[i] = point
+        return [s.mul(p[3]) for s, p in zip(sums, parsed)]
+
+
+def _host_hash_many(msgs: list[bytes], dst: bytes) -> list:
+    return [hash_to_g2(m, dst) for m in msgs]
+
+
+def _merge_by_message(parsed: list, rpk: list) -> dict:
+    """Same-message items merged into one pairing input (block
+    attestations often share AttestationData): k items with m distinct
+    messages -> m+1 Miller loops instead of k+1."""
     merged: dict[bytes, object] = {}
-    for (points, msg, sig, r), rp in zip(parsed, rpk):
+    for (_, msg, _, _), rp in zip(parsed, rpk):
         merged[msg] = rp if msg not in merged else merged[msg] + rp
+    return merged
+
+
+def _fold_signatures(parsed: list):
+    """sum_i r_i * sig_i in ONE native Pippenger MSM (64-bit scalars are
+    always < r, so the reduced path is exact); multi_exp falls back to
+    the bit-exact per-point path without the native core."""
+    from eth_consensus_specs_tpu.utils.bls import multi_exp
+
+    return multi_exp([sig for _, _, sig, _ in parsed], [r for _, _, _, r in parsed])
+
+
+def _served_rlc_check(parsed: list, rpk: list) -> bool:
+    """One random-linear-combination pairing over a served flush or a
+    subset of it, each leg through the C core (above) and under its own
+    clock; one sample of ``bls.rlc_check_ms`` a check."""
+    t0 = time.perf_counter()
+    merged = _merge_by_message(parsed, rpk)
+    with waterfall.leg("bls.h2c"):
+        # kept for the subsets a bisection checks again and for the block
+        # after this one
+        _prime_h2g2_cache(list(merged), _host_hash_many)
+        pairs = [(rp, _h2g2(msg)) for msg, rp in merged.items()]
+    with waterfall.leg("bls.g2_fold"):
+        pairs.append((-g1_generator(), _fold_signatures(parsed)))
+    obs.count("bls.pairings", 1)
+    obs.count("bls.pairing_inputs", len(pairs))
+    obs.count("bls.messages_distinct", len(merged))
+    with waterfall.leg("bls.pairing"):
+        ok = pairing_check(pairs)
+    obs.observe("bls.rlc_check_ms", (time.perf_counter() - t0) * 1e3)
+    return ok
+
+
+def _rlc_pairing_check(parsed: list, rpk: list, mesh=None) -> bool:
+    merged = _merge_by_message(parsed, rpk)
     # optional device hash-to-curve: one batched dispatch maps every
     # distinct message (ops/h2c_device — bit-equal to the host path, so
     # routing can never flip a result); opt-in via env because the
@@ -308,35 +480,31 @@ def _rlc_pairing_check(parsed: list, rpk: list, mesh=None) -> bool:
         from eth_consensus_specs_tpu.ops.h2c_device import hash_to_g2_device
 
         _prime_h2g2_cache(list(merged.keys()), hash_to_g2_device)
-    # sum_i r_i * sig_i in ONE native Pippenger MSM (64-bit scalars are
-    # always < r, so the reduced path is exact); multi_exp falls back to
-    # the bit-exact per-point path without the native core
-    from eth_consensus_specs_tpu.utils.bls import multi_exp
-
-    sig_acc = multi_exp([sig for _, _, sig, _ in parsed], [r for _, _, _, r in parsed])
     pairs = [(rp, _h2g2(msg)) for msg, rp in merged.items()]
-    pairs.append((-g1, sig_acc))
+    pairs.append((-g1_generator(), _fold_signatures(parsed)))
     obs.count("bls.pairings", 1)
     obs.count("bls.pairing_inputs", len(pairs))
     obs.count("bls.messages_distinct", len(merged))
     return _pairing_check_routed(pairs, mesh=mesh)
 
 
-def verify_many(
-    items: list[tuple[list[bytes], bytes, bytes]], mesh=None
-) -> list[bool]:
-    """Per-item verdicts for many (pubkeys, message, aggregate_signature)
-    triples — the serving layer's batch entry point. Parsing and the
-    per-item G1 MSM terms are computed ONCE; one RLC pairing settles an
-    all-valid batch (the overwhelmingly common case), and a reject
-    bisects with only the G2 MSM + pairing per subset, so each invalid
-    item costs ~2*log2(n) pairings instead of n.
+def verify_many(items: list[tuple], mesh=None, keys=None) -> list[bool]:
+    """Per-item verdicts for many (signers, message, aggregate_signature)
+    triples — the serving layer's batch entry point. The signers are
+    48-byte public keys or, with the registry's key table in ``keys``, an
+    array of registry indices. Parsing and the per-item G1 terms are
+    computed ONCE; one RLC pairing settles an all-valid batch (the
+    overwhelmingly common case), and a reject bisects with only the G2
+    MSM + pairing per subset, so each invalid item costs ~2*log2(n)
+    pairings instead of n.
 
-    With a multi-device ``mesh`` the per-item G1 terms shard their item
-    axis and the device pairing's Miller chunks shard across chips; the
-    terms are mesh-independent values (canonical affine points), so the
-    bisection re-checks subsets with the SAME terms and verdicts stay
-    bit-identical whatever the mesh shape.
+    Each leg runs where `_served_pubkey_terms` and `_served_rlc_check`
+    put it. With a multi-device ``mesh`` the per-item G1 terms shard their
+    item axis (the indices where the signers are in the key table, the
+    table replicated; the packed points otherwise); the terms are
+    canonical affine points whichever side summed them, so the bisection
+    re-checks subsets with the SAME terms and verdicts stay bit-identical
+    on every routing and whatever the mesh shape.
 
     Per-item results are exactly what ``batch_verify_aggregates([item])``
     returns: a singleton RLC check is ``X^r == 1`` in the prime-order
@@ -348,13 +516,14 @@ def verify_many(
     with obs.span("bls.verify_many", items=len(items)):
         obs.count("bls.verify_many_items", len(items))
         out = [False] * len(items)
-        parsed = [_parse_item(it) for it in items]
+        with waterfall.leg("bls.keys"):
+            parsed = _parse_flush(items, keys)
         live = [i for i, p in enumerate(parsed) if p is not None]
         if not live:
             return out
         sub = [parsed[i] for i in live]
-        rpk = _rlc_pubkey_terms(sub, mesh=mesh)
-        verdicts = _bisect_rlc(sub, rpk, mesh=mesh)
+        rpk = _served_pubkey_terms(sub, keys, mesh)
+        verdicts = _bisect_rlc(sub, rpk)
         for i, v in zip(live, verdicts):
             out[i] = v
     # sampled device/host coupling on the serving path too (outside the
@@ -362,17 +531,15 @@ def verify_many(
     # reproduce through the plain host pairing
     if live and watchdog.should_check("bls_batch"):
         k = live[watchdog.call_salt("bls_batch") % len(live)]
-        points, msg, sig, _r = parsed[k]
-        watchdog.check_bls_item(points, msg, sig, out[k])
+        signers, msg, sig, _r = parsed[k]
+        watchdog.check_bls_item(_signer_points(signers, keys), msg, sig, out[k])
     return out
 
 
-def _bisect_rlc(parsed: list, rpk: list, mesh=None) -> list[bool]:
-    if _rlc_pairing_check(parsed, rpk, mesh=mesh):
+def _bisect_rlc(parsed: list, rpk: list) -> list[bool]:
+    if _served_rlc_check(parsed, rpk):
         return [True] * len(parsed)
     if len(parsed) == 1:
         return [False]
     mid = len(parsed) // 2
-    return _bisect_rlc(parsed[:mid], rpk[:mid], mesh=mesh) + _bisect_rlc(
-        parsed[mid:], rpk[mid:], mesh=mesh
-    )
+    return _bisect_rlc(parsed[:mid], rpk[:mid]) + _bisect_rlc(parsed[mid:], rpk[mid:])

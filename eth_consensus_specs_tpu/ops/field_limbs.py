@@ -49,7 +49,7 @@ def int_to_limbs(x: int) -> np.ndarray:
 
 
 def _limbs_to_int(arr: np.ndarray) -> int:
-    return sum(int(arr[i]) << (LIMB_BITS * i) for i in range(N_LIMBS))
+    return sum(limb << (LIMB_BITS * i) for i, limb in enumerate(arr.tolist()))
 
 
 P_LIMBS = int_to_limbs(P_INT)
@@ -61,10 +61,13 @@ def to_mont(x: int) -> np.ndarray:
     return int_to_limbs((x * R_INT) % P_INT)
 
 
+R_INV_INT = pow(R_INT, -1, P_INT)
+
+
 def from_mont_int(limbs) -> int:
     """Host: (possibly redundant) Montgomery limbs -> canonical int."""
     x = _limbs_to_int(np.asarray(limbs, np.uint64))
-    return (x * pow(R_INT, -1, P_INT)) % P_INT
+    return (x * R_INV_INT) % P_INT
 
 
 ONE_MONT = to_mont(1)

@@ -41,6 +41,7 @@ from eth_consensus_specs_tpu.crypto.fields import Fq, P as P_INT
 
 from .field_limbs import (
     N_LIMBS,
+    ONE_MONT,
     add_mod,
     from_mont_int,
     is_zero,
@@ -173,6 +174,41 @@ def sum_many_kernel(X, Y, Z):
     return jax.vmap(_tree_sum)(X, Y, Z)
 
 
+def _strip_sum(X, Y, Z, strip: int):
+    """Point sum of L (power-of-two) Jacobian lanes, `strip` lanes at a
+    time: the strips accumulate into `strip` running sums through ONE add
+    body in a scan, and a pairwise tree folds those. Exact group math, so
+    the affine result is the tree's whatever the strip; `strip` = L is
+    the tree alone."""
+    lanes = X.shape[0]
+    if strip >= lanes:
+        return _tree_sum(X, Y, Z)
+    parts = [a.reshape(lanes // strip, strip, N_LIMBS) for a in (X, Y, Z)]
+
+    def step(acc, part):
+        return _add(*acc, *part), None
+
+    acc, _ = lax.scan(step, tuple(a[0] for a in parts), tuple(a[1:] for a in parts))
+    return _tree_sum(*acc)
+
+
+def _sum_indexed(table_x, table_y, index, strip: int):
+    live = index >= 0
+    at = jnp.where(live, index, 0)
+    Z = jnp.where(live[..., None], jnp.asarray(ONE_MONT), jnp.uint64(0))
+    return jax.vmap(partial(_strip_sum, strip=strip))(table_x[at], table_y[at], Z)
+
+
+@partial(jax.jit, static_argnames="strip")
+def sum_indexed_kernel(table_x, table_y, index, strip: int):
+    """Per-item sums of registry keys that live on the device: the
+    table's affine Montgomery coordinates u64[N, 13], `index` i32[I, L]
+    (L a power of two, a negative entry an empty lane). The flush sends
+    the indices; the lanes are gathered here, so no point is packed on
+    the host. Returns Jacobian u64[I, 13] per coordinate."""
+    return _sum_indexed(table_x, table_y, index, strip)
+
+
 def _msm_lanes(bits, X, Y, Z):
     """One item's MSM: vmapped double-and-add over its lanes + pairwise
     tree reduce — the shared body of msm_kernel and the batched
@@ -271,6 +307,19 @@ def _sharded_fn(mesh: Mesh, kind: str):
                 check_vma=False,
             )
         )
+    elif kind == "sum_indexed":
+        # the key table replicated, the index array's item axis sharded:
+        # each shard gathers and sums its own items, no collectives
+
+        def local(table_x, table_y, index):
+            return _sum_indexed(table_x, table_y, index, KEY_SUM_STRIP)
+
+        fn = jax.jit(
+            shard_map(
+                local, mesh=mesh, in_specs=(P(), P(), spec), out_specs=spec,
+                check_vma=False,
+            )
+        )
     else:  # "sum_many": item axis sharded, no collectives
 
         def local(X, Y, Z):
@@ -341,15 +390,28 @@ def _scalars_to_bits(scalars: list[int]) -> np.ndarray:
     return bits
 
 
+def _jacobian_to_points(X, Y, Z) -> list[Point]:
+    """Rows of Jacobian Montgomery limbs u64[n, 13] to affine points, the
+    rows' field inversions batched into one (a flush of committee sums
+    has 128)."""
+    xs, ys, zs = ([from_mont_int(row) for row in np.asarray(a)] for a in (X, Y, Z))
+    live = [i for i, z in enumerate(zs) if z]
+    prefix, acc = [], 1
+    for i in live:
+        prefix.append(acc)
+        acc = acc * zs[i] % P_INT
+    inv = pow(acc, -1, P_INT)
+    points = [g1_infinity()] * len(zs)
+    for i, before in zip(reversed(live), reversed(prefix)):
+        zinv = inv * before % P_INT
+        inv = inv * zs[i] % P_INT
+        zinv2 = zinv * zinv % P_INT
+        points[i] = Point(Fq(xs[i] * zinv2 % P_INT), Fq(ys[i] * zinv2 % P_INT * zinv % P_INT), B1)
+    return points
+
+
 def _jacobian_to_point(X, Y, Z) -> Point:
-    z = from_mont_int(np.asarray(Z))
-    if z == 0:
-        return g1_infinity()
-    x = from_mont_int(np.asarray(X))
-    y = from_mont_int(np.asarray(Y))
-    zinv = pow(z, P_INT - 2, P_INT)
-    zinv2 = zinv * zinv % P_INT
-    return Point(Fq(x * zinv2 % P_INT), Fq(y * zinv2 % P_INT * zinv % P_INT), B1)
+    return _jacobian_to_points(*(np.asarray(a)[None] for a in (X, Y, Z)))[0]
 
 
 def _pad_lanes(arrs, n: int, cap: int):
@@ -498,3 +560,29 @@ def sum_g1_many_device(
         rX, rY, rZ = sum_many_kernel(*args)
     rX, rY, rZ = np.asarray(rX), np.asarray(rY), np.asarray(rZ)
     return [_jacobian_to_point(rX[i], rY[i], rZ[i]) for i in range(n)]
+
+
+# lanes a strip of sum_indexed_kernel. At 128 x 512 on one v5e (PERF.md
+# section 5): strip 4 443 ms, 16 127 ms, 64 68 ms a flush, and 68 s,
+# 159 s, 206 s to compile; the C core sums the same keys in 105 ms
+KEY_SUM_STRIP = 64
+
+
+def sum_indexed_device(table_limbs, rows: list, pad_shape: tuple, mesh: Mesh | None = None):
+    """Per-item sums of registry keys resident on the device
+    (``key_table.KeyTable.device_limbs``): ``rows`` are the items' registry
+    indices, padded here to ``pad_shape`` (items, lanes: the
+    :func:`many_sum_shape` bucket); a multi-device `mesh`, on which the
+    table lies replicated, shards the item axis. Returns the Jacobian sums
+    on the host, u64[items, 13] a coordinate (``_jacobian_to_points``
+    makes points of them)."""
+    index = np.full(pad_shape, -1, np.int32)
+    for i, row in enumerate(rows):
+        index[i, : len(row)] = row
+    if mesh is not None:
+        obs.count("mesh.dispatches", 1)
+        obs.count("mesh.sharded_items", len(rows))
+        out = _sharded_fn(mesh, "sum_indexed")(*table_limbs, jnp.asarray(index))
+    else:
+        out = sum_indexed_kernel(*table_limbs, jnp.asarray(index), strip=KEY_SUM_STRIP)
+    return tuple(np.asarray(a)[: len(rows)] for a in out)

@@ -70,6 +70,11 @@ class MicroBatcher:
         )
         self._queue: deque[Request] = deque()
         self._closed = False
+        # what the waiting batch thread last asked to be woken for
+        # (next_flush): a queue this long, or a flush policy that looks
+        # at more than the queue
+        self._wake_len = 1
+        self._wake_fn: Callable[[], bool] | None = None
 
     def put(self, req: Request) -> None:
         with self._cond:
@@ -77,7 +82,14 @@ class MicroBatcher:
                 raise RuntimeError("service is shut down")
             self._queue.append(req)
             waterfall.mark(req.stamps, "queued")
-            self._cond.notify_all()
+            # the batch thread sleeps until the queue's first request and
+            # then to the oldest one's deadline; only a put that can change
+            # its decision wakes it. A wake-up a put was a system call in
+            # every submit and a thread contending for the GIL with the
+            # submitter, whose block of requests then missed the deadline
+            n = len(self._queue)
+            if n == 1 or n >= self._wake_len or (self._wake_fn is not None and self._wake_fn()):
+                self._cond.notify_all()
 
     def qsize(self) -> int:
         with self._cond:
@@ -102,6 +114,10 @@ class MicroBatcher:
         only adds latency, since co-riders accumulate naturally while a
         dispatch is in flight, not while the pipeline sits empty."""
         with self._cond:
+            self._wake_len = max_batch
+            # the idle policy's answer changes with no put at all: every
+            # put re-asks it, as before
+            self._wake_fn = (lambda: True) if idle_fn is not None else pressure_fn
             while not self._queue:
                 if self._closed:
                     return None

@@ -372,6 +372,20 @@ def bls_msm_key(n_items: int, max_lanes: int, mesh=None) -> tuple:
     )
 
 
+def bls_keysum_key(n_items: int, max_lanes: int, registry: int, mesh=None) -> tuple:
+    """The compile/bucket/warmup key of the committee sums gathered from
+    the registry's key table on the device (``g1_msm.sum_indexed_kernel``):
+    the many_sum_shape (items, lanes) bucket and the table's length, which
+    is a dimension of the program too; mesh-signed when the item axis
+    shards."""
+    from eth_consensus_specs_tpu.ops.g1_msm import many_sum_shape
+    from eth_consensus_specs_tpu.parallel import mesh_ops
+
+    shards = mesh_ops.shard_count(mesh)
+    key = ("bls_keysum", *many_sum_shape(n_items, max_lanes, shards), int(registry))
+    return (*key, mesh_ops.mesh_signature(mesh)) if shards > 1 else key
+
+
 def g2_agg_key_from_profile(
     n_items: int, max_lanes: int, shards: int = 1, sig: str = ""
 ) -> tuple:
@@ -617,6 +631,16 @@ def note_dispatch(op: str, *dims) -> bool:
     return True
 
 
+def is_compiled(op: str, *dims) -> bool:
+    """Whether this process has dispatched (so compiled, or loaded from
+    the persistent cache) the shape key already. A dispatch site whose
+    program takes minutes to compile asks before it takes the device, and
+    goes to the host otherwise: ``precompile`` is what warms such a key."""
+    key = (op, *(d if isinstance(d, str) else int(d) for d in dims))
+    with _SEEN_LOCK:
+        return key in _SEEN_SHAPES
+
+
 def observe_compile_ms(op: str, ms: float, n: int = 1) -> None:
     """Record a first-dispatch compile wall time into the
     ``serve.compile_ms`` (+ per-op) histograms. ``n > 1`` records the
@@ -772,7 +796,8 @@ def _key_mesh(dims: tuple, chips: int | None = None):
 
 
 def precompile(
-    keys: list[tuple] | None = None, path: str | None = None, chips: int | None = None
+    keys: list[tuple] | None = None, path: str | None = None, chips: int | None = None,
+    key_table=None,
 ) -> int:
     """Compile every known bucket shape ahead of traffic. With no
     explicit `keys`, replays the persistent warmup list — from ``path``
@@ -783,7 +808,10 @@ def precompile(
     must not crash an older server), and mesh-signed keys are replayed
     ONLY when the live serve mesh matches the signature — an 8-chip
     artifact must not poison a single-chip boot with alien shapes
-    (``serve.precompile_skipped`` event per skip)."""
+    (``serve.precompile_skipped`` event per skip). ``key_table`` is the
+    service's registry of public keys (ops/key_table.py), which the
+    ``bls_keysum`` programs gather from; their keys are skipped without
+    it, or where it has another length than the key names."""
     import numpy as np
 
     warmed = 0
@@ -809,23 +837,34 @@ def precompile(
                 with first_dispatch(op, *dims):
                     merkleize_many_device([zero], depth, pad_batch=batch, mesh=mesh)
             elif op == "bls_msm" and len(int_dims) in (1, 2):
-                from eth_consensus_specs_tpu.ops.bls_batch import _use_device, verify_many
+                from eth_consensus_specs_tpu.crypto.curve import g1_generator
+                from eth_consensus_specs_tpu.ops.bls_batch import _use_device
+                from eth_consensus_specs_tpu.ops.g1_msm import sum_g1_many_device
 
-                if not _use_device():
-                    continue  # host backend: there is no MSM kernel to warm
+                if mesh is None and not _use_device():
+                    # without a mesh only the switch-routed batch path
+                    # dispatches this kernel: nothing to warm on the host backend
+                    continue
                 # legacy 1-dim keys are (lanes,); current keys are
-                # (items, lanes[, sig]) — the many_sum_shape bucket
+                # (items, lanes[, sig]) — the many_sum_shape bucket. One
+                # throwaway point at exactly the padded shape: the sum is
+                # discarded, only the kernel compile matters
                 items, lanes = (1, int_dims[0]) if len(int_dims) == 1 else int_dims
-                from eth_consensus_specs_tpu.utils import bls as _bls
+                with first_dispatch(op, *dims):
+                    sum_g1_many_device(
+                        [[g1_generator()]], mesh=mesh, pad_shape=(items, lanes)
+                    )
+            elif op == "bls_keysum" and len(int_dims) == 3:
+                from eth_consensus_specs_tpu.ops.g1_msm import sum_indexed_device
 
-                # a throwaway aggregate repeated `items` times with
-                # `lanes` copies of one pubkey: verdicts are discarded,
-                # only the (items, lanes) sum-kernel compile matters.
-                # verify_many's own first_dispatch accounts the compile
-                # (bls_batch._rlc_pubkey_terms), so none is taken here.
-                pk, msg = _bls.SkToPk(1), b"\x00" * 32
-                sig_b = bytes(_bls.Sign(1, msg))
-                verify_many([([bytes(pk)] * lanes, msg, sig_b)] * items, mesh=mesh)
+                items, lanes, registry = int_dims
+                if key_table is None or len(key_table) != registry:
+                    continue  # another registry's program
+                with first_dispatch(op, *dims):
+                    sum_indexed_device(
+                        key_table.device_limbs(mesh), [np.zeros(1, np.int32)],
+                        (items, lanes), mesh=mesh,
+                    )
             elif op == "kzg" and len(int_dims) == 1:
                 from eth_consensus_specs_tpu.crypto.curve import g1_generator
                 from eth_consensus_specs_tpu.ops.g1_msm import msm_g1_many_device

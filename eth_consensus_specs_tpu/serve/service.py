@@ -3,6 +3,9 @@
 Six submit verbs return ``concurrent.futures.Future``s:
 
   * ``submit_bls_aggregate(pubkeys, message, signature) -> Future[bool]``
+    (the signers as 48-byte keys, or as indices into the registry that
+    ``register_pubkeys`` handed over once: decoded and validated once,
+    kept on the host and, as limbs, on the device — ops/key_table.py)
   * ``submit_aggregate(signatures) -> Future[bytes]`` (96-byte
     aggregate signature — the aggregation-pipeline op: ragged
     committees batch into ONE G2 many-sum dispatch per flush)
@@ -121,6 +124,9 @@ class VerifyService:
         # never build a registry
         self._slot_world = None
         self._slot_world_lock = threading.Lock()
+        # the registry's decoded public keys (ops/key_table.KeyTable): None
+        # until register_pubkeys, and then BLS requests resolve against it
+        self._keys = None
         self._batch_thread = threading.Thread(
             target=self._batch_loop, name=f"{name}-batch", daemon=True
         )
@@ -165,11 +171,33 @@ class VerifyService:
             obs.count(f"serve.requests.{kind}", 1)
         return req.future
 
-    def submit_bls_aggregate(self, pubkeys: list, message: bytes, signature: bytes,
+    def register_pubkeys(self, pubkeys: list) -> None:
+        """Hand the registry's public keys over, in registry order, once:
+        each is decompressed and KeyValidated here (ValueError names the
+        first that fails, and nothing is kept), and from then on a BLS
+        request's signers resolve to registry indices, given as indices or
+        found by one dictionary lookup a key, and their committee sums run
+        from the resident table. A key that is not in the registry is
+        decoded as before."""
+        from eth_consensus_specs_tpu.ops.key_table import KeyTable
+
+        self._keys = KeyTable(pubkeys)
+
+    def submit_bls_aggregate(self, pubkeys, message: bytes, signature: bytes,
                              canary: bool = False) -> Future:
         """FastAggregateVerify-shaped request; resolves to the exact bool
-        ``ops.bls_batch.batch_verify_aggregates([item])`` returns."""
-        pks = [bytes(p) for p in pubkeys]
+        ``ops.bls_batch.batch_verify_aggregates([item])`` returns.
+        ``pubkeys`` is a list of 48-byte keys, or an integer array of
+        indices into the registered registry (ValueError without one, or
+        past its end): both forms give the same verdict."""
+        if isinstance(pubkeys, np.ndarray):
+            if pubkeys.dtype.kind not in "iu" or pubkeys.ndim != 1:
+                raise ValueError("signers by index: a one-dimensional integer array")
+            if self._keys is None or (pubkeys.size and self._keys.resolve(pubkeys) is None):
+                raise ValueError("signers by index: not in the registered registry")
+            pks = pubkeys
+        else:
+            pks = [bytes(p) for p in pubkeys]
         item = (pks, bytes(message), bytes(signature))
         cost = 48 * len(pks) + len(item[1]) + len(item[2])
         return self._submit("bls", item, cost, canary=canary)
@@ -321,20 +349,24 @@ class VerifyService:
 
     def _prep(self, reqs: list[Request]) -> None:
         """Host prep, overlapped with the previous flush's device work:
-        SSZ chunk packing for htr, pubkey decompression warm-up for bls.
+        SSZ chunk packing for htr, pubkey decompression warm-up for bls
+        (where no registry was handed over: its keys are decoded already).
         A per-request prep failure resolves THAT future exceptionally and
         drops the request; co-batched requests are unaffected."""
-        from eth_consensus_specs_tpu.crypto.signature import _load_pk, _load_sig
+        from eth_consensus_specs_tpu.crypto.signature import _load_sig
         from eth_consensus_specs_tpu.ops.merkle import _chunks_to_words
 
+        if self._keys is None:
+            from eth_consensus_specs_tpu.ops.bls_batch import warm_keys
+
+            # warms the bounded decompression cache (a malformed key is
+            # the flush's to refuse: nothing raises here)
+            warm_keys([r.payload for r in reqs if r.kind == "bls"])
         for r in reqs:
             try:
                 if r.kind == "htr":
                     chunks, depth = r.payload
                     r.prepped = _chunks_to_words(chunks, 1 << depth)
-                elif r.kind == "bls":
-                    for pk in r.payload[0]:
-                        _load_pk(pk)  # warms the bounded decompression cache
                 elif r.kind == "kzg":
                     # the heavy host-side parse (4096 field elements,
                     # point decompression, Fiat-Shamir challenge) runs
@@ -448,6 +480,7 @@ class VerifyService:
                     verdicts = verify_many(
                         [r.payload for r in bls_reqs],
                         mesh=mesh if len(bls_reqs) >= mesh_ops.min_items() else None,
+                        keys=self._keys,
                     )
             else:
                 from eth_consensus_specs_tpu.crypto.signature import fast_aggregate_verify
@@ -456,7 +489,10 @@ class VerifyService:
                 # (they are out of its serve.requests denominator too)
                 obs.count("serve.degraded_items",
                           sum(1 for r in bls_reqs if not r.canary))
-                verdicts = [fast_aggregate_verify(*r.payload) for r in bls_reqs]
+                verdicts = [
+                    fast_aggregate_verify(self._signer_keys(r.payload[0]), *r.payload[1:])
+                    for r in bls_reqs
+                ]
             for r, v in zip(bls_reqs, verdicts):
                 results[id(r)] = bool(v)
 
@@ -624,6 +660,12 @@ class VerifyService:
                 )
         return results
 
+    def _signer_keys(self, signers) -> list:
+        """A BLS request's signers as 48-byte keys, for the host oracle."""
+        if isinstance(signers, np.ndarray):
+            return [row.tobytes() for row in self._keys.compressed[signers]]
+        return signers
+
     def _release_once(self, req: Request, service_s: float | None = None) -> None:
         """Each request's admission slot is released exactly once, however
         many paths observe its end (prep failure, cancellation sweep,
@@ -710,8 +752,13 @@ class VerifyService:
         """Warm the compile cache from the persistent warmup list (or an
         explicit shippable artifact ``path``, or explicit keys) before
         taking traffic. Mesh-signed keys resolve against THIS service's
-        dispatch mesh (``mesh_chips``), not the host-wide default."""
-        return buckets.precompile(keys, path=path, chips=self.config.mesh_chips or None)
+        dispatch mesh (``mesh_chips``), not the host-wide default. A
+        ``("bls_keysum", items, lanes, registry)`` key warms the committee
+        sums of flushes of that bucket over the registered registry: only
+        a warmed bucket's sums go to the device (ops/bls_batch.py)."""
+        return buckets.precompile(
+            keys, path=path, chips=self.config.mesh_chips or None, key_table=self._keys
+        )
 
     def close(self, timeout: float = 30.0) -> None:
         """Drain queued requests (a final ``close`` flush), stop both
