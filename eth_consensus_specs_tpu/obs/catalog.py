@@ -65,6 +65,7 @@ CATALOG: tuple[Metric, ...] = (
     _c("shuffle.permutations", "full committee permutations"),
     _s("shuffle.permutation", "device shuffle permutation"),
     _c("state_root.real_hashes", "hashes in post-epoch state roots"),
+    _c("state_root.chain_steps", "sequential hash steps of state roots' list tails"),
     _c("state_root.roots", "post-epoch state roots computed"),
     _c("state_root.traces", "state-root kernel (re)traces"),
     _s("state_root.post_epoch", "device post-epoch state root"),

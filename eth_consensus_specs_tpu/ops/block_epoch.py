@@ -506,20 +506,21 @@ def make_root_ctx(spec, arrays, meta, static: BlockEpochStatic, scores, just) ->
         BALANCE_LIMIT_CHUNKS_LOG2,
         bitvector4_chunk,
         checkpoint_root,
-        u64_list_root,
-        validator_registry_root,
+        list_roots,
+        u64_list_tail,
+        validator_registry_tail,
     )
 
     n = meta.n_validators
     slot_of = {name: i for i, name in meta.dynamic_slots}
     chunks = arrays.top_chunks
-    chunks = chunks.at[slot_of["validators"]].set(
-        validator_registry_root(arrays, n, static.eff_balance)
-    )
+    tails = {slot_of["validators"]: validator_registry_tail(arrays, n, static.eff_balance)}
     if "inactivity_scores" in slot_of:
-        chunks = chunks.at[slot_of["inactivity_scores"]].set(
-            u64_list_root(scores, n, BALANCE_LIMIT_CHUNKS_LOG2, arrays.zerohashes)
+        tails[slot_of["inactivity_scores"]] = u64_list_tail(
+            scores, n, BALANCE_LIMIT_CHUNKS_LOG2
         )
+    for slot, root in list_roots(tails, arrays.zerohashes).items():
+        chunks = chunks.at[slot].set(root)
     chunks = chunks.at[slot_of["justification_bits"]].set(
         bitvector4_chunk(just.justification_bits.astype(bool))
     )
@@ -556,22 +557,22 @@ def _slot_root(ctx: SlotRootCtx, st: BlockState, slot_no) -> jnp.ndarray:
     from eth_consensus_specs_tpu.ops.state_root import (
         BALANCE_LIMIT_CHUNKS_LOG2,
         PARTICIPATION_LIMIT_CHUNKS_LOG2,
-        u8_list_root,
-        u64_list_root,
+        list_roots,
+        u8_list_tail,
+        u64_list_tail,
     )
 
     n = ctx.n
     chunks = ctx.top_chunks
     chunks = chunks.at[ctx.slot_field_index].set(_u64_chunk(slot_no))
-    chunks = chunks.at[ctx.balances_slot].set(
-        u64_list_root(st.balance, n, BALANCE_LIMIT_CHUNKS_LOG2, ctx.zerohashes)
-    )
-    chunks = chunks.at[ctx.cur_part_slot].set(
-        u8_list_root(st.cur_part, n, PARTICIPATION_LIMIT_CHUNKS_LOG2, ctx.zerohashes)
-    )
-    chunks = chunks.at[ctx.prev_part_slot].set(
-        u8_list_root(st.prev_part, n, PARTICIPATION_LIMIT_CHUNKS_LOG2, ctx.zerohashes)
-    )
+    # the three dirty columns' tails as lanes of one fold
+    tails = {
+        ctx.balances_slot: u64_list_tail(st.balance, n, BALANCE_LIMIT_CHUNKS_LOG2),
+        ctx.cur_part_slot: u8_list_tail(st.cur_part, n, PARTICIPATION_LIMIT_CHUNKS_LOG2),
+        ctx.prev_part_slot: u8_list_tail(st.prev_part, n, PARTICIPATION_LIMIT_CHUNKS_LOG2),
+    }
+    for slot, root in list_roots(tails, ctx.zerohashes).items():
+        chunks = chunks.at[slot].set(root)
     return tree_root_words(chunks, ctx.top_depth)
 
 

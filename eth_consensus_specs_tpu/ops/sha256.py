@@ -180,8 +180,12 @@ def sha256_pair_words(words: jnp.ndarray) -> jnp.ndarray:
 # round, which for a batch this small is a few KB. What it costs is the
 # same whatever the batch: ~5 s of compile for each call site, compiled
 # for a v5e, against 0.1 to 0.4 s for the scan body (PERF.md, PR 22) —
-# and a state root is mostly SMALL hashes by count: the zero-hash fold
-# chains, the length mixes, three checkpoints, the top combine.
+# and a state root is mostly SMALL hashes by count: three checkpoints,
+# the top combine, the narrow levels of every tree. The one small-batch
+# caller that takes the unrolled body all the same is the list tails'
+# chain (ops/state_root.list_roots), by its own choice: ONE call site a
+# program, run some twenty times in sequence, where the scan's 128 loop
+# trips a hash were 61 % of a 2^20 state root (PERF.md, PR 26 and 28).
 SMALL_BATCH = 64
 
 

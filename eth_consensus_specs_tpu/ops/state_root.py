@@ -31,18 +31,23 @@ ssz.hash_tree_root on the equivalently-updated object state.
 from __future__ import annotations
 
 from functools import lru_cache
-from typing import NamedTuple
+from typing import Iterable, Mapping, NamedTuple
 
 import numpy as np
 
 import eth_consensus_specs_tpu  # noqa: F401
+import jax
 import jax.numpy as jnp
 from jax import lax
 
 from eth_consensus_specs_tpu import fault, obs
 from eth_consensus_specs_tpu.obs import waterfall
 from eth_consensus_specs_tpu.ops.merkle import tree_root_words
-from eth_consensus_specs_tpu.ops.sha256 import sha256_pair_words
+from eth_consensus_specs_tpu.ops.sha256 import (
+    sha256_pair_words,
+    sha256_pair_words_scan,
+    sha256_pair_words_unrolled,
+)
 
 VALIDATOR_REGISTRY_LIMIT_LOG2 = 40  # List[Validator, 2**40]
 BALANCE_LIMIT_CHUNKS_LOG2 = 38  # 2**40 u64 -> 2**38 chunks
@@ -120,26 +125,78 @@ def packed_u8_leaves(vals: jnp.ndarray, n: int) -> jnp.ndarray:
     return (w[..., 0] << 24) | (w[..., 1] << 16) | (w[..., 2] << 8) | w[..., 3]
 
 
-def fold_to_limit(root: jnp.ndarray, depth: int, limit_log2: int, zh: jnp.ndarray):
-    """Chain a subtree root up to the SSZ limit depth with zero-hash
-    siblings (right sibling = zerohashes[d] at each level). One scan
-    body instead of limit-depth unrolled compression instances — the
-    fold is sequential either way, and the state-root graph carries
-    several of these chains (a python loop here put ~25 sha bodies PER
-    CHAIN into the jaxpr, the bulk of the full-state compile wall)."""
-    if depth >= limit_log2:
-        return root
+class ListTail(NamedTuple):
+    """What an SSZ list's root still needs above its live subtree: the
+    zero-hash siblings up to the limit depth, then the length."""
 
-    def step(r, z):
-        return _hash_rows(r[None, :], z[None, :])[0], None
-
-    root, _ = lax.scan(step, root, zh[depth:limit_log2])
-    return root
+    root: jnp.ndarray  # u32[8] root of the subtree over the live chunks
+    depth: int  # that subtree's depth
+    limit_log2: int  # depth of the list's limit, in chunks
+    length: int  # element count, mixed in last
 
 
-def mix_length(root: jnp.ndarray, length: int) -> jnp.ndarray:
-    len_chunk = _u64_chunk_words(jnp.full((1,), np.uint64(length), jnp.uint64))[0]
-    return _hash_rows(root[None, :], len_chunk[None, :])[0]
+def _tree_depth(leaves: int) -> int:
+    return max(leaves - 1, 0).bit_length()
+
+
+def list_tail_steps(tails: Iterable[ListTail]) -> int:
+    """Sequential hash steps :func:`list_roots` runs for these tails: the
+    longest zero-hash chain, and the one step that mixes every length."""
+    return max(max(t.limit_log2 - t.depth, 0) for t in tails) + 1
+
+
+def _chain_hash(words: jnp.ndarray) -> jnp.ndarray:
+    """The hash of a chain step. On an accelerator the UNROLLED body
+    whatever the batch, the one small-batch caller that takes it: a
+    chain's body compiles once and runs some twenty times one after the
+    other, where the round scan that `sha256.SMALL_BATCH` sends every
+    other small hash through is 128 loop trips a hash (1.56 ms against a
+    whole tile's 0.08 on a v5e; PERF.md, PR 26) and four such chains
+    were 61 % of a 2^20 root. XLA:CPU keeps the round scan, as every sha
+    call does there."""
+    if jax.default_backend() == "cpu":
+        return sha256_pair_words_scan(words)
+    return sha256_pair_words_unrolled(words)
+
+
+def list_roots(tails: Mapping, zh: jnp.ndarray) -> dict:
+    """Finish several list roots at once, a root under each key of
+    `tails`: chain each subtree root up to its limit depth with
+    zero-hash siblings (right sibling = zerohashes[d] at level d), then
+    mix in its length. The chains are independent, and equally long for
+    every registry of 17 validators or more, so they ride ONE scan as
+    lanes: step s hashes, for every lane, its running root beside the
+    zero hash of its own level, the last step its root beside its length
+    chunk. A shorter chain enters at its own start step and is passed
+    through before it. One sha body a program (a python loop put ~25 PER
+    CHAIN into the jaxpr, the bulk of the compile wall) and one chain's
+    steps a root (a scan a list ran the lists one after another). This
+    is the only fold. On a v5e two to four lanes, 16 and 128 run a chain
+    of 21 steps in 0.05-0.06 ms; five or eight take 0.25 ms and ~35 s of
+    compile, one lane 1.04 ms (PERF.md, PR 28): no program has five."""
+    lanes = list(tails.values())
+    steps = list_tail_steps(lanes)
+    level = np.zeros((steps - 1, len(lanes)), np.int32)
+    live = np.ones((steps, len(lanes)), bool)
+    for lane, t in enumerate(lanes):
+        start = steps - 1 - max(t.limit_log2 - t.depth, 0)
+        live[:start, lane] = False
+        level[start:, lane] = np.arange(t.depth, max(t.limit_log2, t.depth))
+    lengths = np.stack(
+        [_bytes_to_words(int(t.length).to_bytes(8, "little") + bytes(24)) for t in lanes]
+    )
+    rights = jnp.concatenate([zh[level], lengths[None]])  # [steps, lanes, 8]
+    ragged = not live.all()
+
+    def step(roots, operands):
+        right, on = operands
+        out = _chain_hash(jnp.concatenate([roots, right], axis=-1))
+        if ragged:
+            out = jnp.where(on[:, None], out, roots)
+        return out, None
+
+    roots, _ = lax.scan(step, jnp.stack([t.root for t in lanes]), (rights, live))
+    return dict(zip(tails, roots))
 
 
 def _validator_leaf_rows(
@@ -179,18 +236,17 @@ def _validator_leaf_rows(
     return root
 
 
-def validator_registry_root(
+def validator_registry_tail(
     arrays: StateRootArrays, n: int, effective_balance: jnp.ndarray
-) -> jnp.ndarray:
-    """List[Validator] root from the static nodes + the dynamic
+) -> ListTail:
+    """List[Validator] subtree from the static nodes + the dynamic
     effective-balance column: 3 hashes per validator + the leaf tree."""
     roots = _validator_leaf_rows(
         effective_balance, arrays.slashed_chunk, arrays.val_node_a, arrays.val_node_f
     )  # [N, 8] validator roots
-    depth = max(n - 1, 0).bit_length()
+    depth = _tree_depth(n)
     sub = tree_root_words(_pad_pow2(roots, depth), depth)
-    full = fold_to_limit(sub, depth, VALIDATOR_REGISTRY_LIMIT_LOG2, arrays.zerohashes)
-    return mix_length(full, n)
+    return ListTail(sub, depth, VALIDATOR_REGISTRY_LIMIT_LOG2, n)
 
 
 def _pad_pow2(leaves: jnp.ndarray, depth: int) -> jnp.ndarray:
@@ -200,28 +256,22 @@ def _pad_pow2(leaves: jnp.ndarray, depth: int) -> jnp.ndarray:
     return leaves
 
 
-def u64_list_root(
-    vals: jnp.ndarray, n: int, limit_chunks_log2: int, zh: jnp.ndarray
-) -> jnp.ndarray:
+def u64_list_tail(vals: jnp.ndarray, n: int, limit_chunks_log2: int) -> ListTail:
     if n % 4:
         vals = jnp.concatenate([vals, jnp.zeros(4 - n % 4, jnp.uint64)])
-    chunks = (n + 3) // 4
     leaves = packed_u64_leaves(vals, vals.shape[0])
-    depth = max(chunks - 1, 0).bit_length() if n else 0
+    depth = _tree_depth((n + 3) // 4)
     sub = tree_root_words(_pad_pow2(leaves, depth), depth)
-    return mix_length(fold_to_limit(sub, depth, limit_chunks_log2, zh), n)
+    return ListTail(sub, depth, limit_chunks_log2, n)
 
 
-def u8_list_root(
-    vals: jnp.ndarray, n: int, limit_chunks_log2: int, zh: jnp.ndarray
-) -> jnp.ndarray:
+def u8_list_tail(vals: jnp.ndarray, n: int, limit_chunks_log2: int) -> ListTail:
     if n % 32:
         vals = jnp.concatenate([vals, jnp.zeros(32 - n % 32, jnp.uint8)])
-    chunks = (n + 31) // 32
     leaves = packed_u8_leaves(vals, vals.shape[0])
-    depth = max(chunks - 1, 0).bit_length() if n else 0
+    depth = _tree_depth((n + 31) // 32)
     sub = tree_root_words(_pad_pow2(leaves, depth), depth)
-    return mix_length(fold_to_limit(sub, depth, limit_chunks_log2, zh), n)
+    return ListTail(sub, depth, limit_chunks_log2, n)
 
 
 def _zero_u8_list_root_words(n: int) -> np.ndarray:
@@ -474,6 +524,21 @@ def state_root_real_hashes(meta: StateRootMeta) -> int:
     return hashes + (1 << meta.top_depth)
 
 
+def state_root_chain_steps(meta: StateRootMeta) -> int:
+    """Sequential one-message hash steps of one post_epoch_state_root
+    evaluation's list tails: the longest chain and the length mix, once,
+    however many lists ride it (:func:`list_tail_steps` of the depths
+    the program folds from)."""
+    n = meta.n_validators
+    shapes = [
+        (_tree_depth(n), VALIDATOR_REGISTRY_LIMIT_LOG2),
+        (_tree_depth((n + 3) // 4), BALANCE_LIMIT_CHUNKS_LOG2),  # scores: the same
+    ]
+    if any(name == "previous_epoch_participation" for _, name in meta.dynamic_slots):
+        shapes.append((_tree_depth((n + 31) // 32), PARTICIPATION_LIMIT_CHUNKS_LOG2))
+    return list_tail_steps(ListTail(None, d, limit, n) for d, limit in shapes)
+
+
 def slot_root_real_hashes(n: int, top_depth: int) -> int:
     """Compressions of one per-slot dirty-path root (balances + both
     participation columns + the top tree) — ONE accounting shared by the
@@ -534,6 +599,7 @@ def post_epoch_state_root(
     )
     obs.count("state_root.roots", 1)
     obs.count("state_root.real_hashes", real)
+    obs.count("state_root.chain_steps", state_root_chain_steps(meta))
     return out
 
 
@@ -616,15 +682,18 @@ def _post_epoch_state_root_impl(
     zh = arrays.zerohashes
     slot_of = {name: i for i, name in meta.dynamic_slots}
     dyn: dict[int, jnp.ndarray] = {}
-    dyn[slot_of["validators"]] = validator_registry_root(arrays, n, effective_balance)
-    dyn[slot_of["balances"]] = u64_list_root(balances, n, BALANCE_LIMIT_CHUNKS_LOG2, zh)
+    # every list's subtree first, then all their tails as lanes of one scan
+    tails = {
+        slot_of["validators"]: validator_registry_tail(arrays, n, effective_balance),
+        slot_of["balances"]: u64_list_tail(balances, n, BALANCE_LIMIT_CHUNKS_LOG2),
+    }
     if "inactivity_scores" in slot_of:
-        dyn[slot_of["inactivity_scores"]] = u64_list_root(
-            inactivity_scores, n, BALANCE_LIMIT_CHUNKS_LOG2, zh
+        tails[slot_of["inactivity_scores"]] = u64_list_tail(
+            inactivity_scores, n, BALANCE_LIMIT_CHUNKS_LOG2
         )
     if "previous_epoch_participation" in slot_of:
-        dyn[slot_of["previous_epoch_participation"]] = u8_list_root(
-            arrays.prev_part_flags, n, PARTICIPATION_LIMIT_CHUNKS_LOG2, zh
+        tails[slot_of["previous_epoch_participation"]] = u8_list_tail(
+            arrays.prev_part_flags, n, PARTICIPATION_LIMIT_CHUNKS_LOG2
         )
         # rotated-in current participation: all zero, length n — a
         # CONSTANT for fixed n, folded at trace time (host hashes), not
@@ -632,6 +701,7 @@ def _post_epoch_state_root_impl(
         dyn[slot_of["current_epoch_participation"]] = jnp.asarray(
             _zero_u8_list_root_words(n)
         )
+    dyn.update(list_roots(tails, zh))
     dyn.update(_small_dynamic_roots(slot_of, just))
     return combine_state_root(arrays, meta, dyn)
 
@@ -809,15 +879,29 @@ def build_state_forest(
         inact_nodes = merkle_inc.build_forest(
             _u64_chunk_leaves(inactivity_scores, n, plan.depth_bal), s
         )
-    part_root = u8_list_root(
-        arrays.prev_part_flags, n, PARTICIPATION_LIMIT_CHUNKS_LOG2, arrays.zerohashes
-    )
+    part_tail = u8_list_tail(arrays.prev_part_flags, n, PARTICIPATION_LIMIT_CHUNKS_LOG2)
+    part_root = list_roots({0: part_tail}, arrays.zerohashes)[0]
     return StateForest(
         val_nodes=val_nodes,
         bal_nodes=bal_nodes,
         inact_nodes=inact_nodes,
         part_root=part_root,
     )
+
+
+def _forest_tails(plan: ForestPlan, n: int, slot_of: dict, subtree_roots: dict) -> dict:
+    """The forest trees' roots as list tails by top-level slot, at the
+    plan's depths (the participation list is static in the resident
+    loop: it has no tail)."""
+    shape = {
+        "validators": (plan.depth_val, VALIDATOR_REGISTRY_LIMIT_LOG2),
+        "balances": (plan.depth_bal, BALANCE_LIMIT_CHUNKS_LOG2),
+        "inactivity_scores": (plan.depth_bal, BALANCE_LIMIT_CHUNKS_LOG2),
+    }
+    return {
+        slot_of[name]: ListTail(root, *shape[name], n)
+        for name, root in subtree_roots.items()
+    }
 
 
 def state_root_inc_real_hashes(meta: StateRootMeta, plan: ForestPlan) -> int:
@@ -896,8 +980,7 @@ def post_epoch_state_root_inc(
         plan.dense_val,
         mesh=mesh if s > 1 else None,
     )
-    full = fold_to_limit(sub_val, plan.depth_val, VALIDATOR_REGISTRY_LIMIT_LOG2, zh)
-    dyn[slot_of["validators"]] = mix_length(full, n)
+    subs = {"validators": sub_val}
 
     # -- u64 list columns: chunk-wise diff ------------------------------
     def u64_tree(nodes, old_vals, new_vals):
@@ -914,17 +997,15 @@ def post_epoch_state_root_inc(
             plan.dense_bal,
             mesh=mesh if s > 1 else None,
         )
-        full = fold_to_limit(sub, plan.depth_bal, BALANCE_LIMIT_CHUNKS_LOG2, zh)
-        return nodes, mix_length(full, n)
+        return nodes, sub
 
-    bal_nodes, dyn[slot_of["balances"]] = u64_tree(
-        forest.bal_nodes, old_balances, balances
-    )
+    bal_nodes, subs["balances"] = u64_tree(forest.bal_nodes, old_balances, balances)
     inact_nodes = forest.inact_nodes
     if plan.has_inact and "inactivity_scores" in slot_of:
-        inact_nodes, dyn[slot_of["inactivity_scores"]] = u64_tree(
+        inact_nodes, subs["inactivity_scores"] = u64_tree(
             forest.inact_nodes, old_inactivity_scores, inactivity_scores
         )
+    dyn.update(list_roots(_forest_tails(plan, n, slot_of, subs), zh))
 
     # -- static-in-the-loop participation lists -------------------------
     if "previous_epoch_participation" in slot_of:
@@ -965,19 +1046,13 @@ def state_root_from_forest(
     slot_of = {name: i for i, name in meta.dynamic_slots}
     dyn: dict[int, jnp.ndarray] = {}
 
-    sub_val = merkle_inc.forest_root(forest.val_nodes)
-    full = fold_to_limit(sub_val, plan.depth_val, VALIDATOR_REGISTRY_LIMIT_LOG2, zh)
-    dyn[slot_of["validators"]] = mix_length(full, n)
-
-    sub_bal = merkle_inc.forest_root(forest.bal_nodes)
-    dyn[slot_of["balances"]] = mix_length(
-        fold_to_limit(sub_bal, plan.depth_bal, BALANCE_LIMIT_CHUNKS_LOG2, zh), n
-    )
+    subs = {
+        "validators": merkle_inc.forest_root(forest.val_nodes),
+        "balances": merkle_inc.forest_root(forest.bal_nodes),
+    }
     if plan.has_inact and "inactivity_scores" in slot_of:
-        sub_in = merkle_inc.forest_root(forest.inact_nodes)
-        dyn[slot_of["inactivity_scores"]] = mix_length(
-            fold_to_limit(sub_in, plan.depth_bal, BALANCE_LIMIT_CHUNKS_LOG2, zh), n
-        )
+        subs["inactivity_scores"] = merkle_inc.forest_root(forest.inact_nodes)
+    dyn.update(list_roots(_forest_tails(plan, n, slot_of, subs), zh))
     if "previous_epoch_participation" in slot_of:
         dyn[slot_of["previous_epoch_participation"]] = forest.part_root
         dyn[slot_of["current_epoch_participation"]] = jnp.asarray(

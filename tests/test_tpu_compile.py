@@ -177,3 +177,32 @@ def test_sharded_epoch_step_compiles_for_four_described_chips(
     ("Supported lowering only of Sum all reduce") though it passes on any
     number of virtual CPU devices. parallel/epoch.py sends 16-bit limbs."""
     _check_row(chip_programs.compile_for(None, four_chip_programs["mesh:epoch+tree"]))
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations carry."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_state_root_at_2_20_compiles_with_the_unrolled_chain_body(one_chip, no_compile_cache):
+    """The served state-root program at a 2^20 registry: its four list
+    tails ride ONE scan of 21 steps (20 zero-hash folds and the length
+    mix) over [4, 8] roots, and that scan's body is the UNROLLED
+    compression behind its fusion barrier, no round scan inside it,
+    though four messages are far under sha256.SMALL_BATCH."""
+    prog = _program("state_root")
+    fn, args = prog.build()
+    with chip_programs.as_accelerator():
+        jaxpr = fn.trace(*args).jaxpr
+    chains = [
+        e for e in _eqns(jaxpr.jaxpr)
+        if e.primitive.name == "scan" and e.outvars[0].aval.shape == (4, 8)
+    ]
+    assert [e.params["length"] for e in chains] == [21]
+    body = {e.primitive.name for e in _eqns(chains[0].params["jaxpr"].jaxpr)}
+    assert "optimization_barrier" in body
+    assert not body & {"scan", "while"}
+    _check_row(chip_programs.compile_for(one_chip, prog))
