@@ -2,7 +2,7 @@
 
 PYTHON ?= python
 
-.PHONY: help install test test-fast lint speclint jaxlint rangelint reftests bytediff bench multichip recovery-smoke postmortem serve_docs coverage clean
+.PHONY: help install test test-fast lint speclint jaxlint rangelint reftests bytediff multichip recovery-smoke postmortem serve_docs coverage clean
 
 help:
 	@echo "install    - editable install with test extras"
@@ -17,8 +17,6 @@ help:
 	@echo "             limb intermediate wraps a lane (docs/analysis.md)"
 	@echo "reftests   - emit test vectors to ./test_vectors"
 	@echo "bytediff   - conformance byte-diff vs the compiled reference spec"
-	@echo "bench      - run the driver benchmark"
-	@echo "seed-device- one-time device-kernel compile into .jax_cache"
 	@echo "multichip  - 8-virtual-device sharding dry run"
 	@echo "postmortem - pretty-print the most recent flight-recorder bundle"
 	@echo "clean      - remove caches and generated vectors"
@@ -99,14 +97,6 @@ reftests:
 bytediff:
 	$(PYTHON) scripts/cross_gen_bytediff.py > BYTEDIFF_RESULT.json; \
 	s=$$?; cat BYTEDIFF_RESULT.json; exit $$s
-
-bench:
-	$(PYTHON) bench.py
-
-# one-time device-kernel compile into .jax_cache (accelerator required);
-# after this the bench's hybrid BLS section uses the device stages
-seed-device:
-	$(PYTHON) scripts/seed_device_cache.py
 
 multichip:
 	$(PYTHON) -c "import __graft_entry__ as g; g.dryrun_multichip(8); print('ok')"

@@ -35,15 +35,15 @@ def slot_world_shapes(n_validators: int):
     registry size — abstract shapes only, no registry is materialised."""
     import jax
 
-    import __graft_entry__ as graft
     from eth_consensus_specs_tpu.analysis import kernels
+    from eth_consensus_specs_tpu.ops.altair_epoch import example_altair_inputs
     from eth_consensus_specs_tpu.ops.slot_pipeline import slot_spec
     from eth_consensus_specs_tpu.ops.state_root import forest_plan, synthetic_meta
 
     spec = slot_spec()
     meta = synthetic_meta(spec, n_validators)
     arrays, _, just = kernels._state_root_args(meta)
-    cols = jax.eval_shape(lambda: graft._example_altair_inputs(n_validators)[0])
+    cols = jax.eval_shape(lambda: example_altair_inputs(n_validators)[0])
     return spec, arrays, meta, forest_plan(meta), cols, just
 
 
@@ -193,16 +193,16 @@ def mesh_programs(
     """The two programs of ``chip_smoke.py --chips 4`` over a (dp, sp)
     mesh of ``devices`` (four described chips in the sandbox): the served
     merkle_many flush with its tree axis sharded, and the sharded altair
-    epoch + sharded tree step of ``__graft_entry__``. Each argument
+    epoch + sharded tree step (``parallel/epoch.sharded_step``). Each argument
     carries the NamedSharding its program gives it (compile with
     ``compile_for(None, ...)``)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    import __graft_entry__ as graft
     from eth_consensus_specs_tpu.ops import merkle
-    from eth_consensus_specs_tpu.parallel import make_mesh
+    from eth_consensus_specs_tpu.ops.altair_epoch import example_altair_inputs
+    from eth_consensus_specs_tpu.parallel import epoch, make_mesh
     from eth_consensus_specs_tpu.parallel.mesh_ops import BATCH_AXES
 
     mesh = make_mesh(devices=list(devices))
@@ -220,10 +220,8 @@ def mesh_programs(
         return merkle._many_tree_root_sharded(mesh, tree_depth), (sds,)
 
     def sharded_step():
-        _, stepped, (cols_sh, just_sh, leaves_sh) = graft.sharded_step(mesh, step_depth)
-        cols, just = jax.eval_shape(
-            lambda: graft._example_altair_inputs(validators, electra=True)
-        )
+        _, stepped, (cols_sh, just_sh, leaves_sh) = epoch.sharded_step(mesh, step_depth)
+        cols, just = jax.eval_shape(lambda: example_altair_inputs(validators, electra=True))
         leaves = jax.ShapeDtypeStruct((1 << step_depth, 8), jnp.uint32, sharding=leaves_sh)
         return stepped, (placed(cols, cols_sh), placed(just, just_sh), leaves)
 

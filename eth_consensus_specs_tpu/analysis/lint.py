@@ -1161,7 +1161,6 @@ def check_env_stale(modules: list[ModuleInfo], declared_env: set[str],
     """Declared env vars nothing reads anywhere in the repo are stale."""
     read: set[str] = set()
     scan_roots = [os.path.join(repo_root, d) for d in (PACKAGE, "scripts", "tests")]
-    scan_roots.append(os.path.join(repo_root, "bench.py"))
     pat = re.compile(r"ETH_SPECS_[A-Z0-9_]+")
     for root in scan_roots:
         paths = []

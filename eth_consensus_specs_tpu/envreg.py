@@ -349,15 +349,6 @@ ENV_VARS: tuple[EnvVar, ...] = (
     _v("ETH_SPECS_TPU_NO_NATIVE", "0",
        "`1`: skip the native (CFFI) BLS fast paths, pure-python/device only",
        "tpu.md"),
-    _v("ETH_SPECS_TPU_DEVICE_H2C", "0",
-       "`1`: prime hash-to-G2 through the batched device kernel (host "
-       "fallback per miss)", "tpu.md"),
-    _v("ETH_SPECS_TPU_DEVICE_PAIRING", "0",
-       "`1`: force DEVICE pairing even when the bls backend switch is "
-       "elsewhere (bench hybrid mode)", "tpu.md"),
-    _v("ETH_SPECS_TPU_NO_DEVICE_PAIRING", "0",
-       "`1`: force HOST pairing even under the tpu backend (XLA:CPU fallback "
-       "benches)", "tpu.md"),
     _v("ETH_SPECS_TPU_OBJECT_EPOCH", "0",
        "`1`: route epoch accounting through the object-mode reference path "
        "instead of the columnar kernel", "tpu.md"),
@@ -368,12 +359,6 @@ ENV_VARS: tuple[EnvVar, ...] = (
     _v("ETH_SPECS_REFERENCE", "unset",
        "path to a reference consensus-specs checkout for specc compilation",
        "testing.md"),
-    _v("ETH_SPECS_BENCH_CPU_TIMEOUT", "120",
-       "bench section budget on CPU, seconds", "tpu.md"),
-    _v("ETH_SPECS_BENCH_ACC_TIMEOUT", "600",
-       "bench section budget on accelerators, seconds", "tpu.md"),
-    _v("ETH_SPECS_BENCH_VERIFY_TIMEOUT", "60",
-       "bench correctness-verification budget, seconds", "tpu.md"),
 )
 
 

@@ -6,8 +6,8 @@ injected fault) it retries once through `retrying` — transient allocator
 pressure and nth-shot injections recover here — then falls back to the
 host oracle so the run completes slower rather than not at all. Logic
 errors (anything that doesn't classify as a device failure) propagate:
-masking a real bug behind the oracle would un-couple the two legs the
-bench correctness story depends on. A COMPILE refusal is a logic error
+masking a real bug behind the oracle would un-couple the device path
+from the host oracle it is checked against. A COMPILE refusal is a logic error
 in this sense: the compiler rejecting a kernel is not a device dying
 under load, and answering from the host would hide that the device path
 does not exist on this machine.

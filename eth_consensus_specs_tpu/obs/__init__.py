@@ -1,9 +1,7 @@
 """obs — kernel-level observability: spans, op counters, divergence watchdog.
 
-The reference pyspec has no tracing at all (SURVEY §5); this repo spent
-four rounds publishing a physically impossible 878 Ghash/s because the
-only correctness/roofline gates lived in a private bench script. This
-package makes the discipline ambient:
+The reference pyspec has no tracing at all (SURVEY §5). This package
+makes timing, counting and device-vs-host checking ambient:
 
   * ``obs.span("epoch.justification", work_bytes=...)`` — nested timed
     regions with block_until_ready semantics, mirrored into the jax
@@ -11,8 +9,8 @@ package makes the discipline ambient:
     roofline verdict attached to every timing that declares its traffic;
   * ``obs.count("sha256.compressions", n)`` / ``obs.bytes_moved(...)``
     — thread-safe process counters the hot paths report into;
-  * ``obs.gates`` — the roofline/digest gate logic (extracted from
-    bench.py) as the single shared implementation;
+  * ``obs.gates`` — the roofline/digest gate logic as the single
+    shared implementation;
   * ``obs.watchdog`` — always-on sampled device-vs-host recompute of
     result slices, recording match/mismatch as first-class metrics;
   * a JSONL event sink (``ETH_SPECS_OBS_JSONL=<path>``) and a pytest
@@ -52,17 +50,16 @@ Postmortem/attribution layer (obs/flight.py + obs/xprof.py):
     compiler's bytes-accessed (advisory
     ``xprof.cost_model_mismatch`` counter past tolerance).
 
-Waterfall layer (obs/waterfall.py + obs/devprof.py + obs/ledger.py):
+Waterfall layer (obs/waterfall.py + obs/ledger.py):
 
   * ``obs.waterfall`` — the request stage clock: every serve Request
     carries a monotonic stamp vector; resolve folds it into contiguous
     ``serve.stage_ms.<stage>`` histograms (unattributed time is a
     first-class ``other`` stage) and a bounded trace-id stash carries
     durations across the replica wire, so the front door attributes
-    fleet-wide p99 by stage (docs/observability.md).
-  * ``obs.devprof`` — measured device execution time per dispatch
-    (``device.exec_ms.<kernel>``) with roofline verdicts from MEASURED
-    seconds.
+    fleet-wide p99 by stage (docs/observability.md);
+    ``waterfall.leg`` splits the device stage into named legs
+    (``serve.stage_ms.device.<leg>``).
   * ``obs.ledger`` — the HBM residency ledger: long-lived device
     buffers register bytes per owner (``hbm.resident_bytes.<owner>``
     gauges, high-water via gauge max), embedded in every postmortem
@@ -90,7 +87,6 @@ Environment:
 from __future__ import annotations
 
 from . import (  # noqa: F401  (public submodules)
-    devprof,
     export,
     flight,
     gates,

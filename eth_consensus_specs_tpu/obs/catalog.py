@@ -203,12 +203,6 @@ CATALOG: tuple[Metric, ...] = (
     _s("serve.dispatch", "one batched device dispatch"),
     _s("serve.batch_wait", "the batch thread waiting for a request or its deadline"),
     _s("serve.prep", "host prep of one flush on the batch thread"),
-    # ------------------------------------------------------------- device --
-    _h("device.exec_ms", "measured device execution ms per dispatch (devprof)"),
-    _h("device.exec_ms.*", "measured device execution ms per kernel"),
-    _c("device.roofline_violations",
-       "measured device timings implying impossible bandwidth"),
-    _c("device.roofline_violations.*", "measured-roofline violations per kernel"),
     # ---------------------------------------------------------------- hbm --
     _g("hbm.resident_bytes.*", "ledger-registered device bytes per owner"),
     _g("hbm.resident_bytes_total", "ledger-registered device bytes, all owners"),

@@ -1,12 +1,9 @@
 """Throughput gates — roofline verdicts + result digests, ONE implementation.
 
-Round 5 proved the discipline: a platform that acknowledges work before
-executing it produced 878 Ghash/s (~84 TB/s of implied HBM traffic) that
-survived four rounds because the gate logic lived privately inside
-bench.py. This module is that logic promoted to framework infrastructure,
-consumed by
+A platform that acknowledges work before executing it once produced
+878 Ghash/s (~84 TB/s of implied HBM traffic); the gate that refuses
+such a timing lives here, consumed by
 
-  * bench.py           — refuses unverified / impossible-rate sections;
   * obs/registry.py    — attaches a roofline verdict to every timed span
                          that declares its ``work_bytes``;
   * obs/watchdog.py    — digests device-vs-host slices;
@@ -14,12 +11,13 @@ consumed by
                          cross-generator byte-diff can compare runs from
                          the observability stream alone;
   * tests              — assert the verdict/digest semantics directly.
+
+The benchmark's own peaks are in ``benchmark/peaks.py``.
 """
 
 from __future__ import annotations
 
 import hashlib
-import sys
 from functools import lru_cache
 
 import numpy as np
@@ -60,20 +58,11 @@ def roofline_bytes_s(device_kind: str | None = None) -> float:
             "a sourced row to obs/gates.py PEAK_BYTES_S"
         ) from None
 
-# Per-unit seconds field of each bench section's fragment.
-UNIT_KEY = {
-    "tree": "tree_s",
-    "epoch": "epoch_s",
-    "resident": "per_epoch_s",
-    "das": "round_s",
-    "block_epoch": "epoch_s",
-}
-
 
 def digest(data) -> str:
     """Canonical short fingerprint of a result: ndarray (contiguous bytes)
-    or raw bytes — the digest bench verification and the gen byte-diff
-    stream both key on."""
+    or raw bytes — what the watchdog's divergence events and the gen
+    byte-diff stream key on."""
     if isinstance(data, (bytes, bytearray, memoryview)):
         raw = bytes(data)
     else:
@@ -89,28 +78,3 @@ def roofline_verdict(work_bytes: float, seconds: float) -> dict:
         "implied_gbps": round(implied / 1e9, 1),
         "roofline_ok": implied <= roofline_bytes_s(),
     }
-
-
-def apply_gates(section: str, frag: dict, unit_key: str) -> dict:
-    """Attach implied-traffic and roofline verdicts to an accelerator
-    fragment. unit_key names the per-unit seconds field."""
-    wb = frag.get("work_bytes")
-    unit_s = frag.get(unit_key)
-    if wb and unit_s:
-        frag.update(roofline_verdict(wb, unit_s))
-        if not frag["roofline_ok"]:
-            print(
-                f"[bench] section {section}: REFUSED — implied "
-                f"{wb / unit_s / 1e9:.0f} GB/s exceeds the "
-                f"{roofline_bytes_s() / 1e9:.0f} GB/s single-chip roofline; "
-                "the timing cannot reflect real execution",
-                file=sys.stderr,
-            )
-    return frag
-
-
-def digests_match(expected: str | None, actual: str | None) -> bool:
-    """The correctness-coupling check: a device measurement is only real
-    when its result digest equals the host recompute's on the SAME salted
-    inputs. Missing digests never match — unverifiable is refused."""
-    return expected is not None and actual is not None and expected == actual

@@ -226,8 +226,7 @@ def burn_rate(snap: dict | None = None, window_s: float | None = None) -> dict |
     ``"window_s"`` when capped) or None when no window qualifies. A p99
     SLO that only breaches at the end of a long run looks fine in the
     run-wide histogram; the burn rate says how much of the RUN was
-    spent out of budget. Advisory, never gating — perf_track ingests
-    it as a secondary (lower is better)."""
+    spent out of budget. Advisory, never gating (lower is better)."""
     if window_s is not None:
         cutoff = time.monotonic() - float(window_s)
         with _WINDOWS_LOCK:

@@ -1,9 +1,8 @@
 """Always-on device/host divergence watchdog.
 
-Round 4's failure mode — an accelerator platform acknowledging work
-before executing it — is only caught by *continuously* coupling device
-results to host recomputes, not just inside bench.py. This module
-samples the kernel hot paths at an env-tunable rate and recomputes a
+An accelerator platform acknowledging work before executing it is only
+caught by *continuously* coupling device results to host recomputes.
+This module samples the kernel hot paths at an env-tunable rate and recomputes a
 (salted, where an extra dispatch is involved) slice of each device
 result on the host with an engine that shares nothing with XLA
 (hashlib / the pure spec loop / the host pairing). Match/mismatch lands

@@ -42,12 +42,14 @@ import jax
 
 import eth_consensus_specs_tpu  # noqa: F401  (package import enables x64)
 import jax.numpy as jnp
+import numpy as np
 
 from .state_columns import (
     JustificationState,
     LocalReductions,
     _LOCAL,
     _total_balance,
+    example_inputs,
     isqrt_u64,
     justification_update,
 )
@@ -137,6 +139,37 @@ class AltairEpochResult(NamedTuple):
     cur_justified_root: jnp.ndarray
     finalized_epoch: jnp.ndarray
     finalized_root: jnp.ndarray
+
+
+def example_altair_inputs(n_validators: int, epoch: int = 10, electra: bool = False):
+    """Columnar altair+ state: flags/scores instead of attestation masks.
+    With `electra`, a per-validator MaxEB column (EIP-7251 compounding:
+    ~10% of validators at the 2048 ETH ceiling) rides along."""
+    cols, just = example_inputs(n_validators, epoch=epoch)
+    rng = np.random.default_rng(4321)
+    n = n_validators
+    prev_flags = (
+        rng.integers(0, 2, n) * 1 + rng.integers(0, 2, n) * 2 + rng.integers(0, 2, n) * 4
+    ).astype(np.uint8)
+    max_eff = None
+    if electra:
+        compounding = rng.random(n) < 0.1
+        max_eff = np.where(
+            compounding, np.uint64(2_048_000_000_000), np.uint64(32_000_000_000)
+        ).astype(np.uint64)
+    acols = AltairEpochColumns(
+        effective_balance=cols.effective_balance,
+        balance=cols.balance,
+        slashed=cols.slashed,
+        activation_epoch=cols.activation_epoch,
+        exit_epoch=cols.exit_epoch,
+        withdrawable_epoch=cols.withdrawable_epoch,
+        prev_flags=prev_flags,
+        cur_tgt_att=cols.cur_tgt_att,
+        inactivity_scores=rng.integers(0, 50, n).astype(np.uint64),
+        max_effective_balance=max_eff,
+    )
+    return acols, just
 
 
 def altair_epoch_accounting_impl(

@@ -333,7 +333,7 @@ class BlockEpochStatic(NamedTuple):
 
 
 def make_epoch_static(params, eff_balance, withdrawable_epoch, has_eth1_cred, epoch):
-    active = eff_balance  # bench model: all validators active
+    active = eff_balance  # the synthetic world: all validators active
     total = jnp.maximum(
         jnp.sum(active), U64(params.effective_balance_increment)
     )
@@ -361,8 +361,8 @@ def block_epoch_chain(
     """Scan an epoch of blocks over the dense plane inside one jit.  With
     `root_ctx` (see `make_root_ctx`) each slot also recomputes the dirty
     state-root subtrees (balances + both participation columns + the slot
-    chunk over the cached static tree) and xor-chains the root — the
-    chained-dependency shape bench.py times.  Returns (BlockState,
+    chunk over the cached static tree) and xor-chains the root, so no
+    slot's root can be skipped or reordered.  Returns (BlockState,
     root_acc u32[8])."""
     if obs.tracing(st.balance):
         obs.count("block_epoch.traces", 1)
@@ -449,9 +449,9 @@ def _block_epoch_chain_host(
     with_withdrawals: bool,
 ):
     """fault.degrade fallback for block_epoch_chain: the sequential numpy
-    replay + native-sha slot roots (ops/block_epoch_host.py) — the same
-    independent leg the bench correctness coupling uses, repackaged into
-    the kernel's (BlockState, root_acc) contract."""
+    replay + native-sha slot roots (ops/block_epoch_host.py) — the
+    independent leg the tests compare the kernel against, repackaged
+    into the kernel's (BlockState, root_acc) contract."""
     from eth_consensus_specs_tpu.ops.block_epoch_host import (
         replay_block_epoch_np,
         slot_root_fn_from_ctx,

@@ -1,8 +1,7 @@
-"""Numpy host oracle for ops/block_epoch.py — the independent leg of the
-block-epoch bench's correctness coupling (same contract as
-ops/state_root_host.py: no XLA in the replay, native-SHA trees), and a
-third implementation corner for tests (object path <-> device kernel <->
-this oracle)."""
+"""Numpy host oracle for ops/block_epoch.py — its fault.degrade fallback
+(same contract as ops/state_root_host.py: no XLA in the replay,
+native-SHA trees), and a third implementation corner for tests (object
+path <-> device kernel <-> this oracle)."""
 
 from __future__ import annotations
 

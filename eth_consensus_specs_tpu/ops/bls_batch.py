@@ -24,6 +24,7 @@ attestation surfaces at the exact spec assertion.
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 import secrets
 import threading
@@ -55,9 +56,9 @@ def _use_device() -> bool:
     return bls.backend_name() == "tpu"
 
 
-# hash-to-G2 results keyed by (dst, message) — primed in one batched
-# device dispatch when ETH_SPECS_TPU_DEVICE_H2C is on; host fallback per
-# miss.  The dst is part of the key so a caller priming under one domain
+# hash-to-G2 results keyed by (dst, message): `_rlc_check` primes a
+# check's distinct messages, `_h2g2` hashes on the host per miss. The
+# dst is part of the key so a caller priming under one domain
 # can never serve a point to a reader under another.  All mutation holds
 # _H2G2_LOCK: the serving layer's micro-batcher verifies off-thread, and
 # an unlocked evict (clear + update) racing a concurrent prime could
@@ -112,24 +113,12 @@ def _h2g2(msg: bytes, dst: bytes = DST_G2):
 
 
 def _pairing_check_routed(pairs, mesh=None) -> bool:
-    """Device Miller loop + membership check under the tpu backend; the
-    host/native pairing elsewhere. Both are bit-equivalent implementations
-    of the same check (tests/test_pairing_device.py), so routing can never
-    flip a verification result. Env overrides (both read per call, so a
-    parent process can steer a child):
-
-      ETH_SPECS_TPU_NO_DEVICE_PAIRING=1  force HOST pairing even under the
-        tpu backend (bench's XLA:CPU fallback, where the device pairing's
-        one-time compile would eat the whole section budget);
-      ETH_SPECS_TPU_DEVICE_PAIRING=1     force DEVICE pairing even when the
-        bls backend switch is elsewhere — the bench's hybrid mode: host C
-        aggregation (one core, no dispatch round-trips) + the one batched
-        Miller/final-exp on the accelerator."""
-    import os
-
-    if os.environ.get("ETH_SPECS_TPU_NO_DEVICE_PAIRING"):
-        return pairing_check(pairs)
-    if _use_device() or os.environ.get("ETH_SPECS_TPU_DEVICE_PAIRING"):
+    """Device Miller loop + membership check under the tpu backend
+    (``bls.use_tpu()``); the host/native pairing elsewhere. Both are
+    bit-equivalent implementations of the same check
+    (tests/test_pairing_device.py), so routing can never flip a
+    verification result."""
+    if _use_device():
         from eth_consensus_specs_tpu.ops.pairing_device import pairing_check_device
 
         return pairing_check_device(pairs, mesh=mesh)
@@ -189,7 +178,7 @@ def batch_verify_aggregates(
         ok, parsed = _batch_verify_impl(items, mesh=mesh)
     # the watchdog's host-pairing recompute runs AFTER the span closes
     # (like sha256/merkle/shuffle): the probe must never be clocked as
-    # kernel time — in the obs report or in bench's timed region
+    # kernel time in the obs report
     if ok and parsed and watchdog.should_check("bls_batch"):
         # a True batch verdict must reproduce for any member item through
         # the plain host pairing (no device MSM, no routed pairing, no
@@ -278,7 +267,8 @@ def _batch_verify_impl(
             return False, None
         parsed.append(p)
     rpk = _rlc_pubkey_terms(parsed, mesh=mesh)
-    return _rlc_pairing_check(parsed, rpk, mesh=mesh), parsed
+    pairing = functools.partial(_pairing_check_routed, mesh=mesh)
+    return _rlc_check(parsed, rpk, pairing), parsed
 
 
 def _rlc_pubkey_terms(parsed: list, mesh=None) -> list:
@@ -448,10 +438,12 @@ def _fold_signatures(parsed: list):
     return multi_exp([sig for _, _, sig, _ in parsed], [r for _, _, _, r in parsed])
 
 
-def _served_rlc_check(parsed: list, rpk: list) -> bool:
-    """One random-linear-combination pairing over a served flush or a
-    subset of it, each leg through the C core (above) and under its own
-    clock; one sample of ``bls.rlc_check_ms`` a check."""
+def _rlc_check(parsed: list, rpk: list, pairing) -> bool:
+    """One random-linear-combination pairing over a batch, a served flush
+    or a subset of it. Hash-to-G2 and the G2 fold go through the C core
+    (above), the pairs to the ``pairing`` callable the entry point hands
+    in; each leg under its own clock, one sample of ``bls.rlc_check_ms``
+    a check."""
     t0 = time.perf_counter()
     merged = _merge_by_message(parsed, rpk)
     with waterfall.leg("bls.h2c"):
@@ -465,27 +457,9 @@ def _served_rlc_check(parsed: list, rpk: list) -> bool:
     obs.count("bls.pairing_inputs", len(pairs))
     obs.count("bls.messages_distinct", len(merged))
     with waterfall.leg("bls.pairing"):
-        ok = pairing_check(pairs)
+        ok = pairing(pairs)
     obs.observe("bls.rlc_check_ms", (time.perf_counter() - t0) * 1e3)
     return ok
-
-
-def _rlc_pairing_check(parsed: list, rpk: list, mesh=None) -> bool:
-    merged = _merge_by_message(parsed, rpk)
-    # optional device hash-to-curve: one batched dispatch maps every
-    # distinct message (ops/h2c_device — bit-equal to the host path, so
-    # routing can never flip a result); opt-in via env because the
-    # one-time compile only pays off on a real accelerator
-    if os.environ.get("ETH_SPECS_TPU_DEVICE_H2C") and len(merged) > 1:
-        from eth_consensus_specs_tpu.ops.h2c_device import hash_to_g2_device
-
-        _prime_h2g2_cache(list(merged.keys()), hash_to_g2_device)
-    pairs = [(rp, _h2g2(msg)) for msg, rp in merged.items()]
-    pairs.append((-g1_generator(), _fold_signatures(parsed)))
-    obs.count("bls.pairings", 1)
-    obs.count("bls.pairing_inputs", len(pairs))
-    obs.count("bls.messages_distinct", len(merged))
-    return _pairing_check_routed(pairs, mesh=mesh)
 
 
 def verify_many(items: list[tuple], mesh=None, keys=None) -> list[bool]:
@@ -498,9 +472,8 @@ def verify_many(items: list[tuple], mesh=None, keys=None) -> list[bool]:
     MSM + pairing per subset, so each invalid item costs ~2*log2(n)
     pairings instead of n.
 
-    Each leg runs where `_served_pubkey_terms` and `_served_rlc_check`
-    put it. With a multi-device ``mesh`` the per-item G1 terms shard their
-    item axis (the indices where the signers are in the key table, the
+    Each leg runs where `_served_pubkey_terms` and `_rlc_check` put it.
+    With a multi-device ``mesh`` the per-item G1 terms shard their item axis (the indices where the signers are in the key table, the
     table replicated; the packed points otherwise); the terms are
     canonical affine points whichever side summed them, so the bisection
     re-checks subsets with the SAME terms and verdicts stay bit-identical
@@ -537,7 +510,7 @@ def verify_many(items: list[tuple], mesh=None, keys=None) -> list[bool]:
 
 
 def _bisect_rlc(parsed: list, rpk: list) -> list[bool]:
-    if _served_rlc_check(parsed, rpk):
+    if _rlc_check(parsed, rpk, pairing_check):
         return [True] * len(parsed)
     if len(parsed) == 1:
         return [False]

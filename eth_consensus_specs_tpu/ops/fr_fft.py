@@ -62,7 +62,7 @@ def _stage_twiddles(roots: tuple, n: int) -> list[np.ndarray]:
 
 def fft_stages(vals, twiddles, n: int):
     """The DIT butterfly stage chain over bit-reversed input — the single
-    shared kernel body (also what bench.py's chained measurement runs).
+    shared kernel body.
 
     vals: [B, n, L] Montgomery limbs; twiddles: one [m, L] table per stage."""
     out = vals

@@ -362,10 +362,6 @@ def hash_to_g2_device(msgs: list[bytes], dst: bytes = h2c.DST_G2):
         rows[i] = np.stack([tw.fq2_to_limbs(u0), tw.fq2_to_limbs(u1)])
     ax, ay, inf = _h2c_core(jnp.asarray(rows))
     ax_h, ay_h, inf_h = np.asarray(ax), np.asarray(ay), np.asarray(inf)
-    # results are materialized on host — only now is "warm" true
-    from eth_consensus_specs_tpu.utils.cache import mark_warm
-
-    mark_warm("h2c")
     out = []
     for i in range(len(msgs)):
         if inf_h[i]:

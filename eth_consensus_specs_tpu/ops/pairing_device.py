@@ -473,14 +473,7 @@ def pairing_check_device(pairs: list, mesh=None) -> bool:
     objects (subgroup-checked at deserialization)."""
     if not pairs:
         return True
-    ok = bool(final_exp_is_one(_miller_product(pairs, mesh=mesh)))
-    # the bool() above materialized the device result — record the warm
-    # chain for the bench's sentinel gating (utils/cache.mark_warm is a
-    # no-op without the persistent cache or on cpu)
-    from eth_consensus_specs_tpu.utils.cache import mark_warm
-
-    mark_warm("pairing")
-    return ok
+    return bool(final_exp_is_one(_miller_product(pairs, mesh=mesh)))
 
 
 _PREP_CACHE: dict = {}

@@ -299,7 +299,6 @@ def slot_apply_device(
     import jax
     import jax.numpy as jnp
 
-    from eth_consensus_specs_tpu.obs import devprof
     from eth_consensus_specs_tpu.serve import buckets
 
     arrays, meta = static
@@ -324,25 +323,21 @@ def slot_apply_device(
     r_idx[: len(reward_idx)] = reward_idx
     r_amt[: len(reward_amt)] = reward_amt
     run = _compiled_slot_apply(meta, plan, mesh, p_flags, p_rewards)
-    work = 2 * sum(
-        int(a.nbytes) for a in (cols.balance, cols.prev_flags, cols.cur_tgt_att)
-    )
     with buckets.first_dispatch(*key):
-        with devprof.measure("slot_apply", work_bytes=work):
-            new_balance, new_flags, new_tgt, forest, root = run(
-                jax.device_put(arrays),
-                forest,
-                cols.balance,
-                cols.effective_balance,
-                cols.inactivity_scores,
-                cols.prev_flags,
-                cols.cur_tgt_att,
-                just,
-                jnp.asarray(f_idx),
-                jnp.asarray(f_on),
-                jnp.asarray(r_idx),
-                jnp.asarray(r_amt),
-            )
+        new_balance, new_flags, new_tgt, forest, root = run(
+            jax.device_put(arrays),
+            forest,
+            cols.balance,
+            cols.effective_balance,
+            cols.inactivity_scores,
+            cols.prev_flags,
+            cols.cur_tgt_att,
+            just,
+            jnp.asarray(f_idx),
+            jnp.asarray(f_on),
+            jnp.asarray(r_idx),
+            jnp.asarray(r_amt),
+        )
     new_cols = cols._replace(
         balance=new_balance, prev_flags=new_flags, cur_tgt_att=new_tgt
     )

@@ -35,6 +35,7 @@ import jax
 
 import eth_consensus_specs_tpu  # noqa: F401  (package import enables x64)
 import jax.numpy as jnp
+import numpy as np
 
 U64 = jnp.uint64
 
@@ -127,6 +128,64 @@ class EpochResult(NamedTuple):
     finalized_root: jnp.ndarray
     rewards: jnp.ndarray  # attestation-delta rewards (parity debugging)
     penalties: jnp.ndarray  # attestation-delta penalties
+
+
+def example_inputs(n_validators: int, epoch: int = 10, slashings_half_vector: int = 4096):
+    """Deterministic, spec-plausible columnar state (no RNG seed games:
+    fixed generator seed, valid ranges for every column).
+    `slashings_half_vector` = EPOCHS_PER_SLASHINGS_VECTOR // 2 of the preset
+    the columns will run under (mainnet 4096) — half the slashed validators
+    land exactly inside the correlated-slashing penalty window so that term
+    is live in every driver check."""
+    rng = np.random.default_rng(1234)
+    n = n_validators
+    max_eff = np.uint64(32_000_000_000)
+    incr = np.uint64(1_000_000_000)
+    eff = (rng.integers(17, 33, n).astype(np.uint64)) * incr
+    bal = eff + rng.integers(0, 10**9, n).astype(np.uint64)
+    slashed = rng.random(n) < 0.01
+    act = np.zeros(n, np.uint64)
+    exitep = np.full(n, np.iinfo(np.uint64).max, np.uint64)
+    exited = rng.random(n) < 0.02
+    exitep[exited] = epoch - 1
+    wd = np.full(n, np.iinfo(np.uint64).max, np.uint64)
+    in_window = slashed & (rng.random(n) < 0.5)
+    wd[slashed] = epoch + 4  # slashed but outside the penalty window
+    wd[in_window] = epoch + slashings_half_vector  # penalty applies
+    src = rng.random(n) < 0.9
+    tgt = src & (rng.random(n) < 0.95)
+    head = tgt & (rng.random(n) < 0.9)
+    cur_tgt = rng.random(n) < 0.8
+    delay = rng.integers(1, 9, n).astype(np.uint64)
+    proposer = rng.integers(0, n, n)
+    cols = EpochColumns(
+        effective_balance=np.minimum(eff, max_eff),
+        balance=bal,
+        slashed=slashed,
+        activation_epoch=act,
+        exit_epoch=exitep,
+        withdrawable_epoch=wd,
+        src_att=src,
+        tgt_att=tgt,
+        head_att=head,
+        cur_tgt_att=cur_tgt,
+        incl_delay=delay,
+        incl_proposer=proposer,
+    )
+    just = JustificationState(
+        current_epoch=np.uint64(epoch),
+        justification_bits=np.array([True, True, False, False]),
+        prev_justified_epoch=np.uint64(epoch - 2),
+        prev_justified_root=np.frombuffer(b"\x01" * 32, np.uint8),
+        cur_justified_epoch=np.uint64(epoch - 1),
+        cur_justified_root=np.frombuffer(b"\x02" * 32, np.uint8),
+        finalized_epoch=np.uint64(epoch - 3),
+        finalized_root=np.frombuffer(b"\x03" * 32, np.uint8),
+        block_root_prev=np.frombuffer(b"\x04" * 32, np.uint8),
+        block_root_cur=np.frombuffer(b"\x05" * 32, np.uint8),
+        slashings_sum=np.uint64(64_000_000_000),
+    )
+    return cols, just
 
 
 def isqrt_u64(x: jnp.ndarray) -> jnp.ndarray:

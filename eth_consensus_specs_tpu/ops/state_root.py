@@ -492,8 +492,8 @@ def synthetic_static(spec, n: int, seed: int = 0) -> tuple[StateRootArrays, Stat
     )
     try:
         # creation-site HBM booking (obs/ledger.py): this static tree is
-        # resident for as long as the caller keeps it — bench processes
-        # hold it across the whole run
+        # resident for as long as the caller keeps it — a service holds
+        # it across its whole life
         from eth_consensus_specs_tpu.obs import ledger
 
         ledger.register(
@@ -541,9 +541,8 @@ def state_root_chain_steps(meta: StateRootMeta) -> int:
 
 def slot_root_real_hashes(n: int, top_depth: int) -> int:
     """Compressions of one per-slot dirty-path root (balances + both
-    participation columns + the top tree) — ONE accounting shared by the
-    block_epoch span instrumentation and bench.py's block_epoch section,
-    so their roofline verdicts can never disagree on the same timing."""
+    participation columns + the top tree) — the accounting behind the
+    block_epoch span's ``work_bytes`` and its hash counters."""
     from eth_consensus_specs_tpu.ops.merkle import tree_real_hashes
 
     return (

@@ -2,10 +2,9 @@
 
 Same tree, different engine: numpy word-wrangling + the native C SHA-256
 core (SHA-NI when the host has it), no XLA anywhere in the hash path.
-Purpose is CORRECTNESS-COUPLED benchmark timing (round-4 verdict weak #1:
-device numbers were published without any check that the device actually
-did the work) and an independent leg for tests: device result ==
-host-oracle result on the SAME inputs, or the number is not published.
+It is the independent leg of the tests, of `chip_smoke.py` and of the
+service's host fallback: device result == host-oracle result on the
+SAME inputs.
 
 The reference's equivalent of this oracle is its per-node hashlib path
 (reference: tests/core/pyspec/eth2spec/utils/merkle_minimal.py:47-91 and
@@ -62,9 +61,8 @@ def tree_root_np(leaves: np.ndarray, depth: int) -> np.ndarray:
 def tree_root_chain_np(
     base: np.ndarray, depth: int, chain: int, salt: np.ndarray
 ) -> np.ndarray:
-    """Host recompute of the bench's chained device tree (bench.py tree
-    section): `chain` iterations of root = tree(base ^ root), starting
-    from the salt words.  Only the LOGICAL nodes are hashed — the device
+    """Host recompute of a chained device tree: `chain` iterations of
+    root = tree(base ^ root), starting from the salt words.  Only the LOGICAL nodes are hashed — the device
     kernel's full-width overhead never reaches the root value."""
     acc = salt.astype(np.uint32)
     for _ in range(chain):

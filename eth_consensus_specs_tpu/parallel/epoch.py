@@ -217,3 +217,34 @@ def make_sharded_epoch_fn(mesh: Mesh, params: EpochParams):
         in_shardings=(to_sh(cols_spec), to_sh(just_spec)),
         out_shardings=to_sh(res_spec),
     )
+
+
+def sharded_step(mesh, depth: int):
+    """(epoch params, the jitted sharded step, its input shardings) over
+    ``mesh`` — built from the mesh alone, so the sandbox can compile it
+    for a DESCRIBED four-chip topology (scripts/tpu_compile_inventory.py
+    --mesh) before a real one is paid for."""
+    from eth_consensus_specs_tpu.forks import get_spec
+
+    from .merkle import tree_root_sharded_fn
+
+    params = AltairEpochParams.from_spec(get_spec("electra", "mainnet"))
+    tree_fn = tree_root_sharded_fn(mesh, depth)
+    epoch_fn = sharded_altair_epoch_fn(mesh, params, with_max_effective_balance=True)
+    cols_spec, just_spec, res_spec = altair_epoch_specs(with_max_effective_balance=True)
+    to_sh = lambda tree: jax.tree_util.tree_map(
+        lambda s: NamedSharding(mesh, s), tree, is_leaf=lambda x: isinstance(x, P)
+    )
+
+    def full_step(c, j, lv):
+        res = epoch_fn(c, j)
+        root = tree_fn(lv)
+        return res, root
+
+    in_sh = (to_sh(cols_spec), to_sh(just_spec), NamedSharding(mesh, P(SP_AXIS)))
+    stepped = jax.jit(
+        full_step,
+        in_shardings=in_sh,
+        out_shardings=(to_sh(res_spec), NamedSharding(mesh, P())),
+    )
+    return params, stepped, in_sh

@@ -95,12 +95,12 @@ class ResidentOwner:
         is what makes cold re-ingest a correct recovery leg."""
         import jax
 
-        import __graft_entry__ as graft
+        from eth_consensus_specs_tpu.ops.altair_epoch import example_altair_inputs
         from eth_consensus_specs_tpu.ops.slot_pipeline import slot_spec
         from eth_consensus_specs_tpu.ops.state_root import synthetic_static
 
         self._spec = slot_spec()
-        cols, just = graft._example_altair_inputs(self.cfg.resident_validators)
+        cols, just = example_altair_inputs(self.cfg.resident_validators)
         self._static = synthetic_static(self._spec, self.cfg.resident_validators)
         return jax.device_put(cols), jax.device_put(just)
 

@@ -70,7 +70,7 @@ import numpy as np
 
 from eth_consensus_specs_tpu import fault, obs
 from eth_consensus_specs_tpu.analysis import lockwatch
-from eth_consensus_specs_tpu.obs import devprof, trace, waterfall, xprof
+from eth_consensus_specs_tpu.obs import trace, waterfall, xprof
 from eth_consensus_specs_tpu.obs.histogram import Histogram
 from eth_consensus_specs_tpu.parallel import mesh_ops
 
@@ -472,16 +472,11 @@ class VerifyService:
                 # many-sum dispatch in first_dispatch, keyed by the
                 # shared many_sum_shape bucket + mesh signature), so the
                 # service just routes — mesh live shards the item axis
-                # the verdicts come back as host bools, so the measured
-                # window includes the device sync — honest exec time
-                with devprof.measure(
-                    "bls_msm", work_bytes=sum(r.cost_bytes for r in bls_reqs)
-                ):
-                    verdicts = verify_many(
-                        [r.payload for r in bls_reqs],
-                        mesh=mesh if len(bls_reqs) >= mesh_ops.min_items() else None,
-                        keys=self._keys,
-                    )
+                verdicts = verify_many(
+                    [r.payload for r in bls_reqs],
+                    mesh=mesh if len(bls_reqs) >= mesh_ops.min_items() else None,
+                    keys=self._keys,
+                )
             else:
                 from eth_consensus_specs_tpu.crypto.signature import fast_aggregate_verify
 
@@ -513,12 +508,9 @@ class VerifyService:
                     r.prepped[0] if r.prepped is not None else parse_item(r.payload)
                     for r in kzg_reqs
                 ]
-                with devprof.measure(
-                    "kzg", work_bytes=sum(r.cost_bytes for r in kzg_reqs)
-                ):
-                    verdicts = verify_many_blobs(
-                        [r.payload for r in kzg_reqs], mesh=mesh, parsed=parsed
-                    )
+                verdicts = verify_many_blobs(
+                    [r.payload for r in kzg_reqs], mesh=mesh, parsed=parsed
+                )
             else:
                 from eth_consensus_specs_tpu.ops.kzg_batch import verify_blob_host
 
@@ -549,14 +541,10 @@ class VerifyService:
                     len(agg_reqs), max_lanes, mesh=mesh if sharded else None
                 )
                 with buckets.first_dispatch(*key):
-                    with devprof.measure(
-                        "g2_agg",
-                        work_bytes=sum(r.cost_bytes for r in agg_reqs),
-                    ):
-                        sums = sum_g2_many_device(
-                            lists, mesh=mesh if sharded else None,
-                            pad_shape=(key[1], key[2]),
-                        )
+                    sums = sum_g2_many_device(
+                        lists, mesh=mesh if sharded else None,
+                        pad_shape=(key[1], key[2]),
+                    )
                 for r, p in zip(agg_reqs, sums):
                     results[id(r)] = g2_to_bytes(p)
             else:
@@ -592,14 +580,10 @@ class VerifyService:
                     mesh=mesh if sharded else None,
                 )
                 with buckets.first_dispatch(*key):
-                    with devprof.measure(
-                        "merkle_many",
-                        work_bytes=sum(r.cost_bytes for r in group),
-                    ):
-                        roots = merkleize_many_device(
-                            trees, depth, pad_batch=key[1],
-                            mesh=mesh if sharded else None,
-                        )
+                    roots = merkleize_many_device(
+                        trees, depth, pad_batch=key[1],
+                        mesh=mesh if sharded else None,
+                    )
             else:
                 from eth_consensus_specs_tpu.obs.watchdog import host_tree_root_words
                 from eth_consensus_specs_tpu.ops.merkle import _chunks_to_words
@@ -645,12 +629,11 @@ class VerifyService:
                 )
 
                 with buckets.first_dispatch(*state_root_compile_key(meta)):
-                    # np.asarray IS the sync: the measured window closes
-                    # only once the root words are host-resident
-                    with devprof.measure("state_root", work_bytes=r.cost_bytes):
-                        results[id(r)] = np.asarray(
-                            post_epoch_state_root(arrays, meta, balances, eff, inact, just)
-                        )
+                    # np.asarray IS the sync: the device stage's clock
+                    # closes only once the root words are host-resident
+                    results[id(r)] = np.asarray(
+                        post_epoch_state_root(arrays, meta, balances, eff, inact, just)
+                    )
             else:
                 from eth_consensus_specs_tpu.ops.state_root import post_epoch_state_root_host
 

@@ -114,11 +114,11 @@ class SlotWorld:
         recipe, so cold re-ingest is a correct recovery leg here too."""
         import jax
 
-        import __graft_entry__ as graft
+        from eth_consensus_specs_tpu.ops.altair_epoch import example_altair_inputs
         from eth_consensus_specs_tpu.ops.state_root import synthetic_static
 
         self._spec = slot_pipeline.slot_spec()
-        cols, just = graft._example_altair_inputs(self.n_validators)
+        cols, just = example_altair_inputs(self.n_validators)
         self._static = synthetic_static(self._spec, self.n_validators)
         return jax.device_put(cols), jax.device_put(just)
 
@@ -581,13 +581,13 @@ def precompile_key(key: tuple, mesh=None) -> bool:
 
 @lru_cache(maxsize=None)
 def _warm_cols(n_validators: int):
-    import __graft_entry__ as graft
+    from eth_consensus_specs_tpu.ops.altair_epoch import example_altair_inputs
 
-    return graft._example_altair_inputs(n_validators)[0]
+    return example_altair_inputs(n_validators)[0]
 
 
 @lru_cache(maxsize=None)
 def _warm_just(n_validators: int):
-    import __graft_entry__ as graft
+    from eth_consensus_specs_tpu.ops.altair_epoch import example_altair_inputs
 
-    return graft._example_altair_inputs(n_validators)[1]
+    return example_altair_inputs(n_validators)[1]

@@ -2,7 +2,8 @@
 
 The unrolled SHA-256/limb kernels trade compile time for runtime; caching
 compiled executables across processes makes that cost one-time per machine
-instead of one-time per run (bench and test drivers call this first)."""
+instead of one-time per run (``VerifyService``, ``benchmark/run.py`` and
+``chip_smoke.py`` turn it on before their first compile)."""
 
 from __future__ import annotations
 
@@ -25,8 +26,7 @@ def default_cache_dir() -> str:
 def cache_dir_path() -> str:
     """Where this process keeps compiled executables: the directory
     ``JAX_COMPILATION_CACHE_DIR`` names when it is set (JAX reads it
-    itself; nothing here overrides it), else :func:`default_cache_dir`.
-    The warm sentinels live beside the executables they vouch for."""
+    itself; nothing here overrides it), else :func:`default_cache_dir`."""
     return os.environ.get(_ENV_VAR) or default_cache_dir()
 
 
@@ -52,38 +52,3 @@ def enable_persistent_cache() -> str | None:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
         _enabled = True
     return cache_dir
-
-
-def warm_sentinel(stage: str, backend: str) -> str:
-    """Marker file recording that a device chain (`pairing`, `h2c`, ...)
-    compiled AND executed to completion for `backend` with the entries
-    persisted in the cache.  Lets the bench attempt a device stage only
-    when a warm start is plausible — a cold compile of these chains can
-    exceed a whole section budget (round-3 lesson: never let one slow
-    compile strand a measurement).  The filename is built HERE only, so
-    producers (the kernels' mark_warm) and consumers (bench) can never
-    drift apart."""
-    return os.path.join(cache_dir_path(), f"device_{stage}_warm.{backend}")
-
-
-def pairing_warm_sentinel(backend: str) -> str:
-    return warm_sentinel("pairing", backend)
-
-
-def mark_warm(stage: str) -> None:
-    """Write the warm sentinel for `stage` — call strictly AFTER the
-    chain's results have been materialized on host (a sentinel written
-    before a runtime failure would keep steering later runs into the
-    broken path).  No-op without the persistent cache or on cpu."""
-    try:
-        if not _enabled:
-            return
-        import jax
-
-        backend = jax.default_backend()
-        if backend == "cpu":
-            return
-        with open(warm_sentinel(stage, backend), "w") as fh:
-            fh.write("ok\n")
-    except Exception:
-        pass

@@ -28,7 +28,7 @@ Gates (direct mode):
 
 Primary metric: **attestations aggregated + verified per second** at
 registry scale (``agg.attestations_agg_per_s`` in the report's ``agg``
-section, which scripts/perf_track.py ingests platform-aware).
+section).
 
 Replicated mode (``--replicas R [--chaos]``, the agg-smoke CI job):
 the committee fan-in submitted as ``aggregate`` ops through the

@@ -27,9 +27,7 @@ throughput at all:
 Primary metric: **blobs verified per second** (``das.blobs_per_s``;
 ``ffts_per_s`` rides along — one 4096-point inverse FFT row per blob).
 The report's ``das`` section carries ``correctness_coupled: true``
-exactly when the parity gates passed — scripts/perf_track.py refuses
-to let a das LKG section replace the quarantined entry without it
-(re-earn, never grandfather).
+exactly when the parity gates passed.
 
 Replicated mode (``--replicas R [--chaos]``, the das-smoke CI job):
 every blob rides a ``kzg`` op through the replicated front door.

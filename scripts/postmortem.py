@@ -124,19 +124,6 @@ def _waterfall_lines(bundle: dict) -> list[str]:
                 f"{p50 if p50 is None else f'{p50:.3f}':>10} "
                 f"{p99 if p99 is None else f'{p99:.3f}':>10}"
             )
-    dev = sorted(
-        (name[len("device.exec_ms."):], h)
-        for name, h in reg.get("histograms", {}).items()
-        if name.startswith("device.exec_ms.")
-    )
-    if dev:
-        lines.append("  device time (device.exec_ms):")
-        for kern, h in dev:
-            p50 = h.get("p50")
-            lines.append(
-                f"    {kern:<14} {h.get('count', 0):>7} runs, "
-                f"p50 {p50 if p50 is None else f'{p50:.3f}'} ms"
-            )
     hbm = bundle.get("hbm")
     if hbm:
         lines.append(

@@ -18,8 +18,7 @@ gates the contract the incremental path ships under:
      best-of-N so host-load noise hits both paths alike).
 
 The report JSON lands in ``--out`` (plus a validated Prometheus
-textfile next to it) and carries a ``resident`` section shaped like the
-bench driver's, so perf_track-style tooling can ingest either. CI runs
+textfile next to it) and carries a ``resident`` section. CI runs
 ``--smoke --chips 8`` under forced 8-virtual-device XLA (the
 resident-smoke job in checks.yml) and uploads both artifacts.
 """

@@ -78,22 +78,20 @@ replica mid-load (``--chaos``), and drives the SLO autoscaler through a
 forced breach and an idle window — gating zero lost requests, parity,
 zero cold compiles fleet-wide (respawned replacement included), p99
 within the DEFAULT SLO, and the autoscaler observably growing AND
-retiring a replica. The report's ``fleet`` section feeds perf_track.py
-as platform-aware secondary metrics.
+retiring a replica.
 
 Mesh mode (``--chips N``, the mesh-smoke CI job): forces N virtual CPU
 devices (``--xla_force_host_platform_device_count``; real devices on
 accelerators), then measures every hot kernel chips=1 vs chips=N in one
 process — merkleization through a 1-chip and an N-chip VerifyService
-(mesh-aware buckets, signed warmup keys), the G1 MSM as a direct kernel
-loop, and the sharded RLC pairing when the backend affords the Miller
-compile (``--mesh-pairing`` opts the CPU mesh in). Gates: byte parity
+(mesh-aware buckets, signed warmup keys) and the G1 MSM as a direct
+kernel loop (the sharded device pairing is proven by
+tests/test_mesh_ops.py and tests/test_pairing_device.py). Gates: byte parity
 on every sharded result, zero cold compiles after the mesh-aware warmup
 replay, zero watchdog divergences, and best per-effective-chip scaling
 >= ``--scaling-min`` (effective chips = min(chips, cores) on the
 virtual CPU mesh — 8 virtual devices on 2 cores cannot honestly beat
-2x). The report's ``mesh`` section feeds perf_track.py as
-platform-aware secondary metrics.
+2x).
 """
 
 from __future__ import annotations
@@ -361,8 +359,7 @@ def finish_report(report: dict, failures: list, out: str, trigger: str, snap: di
     if stage_hist:
         report["stage_hist"] = stage_hist
     # SLO burn-rate advisory (obs/slo.py): fraction of supervision
-    # windows spent out of the wait-p99 budget. Non-gating — perf_track
-    # ingests it as a secondary
+    # windows spent out of the wait-p99 budget. Non-gating
     burn = slo.burn_rate(snap)
     if burn is not None:
         report["slo"] = burn
@@ -398,27 +395,26 @@ def finish_report(report: dict, failures: list, out: str, trigger: str, snap: di
 def waterfall_section(
     failures: list,
     out: str,
-    require_kernels: tuple = ("merkle_many", "bls_msm"),
     require_resident: bool = True,
 ) -> dict:
     """The request-waterfall report section (obs/waterfall.py), shared by
     the default, replicated and fleet modes, with its CI gates:
 
       * per-stage p50/p99 from the ``serve.stage_ms.*`` histograms (flat
-        ``<stage>_p50_ms``/``<stage>_p99_ms`` keys — perf_track.py
-        ingests every numeric ``*_ms`` key as a secondary advisory);
+        ``<stage>_p50_ms``/``<stage>_p99_ms`` keys; the device stage's
+        legs among them as ``device.<leg>``);
       * coverage: named-stage milliseconds must tile >= 95% of the
         measured e2e wall (``total``), and the first-class ``other``
         stage must stay under 20% of the e2e p50 — unattributed time is
         reported, never silent, but it must not dominate;
-      * ``device.exec_ms.<kernel>`` populated for the headline kernel
-        families (the dispatch seams actually measured device time) with
-        zero roofline violations from MEASURED seconds;
+      * ``serve.stage_ms.device`` populated (the synced dispatch was
+        clocked) and, for the BLS load every mode carries, the
+        ``bls.pairing`` leg inside it;
       * a forced postmortem bundle whose ``hbm`` section carries a
         positive resident total — the HBM residency ledger is live and
         rides every black box.
 
-    In replicated/fleet modes the stage and device histograms arrive via
+    In replicated/fleet modes the stage histograms arrive via
     the replicas' obs deltas (obs/delta.py) — this reads the MERGED
     parent registry, the same fleet-wide view an operator would.
     """
@@ -448,32 +444,12 @@ def waterfall_section(
             f"waterfall: 'other' (unattributed) stage is {share:.1%} of e2e p50"
         )
 
-    hists = snap["histograms"]
-    counters = snap["counters"]
-    device: dict = {}
-    for name, h in sorted(hists.items()):
-        if name.startswith("device.exec_ms."):
-            kern = name[len("device.exec_ms."):]
-            device[kern] = {
-                "count": h.get("count", 0),
-                "p50_ms": h.get("p50"),
-                "p99_ms": h.get("p99"),
-                "roofline_violations": counters.get(
-                    f"device.roofline_violations.{kern}", 0
-                ),
-            }
-    section["device"] = device
-    for kern in require_kernels:
-        if not device.get(kern, {}).get("count"):
+    for stage in ("device", "device.bls.pairing"):
+        if not wf["stages"].get(stage, {}).get("count"):
             failures.append(
-                f"waterfall: device.exec_ms.{kern} is empty — the dispatch seam "
-                "never measured device time for that family"
+                f"waterfall: serve.stage_ms.{stage} is empty — the dispatch "
+                "was never clocked"
             )
-    if counters.get("device.roofline_violations", 0):
-        failures.append(
-            "waterfall: measured device seconds violate the declared byte model "
-            f"({counters['device.roofline_violations']} roofline violations)"
-        )
 
     # the HBM residency ledger must ride the black box: force one bundle
     # (explicit out_dir — the default smoke sets no postmortem env) and
@@ -1140,10 +1116,7 @@ def run_mesh(args) -> None:
         effective chips = min(chips, cpu cores) on the virtual CPU mesh
         (8 virtual devices on 2 cores cannot beat 2x — gating against
         physical parallelism is what keeps this honest) and = chips on
-        real accelerators.
-
-    The report's ``mesh`` section is what perf_track.py ingests as
-    platform-aware secondary metrics (``mesh_*``)."""
+        real accelerators."""
     import jax
 
     from eth_consensus_specs_tpu.crypto.curve import g1_generator
@@ -1200,25 +1173,6 @@ def run_mesh(args) -> None:
                 }
             )
         ]
-    if args.mesh_pairing or platform != "cpu":
-        # the pairing section's verify_many pays the batched G1 many-sum
-        # compile under the device bls backend — warm its exact
-        # many_sum_shape keys (unsigned + signed) or those dispatches
-        # would land AFTER the compile snapshot and fail the gate (a
-        # parse-rejected item can shrink the live count across a pow2
-        # boundary, so the n-1 shapes are warmed too)
-        from eth_consensus_specs_tpu.ops.bls_batch import _use_device
-        from eth_consensus_specs_tpu.ops.g1_msm import many_sum_shape
-
-        if _use_device():
-            n_p = max(args.requests // 8, 8)
-            pair_shapes = {many_sum_shape(n, args.committee, 1) for n in (n_p, n_p - 1)}
-            warm += [("bls_msm", *shape) for shape in sorted(pair_shapes)]
-            if mesh is not None:
-                mesh_shapes = {
-                    many_sum_shape(n, args.committee, shards) for n in (n_p, n_p - 1)
-                }
-                warm += [("bls_msm", *shape, sig) for shape in sorted(mesh_shapes)]
     serve_buckets.precompile(warm, chips=chips)
     compiles_after_warmup = obs.snapshot()["counters"].get("serve.compiles", 0)
 
@@ -1313,31 +1267,6 @@ def run_mesh(args) -> None:
         if not (msm_g1_device(pts, ks, mesh=mesh) == msm_g1_device(pts, ks) == msm_g1(pts, ks)):
             failures.append("msm parity: sharded scalar MSM != single-device != host")
 
-    # --- RLC pairing: device Miller chunks sharded over the mesh --------
-    # The Miller scan's one-time XLA:CPU compile is minutes — the virtual
-    # CPU mesh runs it only on request (--mesh-pairing); accelerator
-    # backends always do. Bit-parity incl. the bisection invalid-item
-    # path is covered on the CPU mesh by tests/test_mesh_ops.py.
-    if args.mesh_pairing or platform != "cpu":
-        os.environ["ETH_SPECS_TPU_DEVICE_PAIRING"] = "1"
-        items_p = build_bls_items(max(args.requests // 8, 8), args.committee, 4)
-        v1 = bls_batch.verify_many(items_p)
-        vn = bls_batch.verify_many(items_p, mesh=mesh)
-        if v1 != vn:
-            failures.append("pairing parity: sharded verify_many verdicts diverge")
-        tp1 = _timed_reps(lambda: bls_batch.verify_many(items_p), 1)
-        tpn = _timed_reps(lambda: bls_batch.verify_many(items_p, mesh=mesh), 1)
-        p_speedup = tp1 / tpn
-        sections["pairing"] = {
-            "items": len(items_p),
-            "speedup": round(p_speedup, 3),
-            "scaling_factor": round(p_speedup / effective, 3),
-            "parity": v1 == vn,
-        }
-    else:
-        sections["pairing"] = {"skipped": "cpu Miller compile is minutes; "
-                               "run with --mesh-pairing to include it"}
-
     # --- gates -----------------------------------------------------------
     snap = obs.snapshot()
     counters = snap["counters"]
@@ -1417,9 +1346,6 @@ def main() -> None:
                     default=float(os.environ.get("ETH_SPECS_MESH_SCALING_MIN", "0.7")
                                   or 0.7),
                     help="minimum per-effective-chip scaling factor")
-    ap.add_argument("--mesh-pairing", action="store_true",
-                    help="include the sharded device pairing on the CPU mesh "
-                         "(one-time Miller compile is minutes)")
     ap.add_argument("--canary-ms", type=float, default=150.0,
                     help="known-answer canary interval in ms (0 disables the "
                          "telemetry plane; shapes via ETH_SPECS_CANARY_SHAPES)")
@@ -1468,8 +1394,8 @@ def main() -> None:
     svc.precompile(warm_keys)
 
     # --- state_root mini-phase (warm): one post-epoch state root through
-    # the service. Exercises the state_root devprof seam end to end
-    # (device.exec_ms.state_root) and — via synthetic_static's
+    # the service. Exercises the state_root dispatch end to end
+    # and — via synthetic_static's
     # creation-site registration — puts a genuinely resident device tree
     # on the HBM ledger for the waterfall section's residency gate. Runs
     # BEFORE the compile snapshot: its first dispatch is a legitimate

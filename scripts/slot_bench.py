@@ -3,8 +3,7 @@
 Drives ``submit_slot`` (the whole-slot state-transition pipeline,
 ops/slot_pipeline.py + serve/slot.py) end to end through a supervised
 replica fleet and writes a JSON report (default BENCH_SLOT.json) whose
-``slot`` section feeds perf_track.py (``slots_per_s`` headline +
-per-phase p99 advisories).
+``slot`` section carries ``slots_per_s`` and the per-phase p99s.
 
 The load is a deterministic, seeded schedule of mainnet-SHAPED slots:
 ragged committees with realistic size spread, a sync aggregate, a

@@ -4,6 +4,8 @@ hardware. Must run before the first `import jax` anywhere in the suite."""
 
 import os
 
+import pytest
+
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
@@ -24,3 +26,12 @@ from eth_consensus_specs_tpu.test_infra.obs_plugin import (  # noqa: E402,F401
 
 def pytest_configure(config):
     config.pluginmanager.register(ObsPlugin(str(config.rootpath)), "eth-specs-obs")
+
+
+@pytest.fixture
+def reference_tree():
+    """For a test that compiles the reference markdown through specc:
+    skipped, with the reason, where the checkout is not mounted."""
+    from tests.parity.helpers import require_reference
+
+    require_reference()

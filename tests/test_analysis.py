@@ -603,6 +603,56 @@ def test_env_reference_docs_in_lockstep():
     assert proc.returncode == 0, proc.stderr
 
 
+def _sources(*dirs: str, suffix: str = ""):
+    for d in dirs:
+        for dirpath, _, files in os.walk(os.path.join(REPO_ROOT, d)):
+            for f in files:
+                if f.endswith(suffix) and "__pycache__" not in dirpath:
+                    yield os.path.join(dirpath, f)
+
+
+@pytest.mark.parametrize("name", [
+    "ETH_SPECS_TPU_DEVICE_PAIRING",
+    "ETH_SPECS_TPU_NO_DEVICE_PAIRING",
+    "ETH_SPECS_TPU_DEVICE_H2C",
+    "ETH_SPECS_BENCH_ACC_TIMEOUT",
+    "ETH_SPECS_BENCH_CPU_TIMEOUT",
+    "ETH_SPECS_BENCH_VERIFY_TIMEOUT",
+])
+def test_a_variable_of_the_old_bench_is_declared_and_named_nowhere(name):
+    """The BLS path routes on `bls.use_tpu()` alone and the bench driver
+    these steered is gone: neither the registry nor any source of the
+    program, its scripts or its workflows names them."""
+    from eth_consensus_specs_tpu import envreg
+
+    assert name not in envreg.by_name()
+    paths = [*_sources(lint.PACKAGE, "scripts", suffix=".py"), *_sources(".github")]
+    assert len(paths) > 100
+    named = [p for p in paths if name in open(p, encoding="utf-8").read()]
+    assert not named, named
+
+
+def test_the_package_imports_no_module_of_the_repository_root():
+    """A layer below the drivers reaches for none of them: an installed
+    package boots a slot world without the checkout's root on its path."""
+    import ast
+
+    root_modules = {"__graft_entry__", "chip_smoke", "benchmark"}
+    offenders = []
+    for path in _sources(lint.PACKAGE, suffix=".py"):
+        for node in ast.walk(ast.parse(open(path, encoding="utf-8").read())):
+            names = []
+            if isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom) and node.level == 0:
+                names = [node.module or ""]
+            offenders += [
+                f"{os.path.relpath(path, REPO_ROOT)}:{node.lineno} {n}"
+                for n in names if n.split(".")[0] in root_modules
+            ]
+    assert not offenders, offenders
+
+
 def test_validate_text_rejects_uncataloged_family():
     from eth_consensus_specs_tpu.obs import export
 

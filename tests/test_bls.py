@@ -1,6 +1,8 @@
 """BLS12-381 signature-scheme tests: scheme consistency, serialization
 round-trips, negative cases, batch verification, and the backend switch."""
 
+import functools
+
 import pytest
 
 from eth_consensus_specs_tpu.crypto.curve import (
@@ -138,3 +140,54 @@ def test_batch_verify_emits_obs_counters(kernel_counters):
     assert delta["bls.batch_items"] == 1
     assert delta["bls.pairings"] == 1
     assert "bls.batch_verify" in obs.snapshot()["spans"]
+
+
+# ------------------------------------------- one RLC check, two entry points --
+
+
+def _agg_item(sks, msg):
+    return ([bls.SkToPk(s) for s in sks], msg, bls.Aggregate([bls.Sign(s, msg) for s in sks]))
+
+
+@functools.lru_cache(maxsize=None)
+def _rlc_scenarios() -> dict:
+    a, b, c = _agg_item([1, 2], MSG_A), _agg_item([3, 4], MSG_B), _agg_item([5], b"\x56" * 32)
+    return {
+        "all_valid": ([a, b, c], [True, True, True]),
+        "wrong_signature": ([a, (b[0], b[1], c[2]), c], [True, False, True]),
+        "wrong_message": ([a, (b[0], MSG_A, b[2]), c], [True, False, True]),
+        "shared_message": ([a, _agg_item([3, 4], MSG_A), c], [True, True, True]),
+        "malformed_signature": ([a, (b[0], b[1], b"\x01" + bytes(b[2])[1:]), c], [True, False, True]),
+        "empty_signers": ([a, ([], b[1], b[2]), c], [True, False, True]),
+    }
+
+
+@pytest.mark.parametrize("entry", ["batch_verify_aggregates", "verify_many"])
+@pytest.mark.parametrize("scenario", [
+    "all_valid", "wrong_signature", "wrong_message", "shared_message",
+    "malformed_signature", "empty_signers",
+])
+def test_one_rlc_check_behind_both_entry_points(scenario, entry, kernel_counters):
+    """`batch_verify_aggregates` (the spec path, item by item) and
+    `verify_many` (the served path) reach the SAME check: equal verdicts,
+    and one sample of ``bls.rlc_check_ms`` a pairing from either."""
+    from eth_consensus_specs_tpu import obs
+    from eth_consensus_specs_tpu.ops import bls_batch
+
+    def samples() -> int:
+        return obs.snapshot()["histograms"].get("bls.rlc_check_ms", {}).get("count", 0)
+
+    items, want = _rlc_scenarios()[scenario]
+    before = samples()
+    if entry == "verify_many":
+        got = bls_batch.verify_many(items)
+    else:
+        got = [batch_verify_aggregates([it]) for it in items]
+    assert got == want
+    pairings = kernel_counters()["bls.pairings"]
+    # an item that does not parse is refused before any pairing
+    assert pairings >= 1 and samples() - before == pairings
+    if entry == "batch_verify_aggregates":
+        assert pairings == sum(
+            1 for pks, _, sig in items if len(pks) and bytes(sig)[0] != 1
+        )

@@ -155,7 +155,7 @@ def test_inactivity_and_hysteresis_quotients(preset):
     )
 
 
-def test_intervals_and_due_bps_sane():
+def test_intervals_and_due_bps_sane(reference_tree):
     fc = None
     from eth_consensus_specs_tpu.specc import compile_fork
 

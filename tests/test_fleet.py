@@ -188,36 +188,6 @@ def test_frontdoor_config_fleet_knobs(monkeypatch):
     assert FrontDoorConfig().chips_for(3, 4) == 4  # empty matrix: default
 
 
-def test_perf_track_ingests_fleet_matrix(tmp_path):
-    """The fleet matrix rides the perf trajectory as platform-aware
-    secondaries: cells are advisories, never cross-platform gates."""
-    import importlib.util
-    import json
-
-    spec = importlib.util.spec_from_file_location(
-        "perf_track",
-        os.path.join(os.path.dirname(__file__), "..", "scripts", "perf_track.py"),
-    )
-    pt = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(pt)
-    for rnd, factor in ((1, 1.6), (2, 0.4)):
-        (tmp_path / f"BENCH_r{rnd:02d}.json").write_text(json.dumps({
-            "rc": 0,
-            "parsed": {
-                "metric": "hashes_per_sec", "value": 100.0, "platform": "cpu",
-                "fleet": {"grown": 1, "retired": 1,
-                          "r3x8_rps": 40.0 * factor, "r3x8_scaling": factor},
-            },
-        }))
-    entries = pt.load_rounds(str(tmp_path))
-    assert entries[0]["metrics"]["fleet_r3x8_scaling"] == 1.6
-    assert entries[0]["metrics"]["fleet_r3x8_rps"] == 64.0
-    assert "fleet_grown" not in entries[0]["metrics"]  # event count, not perf
-    regressions, advisories = pt.compare(entries, threshold=0.30, strict=False)
-    assert not regressions
-    assert any(a["metric"] == "fleet_r3x8_scaling" for a in advisories)
-
-
 # ------------------------------------------------- heterogeneous fleet --
 
 

@@ -192,20 +192,17 @@ def _bls_items(n, committee=3, invalid=()):
     return items
 
 
-def test_verify_many_mesh_bisection_bit_identical(monkeypatch):
+def test_verify_many_mesh_bisection_bit_identical():
     """The serving batch entry point over the mesh: sharded per-item G1
-    terms (device sum kernel under the tpu backend switch), host pairing
-    (ETH_SPECS_TPU_NO_DEVICE_PAIRING — the Miller compile rides the slow
-    lane), invalid items exercising the bisection — verdicts must be
-    bit-identical to the single-device path and to direct singleton
-    calls."""
+    terms, host pairing, invalid items exercising the bisection —
+    verdicts must be bit-identical to the single-device path and to
+    direct singleton calls. `verify_many` reads no backend switch: the
+    mesh handed in is what shards the sums."""
     from eth_consensus_specs_tpu.ops import bls_batch
 
     mesh = _mesh()
-    monkeypatch.setenv("ETH_SPECS_TPU_NO_DEVICE_PAIRING", "1")
-    prior_active, prior_backend = bls.bls_active, bls.backend_name()
+    prior_active = bls.bls_active
     bls.bls_active = True
-    bls.use_tpu()
     try:
         items = _bls_items(7, invalid={2, 5})
         direct = [bls_batch.batch_verify_aggregates([it]) for it in items]
@@ -216,24 +213,30 @@ def test_verify_many_mesh_bisection_bit_identical(monkeypatch):
         assert _counter("mesh.dispatches") > before
     finally:
         bls.bls_active = prior_active
-        if prior_backend == "pyspec":
-            bls.use_pyspec()
 
 
 @pytest.mark.slow
-def test_verify_many_sharded_pairing_bisection(monkeypatch):
+def test_verify_many_sharded_pairing_bisection():
     """Full sharded path: per-shard partial Miller products + psum-style
-    Fq12 combine, with an invalid item forcing bisection re-checks
-    through the SAME sharded pairing — minutes of XLA:CPU compile,
-    nightly lane."""
+    Fq12 combine, reached through the `bls.use_tpu()` backend on
+    `batch_verify_aggregates(..., mesh=mesh)`, each item alone as a
+    bisection's last step checks it — minutes of XLA:CPU compile, nightly
+    lane."""
     from eth_consensus_specs_tpu.ops import bls_batch
 
     mesh = _mesh(2)
-    monkeypatch.setenv("ETH_SPECS_TPU_DEVICE_PAIRING", "1")
-    items = _bls_items(17, invalid={7})
-    direct = bls_batch.verify_many(items)
-    assert direct == [i != 7 for i in range(17)]
-    assert bls_batch.verify_many(items, mesh=mesh) == direct
+    items = _bls_items(5, invalid={3})
+    prior_active, prior_backend = bls.bls_active, bls.backend_name()
+    bls.bls_active = True
+    bls.use_tpu()
+    try:
+        sharded = [bls_batch.batch_verify_aggregates([it], mesh=mesh) for it in items]
+    finally:
+        bls.bls_active = prior_active
+        if prior_backend == "pyspec":
+            bls.use_pyspec()
+    assert sharded == [i != 3 for i in range(5)]
+    assert sharded == bls_batch.verify_many(items)
 
 
 # ------------------------------------------- serve buckets + warmup keys --
@@ -359,36 +362,6 @@ def test_host_local_slice_pad_covers_every_row():
     assert (lo, hi) == (0, padded)
     # divisible splits are untouched by the fix
     assert multihost.host_local_slice(mesh, 1024) == (0, 1024)
-
-
-def test_perf_track_ingests_mesh_scaling(tmp_path):
-    """perf_track treats the per-chip scaling factors as platform-aware
-    secondary metrics: a cpu virtual-mesh round never compares against
-    accelerator history, and a scaling regression is an advisory."""
-    import importlib.util
-    import json
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "perf_track", os.path.join(os.path.dirname(__file__), "..", "scripts", "perf_track.py")
-    )
-    pt = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(pt)
-    for rnd, factor in ((1, 1.8), (2, 0.5)):
-        (tmp_path / f"BENCH_r{rnd:02d}.json").write_text(json.dumps({
-            "rc": 0,
-            "parsed": {
-                "metric": "hashes_per_sec", "value": 100.0, "platform": "cpu",
-                "mesh": {"chips": 8, "chip_scaling": factor, "merkle_scaling": factor},
-            },
-        }))
-    entries = pt.load_rounds(str(tmp_path))
-    assert entries[0]["metrics"]["mesh_chip_scaling"] == 1.8
-    assert entries[0]["metrics"]["mesh_merkle_scaling"] == 1.8
-    assert "mesh_chips" not in entries[0]["metrics"]  # config, not a metric
-    regressions, advisories = pt.compare(entries, threshold=0.30, strict=False)
-    assert not regressions  # secondaries never gate by default
-    assert any(a["metric"] == "mesh_chip_scaling" for a in advisories)
 
 
 def test_sharded_dispatch_thread_safety():

@@ -125,33 +125,6 @@ def test_gates_roofline_is_keyed_by_device_kind_and_unknown_is_an_error():
         gates.roofline_bytes_s("TPU v9")
 
 
-def test_gates_apply_gates_matches_bench_semantics(capsys):
-    frag = {"work_bytes": int(1e15), "unit_s": 0.001}
-    gates.apply_gates("tree", frag, "unit_s")
-    assert frag["roofline_ok"] is False
-    # fragment without timing info passes through unjudged
-    frag2 = {"work_bytes": 100}
-    gates.apply_gates("tree", frag2, "unit_s")
-    assert "roofline_ok" not in frag2
-
-
-def test_gates_digests_match_refuses_missing():
-    assert gates.digests_match("ab", "ab")
-    assert not gates.digests_match(None, "ab")
-    assert not gates.digests_match("ab", None)
-    assert not gates.digests_match("ab", "cd")
-
-
-def test_bench_imports_gate_logic_from_obs():
-    """Acceptance: bench.py consumes obs/gates.py, no duplicated code."""
-    import bench
-
-    assert bench._apply_gates is gates.apply_gates
-    assert bench._digest is gates.digest
-    assert bench._UNIT_KEY is gates.UNIT_KEY
-    assert not hasattr(bench, "ACCEL_ROOFLINE_BYTES_S")  # the ceiling is per device kind
-
-
 # ------------------------------------------------------------------ watchdog --
 
 

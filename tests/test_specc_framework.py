@@ -91,20 +91,20 @@ def test_parse_doc_from_text_matches_file(tmp_path):
 # == compiled-oracle structure ==============================================
 
 
-def test_compile_fork_exposes_spec_surface():
+def test_compile_fork_exposes_spec_surface(reference_tree):
     m = c.compile_fork("phase0", "minimal")
     assert callable(m.state_transition)
     assert callable(m.process_epoch)
     assert m.SLOTS_PER_EPOCH == 8  # minimal preset substitution
 
 
-def test_compile_fork_preset_substitution_differs():
+def test_compile_fork_preset_substitution_differs(reference_tree):
     minimal = c.compile_fork("phase0", "minimal")
     mainnet = c.compile_fork("phase0", "mainnet")
     assert int(minimal.SLOTS_PER_EPOCH) != int(mainnet.SLOTS_PER_EPOCH)
 
 
-def test_compile_fork_lineage_override():
+def test_compile_fork_lineage_override(reference_tree):
     """A later fork's markdown redefinition replaces the ancestor's."""
     p0 = c.compile_fork("phase0", "minimal")
     altair = c.compile_fork("altair", "minimal")
@@ -112,14 +112,14 @@ def test_compile_fork_lineage_override():
     assert p0.process_epoch.__code__.co_code != altair.process_epoch.__code__.co_code
 
 
-def test_compile_fork_ancestor_modules_linked():
+def test_compile_fork_ancestor_modules_linked(reference_tree):
     electra = c.compile_fork("electra", "minimal")
     # upgrade functions address ancestors as modules
     assert hasattr(electra, "deneb")
     assert callable(electra.deneb.get_current_epoch)
 
 
-def test_compile_fork_builder_classes_injected():
+def test_compile_fork_builder_classes_injected(reference_tree):
     deneb = c.compile_fork("deneb", "minimal")
     from eth_consensus_specs_tpu.utils.bls import Scalar
 
@@ -133,7 +133,7 @@ def test_compile_fork_rejects_unknown_fork():
         c.compile_fork("notafork", "minimal")
 
 
-def test_fork_choice_namespace_layers_on_top():
+def test_fork_choice_namespace_layers_on_top(reference_tree):
     plain = c.compile_fork("phase0", "minimal")
     fc = c.compile_fork("phase0", "minimal", None, True)
     assert not hasattr(plain, "on_block")
@@ -142,7 +142,7 @@ def test_fork_choice_namespace_layers_on_top():
     assert plain.SLOTS_PER_EPOCH == fc.SLOTS_PER_EPOCH
 
 
-def test_zero_skip_reports_across_lineage():
+def test_zero_skip_reports_across_lineage(reference_tree):
     for fork in c.CHAIN:
         rep = c.compile_fork(fork, "minimal").__specc_report__
         assert not rep.skipped_constants, (fork, rep.skipped_constants)
@@ -162,7 +162,7 @@ def test_pins_cover_every_compiled_doc():
                 assert rel in pins, f"unpinned compiled doc {rel}"
 
 
-def test_read_pinned_rejects_tampered_content(tmp_path, monkeypatch):
+def test_read_pinned_rejects_tampered_content(reference_tree, tmp_path, monkeypatch):
     target = os.path.join(c.REFERENCE_SPECS, "specs", "phase0", "beacon-chain.md")
     tampered = tmp_path / "beacon-chain.md"
     tampered.write_text(open(target).read() + "\n<!-- tampered -->\n")

@@ -1,6 +1,6 @@
 """Request waterfall (obs/waterfall.py) with its legs and the compile
-listener that files under them (obs/xprof.py), device-time profiling
-(obs/devprof.py), and the HBM residency ledger (obs/ledger.py).
+listener that files under them (obs/xprof.py), and the HBM residency
+ledger (obs/ledger.py).
 
 The tier-1 acceptance story: stamp vectors stay monotone through a real
 VerifyService (first-write-wins marks, shared flush clocks), stage
@@ -21,7 +21,7 @@ import pytest
 import jax.numpy as jnp
 
 from eth_consensus_specs_tpu import obs, serve
-from eth_consensus_specs_tpu.obs import devprof, ledger, trace, waterfall
+from eth_consensus_specs_tpu.obs import ledger, trace, waterfall
 from eth_consensus_specs_tpu.obs.registry import Registry
 from eth_consensus_specs_tpu.ops import merkle as ops_merkle
 from eth_consensus_specs_tpu.serve.config import ServeConfig
@@ -36,12 +36,10 @@ def _fresh_obs_state(monkeypatch):
 
     waterfall.reset_for_tests()
     ledger.reset_for_tests()
-    devprof.reset_for_tests()
     monkeypatch.setattr(registry_mod, "_REGISTRY", Registry())
     yield
     waterfall.reset_for_tests()
     ledger.reset_for_tests()
-    devprof.reset_for_tests()
 
 
 @pytest.fixture
@@ -233,51 +231,56 @@ def test_ledger_rides_postmortem_bundle(tmp_path):
     assert bundle["hbm"]["owners"] == {"resident_state": 2048}
 
 
-# ----------------------------------------------------------------- devprof --
-
-
-def test_devprof_measure_records_and_rooflines():
-    # 96 bytes over any measurable wall implies a rate far below the
-    # roofline: no violation
-    with devprof.measure("merkle_many", work_bytes=96):
-        pass
-    snap = obs.snapshot()
-    assert snap["histograms"]["device.exec_ms.merkle_many"]["count"] == 1
-    assert snap["histograms"]["device.exec_ms"]["count"] == 1
-    assert snap["counters"].get("device.roofline_violations", 0) == 0
-    # an impossible byte claim against measured time IS a violation
-    devprof.record("merkle_many", 1e-6, work_bytes=10**15)
-    c = obs.snapshot()["counters"]
-    assert c["device.roofline_violations"] == 1
-    assert c["device.roofline_violations.merkle_many"] == 1
-
-
-def test_devprof_raising_body_records_nothing():
-    with pytest.raises(RuntimeError):
-        with devprof.measure("bls_msm"):
-            raise RuntimeError("degraded dispatch")
-    assert "device.exec_ms.bls_msm" not in obs.snapshot()["histograms"]
-
-
-def test_devprof_noop_when_obs_disabled(monkeypatch):
+def test_ledger_noop_when_obs_disabled(monkeypatch):
     from eth_consensus_specs_tpu.obs import registry as registry_mod
 
     monkeypatch.setenv("ETH_SPECS_OBS", "0")
     assert registry_mod.refresh_enabled() is False
     try:
-        with devprof.measure("merkle_many", work_bytes=10**15):
-            pass
-        assert devprof.record("merkle_many", 1.0, work_bytes=10**15) is None
         reg = registry_mod.get_registry()
-        assert reg.counters == {} and reg.histograms == {}
         # the ledger's internal books stay live (tests rely on exact
         # bytes) but publish no gauges
         ledger.register("resident_state", "x", 128)
         assert ledger.resident_bytes() == 128
-        assert reg.gauges == {}
+        assert reg.gauges == {} and reg.counters == {}
     finally:
         monkeypatch.setenv("ETH_SPECS_OBS", "1")
         assert registry_mod.refresh_enabled() is True
+
+
+# ------------------------------------------------- the device stage's clock --
+
+
+def _htr_flush(svc):
+    rng = np.random.default_rng(11)
+    tree = rng.integers(0, 256, size=(5, 32)).astype(np.uint8)
+    return [svc.submit_hash_tree_root(tree)], ()
+
+
+def _bls_flush(svc):
+    from eth_consensus_specs_tpu.utils import bls
+
+    msg = b"\x21" * 32
+    pks = [bls.SkToPk(s) for s in (1, 2)]
+    sig = bls.Aggregate([bls.Sign(s, msg) for s in (1, 2)])
+    return [svc.submit_bls_aggregate(pks, msg, sig)], ("bls.keys", "bls.h2c", "bls.pairing")
+
+
+@pytest.mark.parametrize("flush", [_htr_flush, _bls_flush], ids=["htr", "bls"])
+def test_a_served_flush_is_clocked_by_the_device_stage_alone(flush):
+    """One clock round the synced dispatch, `serve.stage_ms.device`, split
+    by the legs where the kind has them; no histogram under a device's
+    name (`device.*`) beside it."""
+    with serve.VerifyService(ServeConfig.from_env(max_batch=4, max_wait_ms=5)) as svc:
+        futs, legs = flush(svc)
+        for f in futs:
+            f.result(timeout=120)
+    hists = obs.snapshot()["histograms"]
+    assert hists["serve.stage_ms.device"]["count"] == len(futs)
+    assert hists["serve.stage_ms.device.other"]["count"] == len(futs)
+    for leg in legs:
+        assert hists[f"serve.stage_ms.device.{leg}"]["count"] == len(futs)
+    assert not [name for name in hists if name.startswith("device.")]
 
 
 # -------------------------------------------------------------------- legs --
