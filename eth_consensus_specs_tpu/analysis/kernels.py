@@ -402,6 +402,9 @@ def _fr_fft_args(batch: int, n: int, stages: int):
     fr = fr_fft.FR
     return (
         _sds((batch, n, fr.n_limbs), "uint64"),
+        # enter, leave: the two boundary constants (ops/fr_fft.fft_program)
+        _sds((fr.n_limbs,), "uint64"),
+        _sds((fr.n_limbs,), "uint64"),
         *(_sds((1 << i, fr.n_limbs), "uint64") for i in range(stages)),
     )
 
@@ -419,8 +422,17 @@ def _fr_fft_variants(mesh):
         "twiddles: canonical Montgomery Fr (< r limb-wise)",
         hi=limb_caps(fr.modulus - 1, 30, fr.n_limbs),
     )
+    # enter / leave: R^2, R, 1 or 1/n mod r, host-built, always < r
+    const_dom = Domain(
+        "boundary constant: canonical Fr (< r limb-wise)",
+        hi=limb_caps(fr.modulus - 1, 30, fr.n_limbs),
+    )
     doms = (
-        mont_domain("values: Montgomery Fr in [0, 2r)", fr.modulus, 30, fr.n_limbs),
+        # plain limbs < r on the served path; batch_fft_mont hands the
+        # same program Montgomery limbs in the redundant range
+        mont_domain("values: Fr limbs in [0, 2r)", fr.modulus, 30, fr.n_limbs),
+        const_dom,
+        const_dom,
         *([tw_dom] * stages),
     )
     out = [

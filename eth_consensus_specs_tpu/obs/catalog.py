@@ -143,9 +143,9 @@ CATALOG: tuple[Metric, ...] = (
     _s("kzg.horner", "leg: Horner over a flush's monomial coefficients"),
     _s("kzg.rlc_fold", "leg: Fiat-Shamir hash, powers and lane lists of one RLC check"),
     _s("kzg.pairing", "leg: the routed pairing check of one RLC check"),
-    _s("fr_fft.pack", "leg: integers to Montgomery limbs"),
+    _s("fr_fft.pack", "leg: integers reduced mod r and cut into plain limbs, one array a flush"),
     _s("fr_fft.call", "leg: host clock round the synced batched-FFT device call"),
-    _s("fr_fft.unpack", "leg: Montgomery limbs back to integers"),
+    _s("fr_fft.unpack", "leg: plain limbs of the real rows joined back to integers"),
     _s("g1_msm.pack", "leg: points and scalars to limbs and bits"),
     _s("g1_msm.call", "leg: host clock round the synced multi-MSM device call"),
     _s("g1_msm.unpack", "leg: Jacobian results to affine points"),

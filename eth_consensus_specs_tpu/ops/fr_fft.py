@@ -4,11 +4,18 @@ The 8192-point radix-2 FFT over the 255-bit scalar field is the most
 TPU-shaped math in the spec (SURVEY §2.3; reference:
 specs/fulu/polynomial-commitments-sampling.md:155-209,779): thousands of
 independent butterflies per stage, 13 static stages, no data-dependent
-control flow.  Elements live as 9x30-bit Montgomery limbs in uint64 lanes
+control flow.  Elements live as 9x30-bit limbs in uint64 lanes
 (ops/limb_field.py); all log2(n) stages run inside ONE jit with the
 stage loop unrolled (static shapes per stage), so XLA fuses the butterfly
 chain, and a leading batch axis amortizes recovery over many columns at
 once.
+
+Montgomery form begins and ends INSIDE that one program
+(:func:`fft_program`): it takes and returns plain (canonical) limbs,
+enters with one multiply by ``R^2 mod r`` and leaves with one multiply by
+a plain constant, which is also where an inverse transform's ``1/n``
+goes. The host (:func:`batch_fft_field`) only reduces ``% r`` and splits
+or joins bits, as array operations over the whole flush.
 
 Bit-exact with the host oracle crypto/das.fft_field (same DIT butterfly
 order: both equal the textbook DFT in exact modular arithmetic)."""
@@ -99,29 +106,46 @@ def _device_twiddles(roots: tuple, n: int) -> tuple:
     return tables
 
 
+def fft_program(vals, enter, leave, twiddles, n: int):
+    """The whole device program, shared by the single-device and the
+    mesh variant: ``mont_mul(vals, enter)``, the stage chain,
+    ``mont_mul(., leave)``, one conditional subtraction of r.
+
+    ``mont_mul(x, c) = x * c / R``, so ``enter = R^2 mod r`` lifts plain
+    limbs into Montgomery form, and a plain ``leave = s`` drops back out
+    of it scaled by ``s`` (1, or ``1/n`` for an inverse transform) in the
+    one multiply; ``enter = leave = R mod r`` keeps Montgomery form on
+    both sides (:func:`batch_fft_mont`). The product of a value below 2r
+    and a constant below r is below 1.5r (R > 4r), so the one
+    subtraction makes the output canonical."""
+    out = fft_stages(FR.mont_mul(vals, enter), twiddles, n)
+    return FR._cond_sub(FR.mont_mul(out, leave), FR.p_limbs)
+
+
 @lru_cache(maxsize=None)
 def _compiled_fft(n: int, n_stages: int):
-    """One executable per size; twiddles enter as traced args so coset
-    variants and inverse roots reuse the same compilation. The input
-    limb array is DONATED: it is a private bit-reversed copy built in
-    batch_fft_mont (never reused after the call) and its aval equals the
-    output's, so XLA writes the butterfly stages back into the same
-    [B, n, L] buffer — at 8192-point DAS batches that halves the
-    kernel's resident footprint (the jaxlint donation-audit rule is what
-    flagged the missed alias)."""
+    """One executable per size; the twiddles and the two boundary
+    constants enter as traced args so forward, inverse and coset
+    variants, plain or Montgomery limbs, reuse the same compilation. The
+    input limb array is DONATED: it is a private bit-reversed copy
+    (never reused after the call) and its aval equals the output's, so
+    XLA writes the butterfly stages back into the same [B, n, L] buffer —
+    at 8192-point DAS batches that halves the kernel's resident
+    footprint (the jaxlint donation-audit rule is what flagged the
+    missed alias)."""
 
     @partial(jax.jit, donate_argnums=(0,))
-    def run(vals, *twiddles):
-        return fft_stages(vals, list(twiddles), n)
+    def run(vals, enter, leave, *twiddles):
+        return fft_program(vals, enter, leave, list(twiddles), n)
 
     return run
 
 
 # -- mesh-sharded variant: rows of a batched FFT are independent, so the
 # BATCH axis shards with NO collectives (every shard runs the identical
-# butterfly chain over its rows) — byte-identical to the single-device
-# dispatch at any shard count. The donated vals buffer aliases per shard
-# exactly like the single-device jit.
+# program over its rows) — byte-identical to the single-device dispatch
+# at any shard count. The donated vals buffer aliases per shard exactly
+# like the single-device jit.
 _SHARDED_FFT: dict[tuple, object] = {}
 
 
@@ -132,14 +156,14 @@ def _sharded_fft(mesh: Mesh, n: int, n_stages: int):
         return fn
     from eth_consensus_specs_tpu.parallel.mesh_ops import BATCH_AXES
 
-    def local(vals, *twiddles):
-        return fft_stages(vals, list(twiddles), n)
+    def local(vals, enter, leave, *twiddles):
+        return fft_program(vals, enter, leave, list(twiddles), n)
 
     fn = jax.jit(
         shard_map(
             local,
             mesh=mesh,
-            in_specs=(P(BATCH_AXES),) + (P(),) * n_stages,
+            in_specs=(P(BATCH_AXES),) + (P(),) * (2 + n_stages),
             out_specs=P(BATCH_AXES),
             check_vma=False,
         ),
@@ -159,18 +183,15 @@ def _clear_sharded_after_fork_in_child() -> None:
 os.register_at_fork(after_in_child=_clear_sharded_after_fork_in_child)
 
 
-def batch_fft_mont(
-    vals_mont: jnp.ndarray, roots: tuple, mesh: Mesh | None = None
-) -> jnp.ndarray:
-    """[B, n, L] Montgomery limbs -> DFT, natural order in and out. With
-    a multi-device `mesh` the batch axis shards (B must divide evenly —
-    callers pad rows through serve/buckets.fr_fft_key, whose mesh-aware
-    bucket guarantees it)."""
-    n = vals_mont.shape[1]
+def _dispatch(vals, roots: tuple, enter: int, leave: int, mesh: Mesh | None):
+    """[B, n, L] limbs in BIT-REVERSED order -> the program's output,
+    natural order, still on the device. With a multi-device `mesh` the
+    batch axis shards (B must divide evenly — callers pad rows through
+    serve/buckets.fr_fft_key, whose mesh-aware bucket guarantees it)."""
+    n = vals.shape[1]
     assert n & (n - 1) == 0 and n == len(roots)
-    rev = jnp.asarray(_bit_reversal_indices(n))
-    vals = jnp.take(vals_mont, rev, axis=1)
-    twiddles = list(_device_twiddles(tuple(roots), n))
+    twiddles = _device_twiddles(roots, n)
+    consts = (FR.int_to_limbs(enter), FR.int_to_limbs(leave))
     from eth_consensus_specs_tpu.parallel.mesh_ops import shard_count
 
     if mesh is not None and shard_count(mesh) > 1:
@@ -179,8 +200,19 @@ def batch_fft_mont(
         assert vals.shape[0] % shard_count(mesh) == 0
         obs.count("mesh.dispatches", 1)
         obs.count("mesh.sharded_items", int(vals.shape[0]))
-        return _sharded_fft(mesh, n, len(twiddles))(vals, *twiddles)
-    return _compiled_fft(n, len(twiddles))(vals, *twiddles)
+        return _sharded_fft(mesh, n, len(twiddles))(vals, *consts, *twiddles)
+    return _compiled_fft(n, len(twiddles))(vals, *consts, *twiddles)
+
+
+def batch_fft_mont(
+    vals_mont: jnp.ndarray, roots: tuple, mesh: Mesh | None = None
+) -> jnp.ndarray:
+    """[B, n, L] Montgomery limbs in [0, 2r) -> DFT in Montgomery limbs
+    below r, natural order in and out: the same executable as
+    :func:`batch_fft_field`, entered and left with ``R mod r``."""
+    rev = jnp.asarray(_bit_reversal_indices(vals_mont.shape[1]))
+    one = FR.r_int % BLS_MODULUS
+    return _dispatch(jnp.take(vals_mont, rev, axis=1), tuple(roots), one, one, mesh)
 
 
 def batch_fft_field(
@@ -194,29 +226,32 @@ def batch_fft_field(
     applied row-wise (host ints in, host ints out). ``pad_batch`` pads
     the batch axis with zero rows to a bucketed compile shape (the serve
     layer passes its fr_fft_key bucket so accounting and dispatch
-    agree); padded rows are discarded."""
+    agree); padded rows are discarded.
+
+    No value is in Montgomery form on the host: plain limbs cross the
+    boundary both ways, and the program (:func:`fft_program`) enters
+    Montgomery form, leaves it and applies an inverse transform's 1/n."""
     roots = tuple(int(r) for r in roots_of_unity)
     n = len(roots)
     b = len(batches)
     with waterfall.leg("fr_fft.pack"):
-        rows = [[int(x) % BLS_MODULUS for x in row] for row in batches]
-        if pad_batch is not None:
-            assert pad_batch >= b
-            rows += [[0] * n] * (pad_batch - b)
-        arr = FR.ints_to_mont_batch(rows)
-    # host clock round a synced device call: transfer in, the FFT program,
-    # the eager inverse scaling, transfer out
+        flat = [int(x) % BLS_MODULUS for row in batches for x in row]
+        assert len(flat) == b * n
+        limbs = FR.ints_to_limbs_batch(flat).reshape(b, n, FR.n_limbs)
+        padded = b if pad_batch is None else pad_batch
+        assert padded >= b
+        arr = np.zeros((padded, n, FR.n_limbs), np.uint64)
+        arr[:b] = limbs[:, _bit_reversal_indices(n)]
+    # host clock round a synced device call: transfer in, the ONE program,
+    # transfer out
     with waterfall.leg("fr_fft.call"):
         if inv:
-            inv_roots = (roots[0],) + roots[:0:-1]
-            out = batch_fft_mont(jnp.asarray(arr), inv_roots, mesh=mesh)
-            invlen_mont = jnp.asarray(FR.to_mont(pow(n, BLS_MODULUS - 2, BLS_MODULUS)))
-            out = FR.mont_mul(out, invlen_mont)
-        else:
-            out = batch_fft_mont(jnp.asarray(arr), roots, mesh=mesh)
-        out = np.asarray(out)
+            roots = (roots[0],) + roots[:0:-1]
+        leave = pow(n, -1, BLS_MODULUS) if inv else 1
+        enter = FR.r_int * FR.r_int % BLS_MODULUS
+        out = np.asarray(_dispatch(jnp.asarray(arr), roots, enter, leave, mesh))
     with waterfall.leg("fr_fft.unpack"):
-        flat = FR.mont_batch_to_ints(out[:b])
+        flat = FR.limbs_to_ints_batch(out[:b])
         return [flat[i * n : (i + 1) * n] for i in range(b)]
 
 

@@ -33,6 +33,8 @@ class LimbField:
         self.modulus = modulus
         self.n_limbs = n_limbs
         self.r_int = 1 << (LIMB_BITS * n_limbs)
+        # 64-bit words that hold every limb: the host's array conversions
+        self.n_words = -(-LIMB_BITS * n_limbs // 64)
         self.n0_inv = (-pow(modulus, -1, 1 << LIMB_BITS)) % (1 << LIMB_BITS)
         self.p_limbs = self.int_to_limbs(modulus)
         self.p2_limbs = self.int_to_limbs(2 * modulus)
@@ -71,6 +73,45 @@ class LimbField:
         arr = np.asarray(limbs)
         flat = arr.reshape(-1, self.n_limbs)
         return [self.from_mont_int(row) for row in flat]
+
+    # The array forms of int_to_limbs / limbs_to_int: a limb is a bit
+    # field of the integer, so a whole batch is cut from (joined into)
+    # little-endian 64-bit words with one shift and mask a limb. A limb
+    # never reaches past the last word (n_words covers all the limbs).
+
+    def ints_to_limbs_batch(self, values) -> np.ndarray:
+        """Flat sequence of ints in [0, 2^(30 * n_limbs)) -> [len, n_limbs]
+        u64 limbs, row i equal to ``int_to_limbs(values[i])``."""
+        buf = b"".join([v.to_bytes(8 * self.n_words, "little") for v in values])
+        words = np.frombuffer(buf, dtype="<u8").reshape(-1, self.n_words)
+        out = np.empty((words.shape[0], self.n_limbs), np.uint64)
+        for k in range(self.n_limbs):
+            q, o = divmod(k * LIMB_BITS, 64)
+            limb = words[:, q] >> np.uint64(o)
+            if o + LIMB_BITS > 64:
+                limb = limb | (words[:, q + 1] << np.uint64(64 - o))
+            out[:, k] = limb & np.uint64(MASK)
+        # int_to_limbs' `assert x == 0`: no bit above the last limb
+        top = LIMB_BITS * self.n_limbs - 64 * (self.n_words - 1)
+        assert top == 64 or not (words[:, -1] >> np.uint64(top)).any()
+        return out
+
+    def limbs_to_ints_batch(self, limbs) -> list[int]:
+        """[..., n_limbs] limbs, each below 2^30 -> flat list of ints,
+        entry i equal to ``limbs_to_int`` of row i."""
+        arr = np.asarray(limbs, np.uint64).reshape(-1, self.n_limbs)
+        words = np.zeros((arr.shape[0], self.n_words), np.uint64)
+        for k in range(self.n_limbs):
+            q, o = divmod(k * LIMB_BITS, 64)
+            words[:, q] |= arr[:, k] << np.uint64(o)
+            if o + LIMB_BITS > 64:
+                words[:, q + 1] |= arr[:, k] >> np.uint64(64 - o)
+        buf = memoryview(words.astype("<u8", copy=False).tobytes())
+        step = 8 * self.n_words
+        return [
+            int.from_bytes(buf[i : i + step], "little")
+            for i in range(0, len(buf), step)
+        ]
 
     # -- device ops (shape-generic over leading axes) ----------------------
 
