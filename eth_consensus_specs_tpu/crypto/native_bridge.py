@@ -156,30 +156,49 @@ def g1_aggregate_affine(points: bytes) -> tuple[int, int] | None:
     return _g1_out(out, inf)
 
 
+def _over_threads(n: int, run) -> list:
+    """`run(at, count)` over slices of `n` items, one a thread (the core
+    runs without the GIL): the slices' starts and what each returned."""
+    import os
+    from concurrent.futures import ThreadPoolExecutor
+
+    step = max(-(-n // (os.cpu_count() or 1)), 256)
+    starts = range(0, n, step)
+    with ThreadPoolExecutor(max_workers=len(starts) or 1) as pool:
+        return list(zip(starts, pool.map(lambda at: run(at, min(step, n - at)), starts)))
+
+
 def g1_key_validate_many(keys: bytes) -> tuple[bytes, int]:
     """KeyValidate of the 48-byte compressed public keys laid end to end:
     (their 96-byte affine points end to end, the place of the first key
     that fails or the number of keys when none does). A registry's worth
-    is shared out over threads (the core runs without the GIL)."""
-    import os
-    from concurrent.futures import ThreadPoolExecutor
-
+    is shared out over threads."""
     lib = get_bls_lib()
     n = len(keys) // 48
     out = ctypes.create_string_buffer(96 * n)
     src = ctypes.create_string_buffer(keys, len(keys))
     base_in, base_out = ctypes.addressof(src), ctypes.addressof(out)
-    step = max(-(-n // (os.cpu_count() or 1)), 256)
-    starts = range(0, n, step)
-
-    def run(at: int) -> int:
-        count = min(step, n - at)
-        return at + lib.bls_g1_key_validate_many(count, base_in + 48 * at, base_out + 96 * at)
-
-    with ThreadPoolExecutor(max_workers=len(starts) or 1) as pool:
-        ends = list(pool.map(run, starts))
-    bad = min((e for at, e in zip(starts, ends) if e < min(at + step, n)), default=n)
+    ends = _over_threads(n, lambda at, count: (
+        count, lib.bls_g1_key_validate_many(count, base_in + 48 * at, base_out + 96 * at)))
+    bad = min((at + good for at, (count, good) in ends if good < count), default=n)
     return out.raw, bad
+
+
+def g1_decompress_many(points: bytes) -> tuple[bytes, bytes]:
+    """The 48-byte compressed G1 points laid end to end, each decided as
+    crypto/kzg.validate_kzg_g1 decides it: (their 96-byte affine forms end
+    to end, a status byte each: 1 a point of the subgroup, 2 the point at
+    infinity in its one encoding, 0 neither). A block's proofs are shared
+    out over threads."""
+    lib = get_bls_lib()
+    n = len(points) // 48
+    out = ctypes.create_string_buffer(96 * n)
+    status = ctypes.create_string_buffer(n)
+    src = ctypes.create_string_buffer(points, len(points))
+    base = [ctypes.addressof(b) for b in (src, out, status)]
+    _over_threads(n, lambda at, count: lib.bls_g1_decompress_many(
+        count, base[0] + 48 * at, base[1] + 96 * at, base[2] + at))
+    return out.raw, status.raw
 
 
 def g2_aggregate(points):

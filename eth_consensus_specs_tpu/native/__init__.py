@@ -240,6 +240,8 @@ def get_bls_lib() -> ctypes.CDLL | None:
     lib.bls_g2_in_subgroup.restype = c.c_int
     lib.bls_g1_key_validate_many.argtypes = [c.c_uint64, c.c_void_p, c.c_void_p]
     lib.bls_g1_key_validate_many.restype = c.c_uint64
+    lib.bls_g1_decompress_many.argtypes = [c.c_uint64, c.c_void_p, c.c_void_p, c.c_void_p]
+    lib.bls_g1_decompress_many.restype = None
     lib.bls_g2_clear_cofactor.argtypes = [u8p, u8p, u8p]
     lib.bls_g2_decompress.argtypes = [u8p, u8p, u8p]
     lib.bls_g2_decompress.restype = c.c_int

@@ -1480,14 +1480,9 @@ int bls_g1_in_subgroup(const uint8_t in[96]) {
     return g1_is_inf(&r);
 }
 
-/* KeyValidate of n compressed public keys, as crypto/curve.g1_from_bytes
- * decides it (compressed flag, x < p, on the curve, the sign flag, in the
- * subgroup) with infinity refused besides: out takes the 96-byte affine
- * points. Returns the place of the first key that fails, n when none
- * does. One call a slice of a registry, so that threads share the work. */
-uint64_t bls_g1_key_validate_many(uint64_t n, const uint8_t *in, uint8_t *out) {
-    ensure_init();
-    uint8_t pbe[48], halfbe[48];
+/* p and (p - 1) / 2 as 48 big-endian bytes: what a compressed x and the
+ * sign of y are compared with. */
+static void g1_compress_bounds(uint8_t pbe[48], uint8_t halfbe[48]) {
     uint64_t half[6];
     for (int i = 0; i < 6; i++) {
         half[i] = FP_P[i] >> 1;
@@ -1498,29 +1493,67 @@ uint64_t bls_g1_key_validate_many(uint64_t n, const uint8_t *in, uint8_t *out) {
             pbe[48 - 1 - (8 * i + j)] = (uint8_t)(FP_P[i] >> (8 * j));
             halfbe[48 - 1 - (8 * i + j)] = (uint8_t)(half[i] >> (8 * j));
         }
-    for (uint64_t k = 0; k < n; k++) {
-        const uint8_t *key = in + 48 * k;
-        int flags = key[0];
-        if (!(flags & 0x80) || (flags & 0x40)) return k;
-        uint8_t xb[48], yb[48];
-        memcpy(xb, key, 48);
-        xb[0] &= 0x1F;
-        if (memcmp(xb, pbe, 48) >= 0) return k;
-        fp x, y, y2, four;
-        fp_from_be(&x, xb);
-        fp_sqr(&y2, &x);
-        fp_mul(&y2, &y2, &x);
-        fp_one(&four);
-        fp_add(&four, &four, &four);
-        fp_add(&four, &four, &four);
-        fp_add(&y2, &y2, &four);
-        if (!fp_sqrt(&y, &y2)) return k;
-        fp_to_be(yb, &y);
-        if ((memcmp(yb, halfbe, 48) > 0) != ((flags & 0x20) ? 1 : 0)) fp_neg(&y, &y);
-        g1_store(out + 96 * k, &x, &y);
-        if (!bls_g1_in_subgroup(out + 96 * k)) return k;
-    }
+}
+
+/* One compressed point that is not the point at infinity, as
+ * crypto/curve.g1_from_bytes decides it (compressed flag, x < p, on the
+ * curve, the sign flag, in the subgroup): out takes its 96-byte affine
+ * form. Returns 1 where it is such a point, 0 otherwise (the infinity
+ * flag among the refusals). */
+static int g1_decompress_one(const uint8_t key[48], uint8_t out[96],
+                             const uint8_t pbe[48], const uint8_t halfbe[48]) {
+    int flags = key[0];
+    if (!(flags & 0x80) || (flags & 0x40)) return 0;
+    uint8_t xb[48], yb[48];
+    memcpy(xb, key, 48);
+    xb[0] &= 0x1F;
+    if (memcmp(xb, pbe, 48) >= 0) return 0;
+    fp x, y, y2, four;
+    fp_from_be(&x, xb);
+    fp_sqr(&y2, &x);
+    fp_mul(&y2, &y2, &x);
+    fp_one(&four);
+    fp_add(&four, &four, &four);
+    fp_add(&four, &four, &four);
+    fp_add(&y2, &y2, &four);
+    if (!fp_sqrt(&y, &y2)) return 0;
+    fp_to_be(yb, &y);
+    if ((memcmp(yb, halfbe, 48) > 0) != ((flags & 0x20) ? 1 : 0)) fp_neg(&y, &y);
+    g1_store(out, &x, &y);
+    return bls_g1_in_subgroup(out);
+}
+
+/* KeyValidate of n compressed public keys (infinity refused): out takes
+ * the 96-byte affine points. Returns the place of the first key that
+ * fails, n when none does. One call a slice of a registry, so that
+ * threads share the work. */
+uint64_t bls_g1_key_validate_many(uint64_t n, const uint8_t *in, uint8_t *out) {
+    ensure_init();
+    uint8_t pbe[48], halfbe[48];
+    g1_compress_bounds(pbe, halfbe);
+    for (uint64_t k = 0; k < n; k++)
+        if (!g1_decompress_one(in + 48 * k, out + 96 * k, pbe, halfbe)) return k;
     return n;
+}
+
+/* n compressed G1 points as crypto/kzg.validate_kzg_g1 admits them: a
+ * point of the subgroup, or the ONE encoding of the point at infinity
+ * (0xc0 and 47 zero bytes), which KeyValidate refuses and a KZG proof
+ * or commitment may be. status[k] takes 1 for a point (its affine form
+ * in out), 2 for infinity, 0 for bytes that are neither; every point is
+ * decided, so one bad proof costs its own sidecar only. */
+void bls_g1_decompress_many(uint64_t n, const uint8_t *in, uint8_t *out, uint8_t *status) {
+    ensure_init();
+    uint8_t pbe[48], halfbe[48], inf[48] = {0xC0};
+    g1_compress_bounds(pbe, halfbe);
+    for (uint64_t k = 0; k < n; k++) {
+        if (memcmp(in + 48 * k, inf, 48) == 0) {
+            memset(out + 96 * k, 0, 96);
+            status[k] = 2;
+        } else {
+            status[k] = (uint8_t)g1_decompress_one(in + 48 * k, out + 96 * k, pbe, halfbe);
+        }
+    }
 }
 
 /* psi(x, y) = (conj(x) * PSI_X, conj(y) * PSI_Y) on E'(Fp2). */

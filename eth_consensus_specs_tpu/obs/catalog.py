@@ -152,6 +152,16 @@ CATALOG: tuple[Metric, ...] = (
     # ---------------------------------------------------------------- das --
     _g("das.blobs", "blobs in the live DAS bench flush"),
     _c("das.flushes", "DAS bench blob-verification flushes"),
+    _c("das.columns_verified", "data column sidecars through verify_many_columns"),
+    _c("das.fft_rows", "cells interpolated, rows of a flush's one inverse FFT of 64 points"),
+    _c("das.isolated_invalid", "invalid data column sidecars isolated by bisection"),
+    _s("das.verify_many", "batched data column sidecar verification with bisection"),
+    _s("das.fold", "leg: dedup, Fiat-Shamir challenge and powers, the cells as the FFT's rows"),
+    _s("das.interp_fold", "leg: a sidecar's weighted sum of interpolation coefficients, coset unshift"),
+    _s("das.check", "leg: one check of a run of sidecars: sums, RLC, RLI, the pairing"),
+    _h("das.msm_call_ms",
+       "a flush's per-sidecar proof sums, ms: ONE multi-MSM execution (a sample an execution)"),
+    _h("das.rlc_check_ms", "one check of the verification equation, ms (a sample a check)"),
     # ------------------------------------------------------------- fault --
     _c("fault.degraded", "device->host degradations"),
     _c("fault.degraded.*", "degradations per site"),
@@ -190,7 +200,7 @@ CATALOG: tuple[Metric, ...] = (
     _c("serve.rejected", "admission sheds"),
     _c("serve.rejected.*", "admission sheds by reason (queue/bytes)"),
     _c("serve.requests", "submits admitted"),
-    _c("serve.requests.*", "submits by kind (bls/htr/state_root)"),
+    _c("serve.requests.*", "submits by kind (bls/htr/state_root/das)"),
     _g("serve.in_flight_bytes", "admitted payload bytes in flight"),
     _g("serve.queue_depth", "admitted requests queued + in flight"),
     _h("serve.compile_ms", "first-dispatch compile wall ms"),

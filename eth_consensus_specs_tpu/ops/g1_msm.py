@@ -42,6 +42,7 @@ from eth_consensus_specs_tpu.crypto.fields import Fq, P as P_INT
 from .field_limbs import (
     N_LIMBS,
     ONE_MONT,
+    R_INT,
     add_mod,
     from_mont_int,
     is_zero,
@@ -50,6 +51,7 @@ from .field_limbs import (
     sub_mod,
     to_mont,
 )
+from .limb_field import LimbField
 
 SCALAR_BITS = 256
 
@@ -364,30 +366,33 @@ def many_sum_shape(n_items: int, max_lanes: int, shards: int = 1) -> tuple[int, 
 # == host conversion boundary ==============================================
 
 
+# the host's array forms of int <-> 13 x 30-bit limbs (no device op of it is used)
+_FQ_LIMBS = LimbField(P_INT)
+
+
 def _points_to_limbs(points: list) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Affine points to Montgomery limbs u64[n, 13] a coordinate, Z = 0
+    for infinity: one multiply an integer, the limbs cut from them all by
+    array operations."""
     n = len(points)
     X = np.zeros((n, N_LIMBS), np.uint64)
     Y = np.zeros((n, N_LIMBS), np.uint64)
     Z = np.zeros((n, N_LIMBS), np.uint64)
-    one = to_mont(1)
-    for i, p in enumerate(points):
-        if p.is_infinity():
-            continue  # Z stays zero
-        X[i] = to_mont(p.x.n)
-        Y[i] = to_mont(p.y.n)
-        Z[i] = one
+    live = [i for i, p in enumerate(points) if not p.is_infinity()]
+    if live:
+        coords = [points[i].x.n * R_INT % P_INT for i in live]
+        coords += [points[i].y.n * R_INT % P_INT for i in live]
+        limbs = _FQ_LIMBS.ints_to_limbs_batch(coords)
+        X[live], Y[live], Z[live] = limbs[: len(live)], limbs[len(live) :], ONE_MONT
     return X, Y, Z
 
 
 def _scalars_to_bits(scalars: list[int]) -> np.ndarray:
-    n = len(scalars)
-    bits = np.zeros((n, SCALAR_BITS), np.uint64)
-    for i, k in enumerate(scalars):
-        k = int(k)
-        assert 0 <= k < (1 << SCALAR_BITS)
-        for j in range(SCALAR_BITS):
-            bits[i, j] = (k >> (SCALAR_BITS - 1 - j)) & 1
-    return bits
+    """Scalars in [0, 2^256) to their bits, most significant first,
+    u64[n, 256]; ``to_bytes`` raises OverflowError outside that range."""
+    buf = b"".join([int(k).to_bytes(SCALAR_BITS // 8, "big") for k in scalars])
+    octets = np.frombuffer(buf, np.uint8).reshape(len(scalars), SCALAR_BITS // 8)
+    return np.unpackbits(octets, axis=1).astype(np.uint64)
 
 
 def _jacobian_to_points(X, Y, Z) -> list[Point]:
@@ -498,13 +503,16 @@ def msm_g1_many_device(
         X = np.zeros((item_pad, lane_pad, N_LIMBS), np.uint64)
         Y = np.zeros((item_pad, lane_pad, N_LIMBS), np.uint64)
         Z = np.zeros((item_pad, lane_pad, N_LIMBS), np.uint64)
-        for i, (points, scalars) in enumerate(zip(point_lists, scalar_lists)):
-            assert len(points) == len(scalars)
-            if points:
-                X[i, : len(points)], Y[i, : len(points)], Z[i, : len(points)] = (
-                    _points_to_limbs(points)
-                )
-                bits[i, : len(points)] = _scalars_to_bits([int(s) for s in scalars])
+        # every item's lanes converted together, then laid at (item, lane)
+        lengths = [len(points) for points in point_lists]
+        assert lengths == [len(scalars) for scalars in scalar_lists]
+        if sum(lengths):
+            item = np.repeat(np.arange(n), lengths)
+            lane = np.concatenate([np.arange(k) for k in lengths])
+            X[item, lane], Y[item, lane], Z[item, lane] = _points_to_limbs(
+                [p for points in point_lists for p in points]
+            )
+            bits[item, lane] = _scalars_to_bits([s for scalars in scalar_lists for s in scalars])
         args = (jnp.asarray(bits), jnp.asarray(X), jnp.asarray(Y), jnp.asarray(Z))
     # host clock round a synced device call: launch, the MSM program,
     # transfer out
@@ -517,7 +525,7 @@ def msm_g1_many_device(
             rX, rY, rZ = msm_many_kernel(*args)
         rX, rY, rZ = np.asarray(rX), np.asarray(rY), np.asarray(rZ)
     with waterfall.leg("g1_msm.unpack"):
-        return [_jacobian_to_point(rX[i], rY[i], rZ[i]) for i in range(n)]
+        return _jacobian_to_points(rX[:n], rY[:n], rZ[:n])
 
 
 def sum_g1_many_device(

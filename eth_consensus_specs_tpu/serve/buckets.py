@@ -386,6 +386,17 @@ def bls_keysum_key(n_items: int, max_lanes: int, registry: int, mesh=None) -> tu
     return (*key, mesh_ops.mesh_signature(mesh)) if shards > 1 else key
 
 
+def das_msm_key(n_items: int, max_lanes: int) -> tuple:
+    """The compile/bucket/warmup key of a flush of data column sidecars'
+    multi-MSM (``g1_msm.msm_many_kernel`` through ``ops/das_batch``): two
+    items a sidecar and a lane a proof, each pow2-bucketed. Another family
+    than ``kzg``, whose program is the same kernel at two items: a block
+    of 128 sidecars of 21 blobs is 256 x 32. One chip holds the block, so
+    the key is never mesh-signed. The flush's inverse FFT runs under
+    :func:`fr_fft_key` at 64 points."""
+    return ("das_msm", pow2_bucket(max(int(n_items), 1)), pow2_bucket(max(int(max_lanes), 1)))
+
+
 def g2_agg_key_from_profile(
     n_items: int, max_lanes: int, shards: int = 1, sig: str = ""
 ) -> tuple:
@@ -811,7 +822,9 @@ def precompile(
     (``serve.precompile_skipped`` event per skip). ``key_table`` is the
     service's registry of public keys (ops/key_table.py), which the
     ``bls_keysum`` programs gather from; their keys are skipped without
-    it, or where it has another length than the key names."""
+    it, or where it has another length than the key names. A
+    ``das_msm`` key, and the ``fr_fft`` key at 64 points beside it, are
+    what sends a flush of data column sidecars to the device."""
     import numpy as np
 
     warmed = 0
@@ -878,6 +891,14 @@ def precompile(
                         [[g1_generator()]] * 2, [[1]] * 2,
                         mesh=mesh, pad_shape=(2, lanes),
                     )
+            elif op == "das_msm" and len(int_dims) == 2 and mesh is None:
+                from eth_consensus_specs_tpu.crypto.curve import g1_generator
+                from eth_consensus_specs_tpu.ops.g1_msm import msm_g1_many_device
+
+                # one throwaway lane at exactly the padded shape; only a
+                # warmed bucket's flushes go to the device (ops/das_batch.py)
+                with first_dispatch(op, *dims):
+                    msm_g1_many_device([[g1_generator()]], [[1]], pad_shape=int_dims)
             elif op == "fr_fft" and len(int_dims) == 2:
                 from eth_consensus_specs_tpu.crypto.kzg import compute_roots_of_unity
                 from eth_consensus_specs_tpu.ops.fr_fft import batch_fft_field

@@ -1,6 +1,6 @@
 """The in-process async verification service.
 
-Six submit verbs return ``concurrent.futures.Future``s:
+Seven submit verbs return ``concurrent.futures.Future``s:
 
   * ``submit_bls_aggregate(pubkeys, message, signature) -> Future[bool]``
     (the signers as 48-byte keys, or as indices into the registry that
@@ -12,6 +12,11 @@ Six submit verbs return ``concurrent.futures.Future``s:
   * ``submit_blob_verify(blob, commitment, proof) -> Future[bool]``
     (the DAS workload op: the flush folds into ONE batched inverse FFT
     + ONE RLC multi-MSM + one pairing — ops/kzg_batch)
+  * ``submit_column_verify(sidecar) -> Future[bool]`` (a Fulu data
+    column sidecar ``(index, column, kzg_commitments, kzg_proofs)``: the
+    flush's cells ride ONE batched inverse FFT of 64 points and ONE
+    multi-MSM whose items are the sidecars, a reject is isolated from
+    the partial sums with no second execution — ops/das_batch)
   * ``submit_hash_tree_root(chunks) -> Future[bytes]`` (32-byte root)
   * ``submit_state_root(arrays, meta, balances, eff_bal, inact, just)
     -> Future[np.ndarray]`` (u32[8] root words)
@@ -224,6 +229,25 @@ class VerifyService:
         item = (bytes(blob), bytes(commitment), bytes(proof))
         return self._submit("kzg", item, sum(len(b) for b in item), canary=canary)
 
+    def submit_column_verify(self, sidecar, canary: bool = False) -> Future:
+        """One data column sidecar ``(index, column, kzg_commitments,
+        kzg_proofs)`` (PeerDAS, Fulu); resolves to the exact bool
+        ``ops.das_batch.verify_column_host`` returns: the spec's
+        ``verify_data_column_sidecar and
+        verify_data_column_sidecar_kzg_proofs`` on that sidecar alone, a
+        malformed one ``False``, never an exception. A block's sidecars in
+        one flush share their commitments' decoding, one inverse FFT, one
+        multi-MSM and, where all are valid, one pairing. Admission
+        accounts the sidecar's bytes (45 KB at 21 blobs). The three
+        sequences are copied here and their elements taken as they are
+        (``bytes`` in practice; the flush's parse converts whatever else):
+        a block's 128 submits have the batcher's 5 ms to close one flush,
+        and take ~4 of them on a v5e's host."""
+        index, column, commitments, proofs = sidecar
+        parts = tuple(column), tuple(commitments), tuple(proofs)
+        cost = 8 + sum(sum(map(len, part)) for part in parts)
+        return self._submit("das", (int(index), *parts), cost, canary=canary)
+
     def submit_hash_tree_root(self, chunks: np.ndarray, canary: bool = False) -> Future:
         """Merkleize uint8[N, 32] chunks into the root of the pow2
         subtree holding them; resolves to the exact bytes
@@ -362,6 +386,16 @@ class VerifyService:
             # warms the bounded decompression cache (a malformed key is
             # the flush's to refuse: nothing raises here)
             warm_keys([r.payload for r in reqs if r.kind == "bls"])
+        das_reqs = [r for r in reqs if r.kind == "das"]
+        if das_reqs:
+            # the flush's sidecars together: structure checks, its distinct
+            # commitments decoded once (a block's 128 sidecars carry the
+            # same ones) and every proof in one call of the C core; None
+            # marks a malformed sidecar (a False verdict, not an error)
+            from eth_consensus_specs_tpu.ops.das_batch import prepare_columns
+
+            for r, column in zip(das_reqs, prepare_columns([r.payload for r in das_reqs])):
+                r.prepped = (column,)
         for r in reqs:
             try:
                 if r.kind == "htr":
@@ -518,6 +552,24 @@ class VerifyService:
                           sum(1 for r in kzg_reqs if not r.canary))
                 verdicts = [verify_blob_host(*r.payload) for r in kzg_reqs]
             for r, v in zip(kzg_reqs, verdicts):
+                results[id(r)] = bool(v)
+
+        das_reqs = [r for r in reqs if r.kind == "das"]
+        if das_reqs:
+            from eth_consensus_specs_tpu.ops import das_batch
+
+            if device:
+                # _prep parsed the flush; the op accounts its own two
+                # buckets and takes the device only for compiled ones
+                verdicts = das_batch.verify_many_columns(
+                    [r.payload for r in das_reqs],
+                    parsed=[r.prepped[0] for r in das_reqs],
+                )
+            else:
+                obs.count("serve.degraded_items",
+                          sum(1 for r in das_reqs if not r.canary))
+                verdicts = [das_batch.verify_column_host(r.payload) for r in das_reqs]
+            for r, v in zip(das_reqs, verdicts):
                 results[id(r)] = bool(v)
 
         agg_reqs = [r for r in reqs if r.kind == "agg"]
@@ -738,7 +790,10 @@ class VerifyService:
         dispatch mesh (``mesh_chips``), not the host-wide default. A
         ``("bls_keysum", items, lanes, registry)`` key warms the committee
         sums of flushes of that bucket over the registered registry: only
-        a warmed bucket's sums go to the device (ops/bls_batch.py)."""
+        a warmed bucket's sums go to the device (ops/bls_batch.py). A
+        ``("das_msm", items, lanes)`` key and the ``("fr_fft", rows, 64)``
+        key beside it do the same for a flush of data column sidecars
+        (ops/das_batch.py)."""
         return buckets.precompile(
             keys, path=path, chips=self.config.mesh_chips or None, key_table=self._keys
         )

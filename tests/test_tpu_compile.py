@@ -206,3 +206,36 @@ def test_state_root_at_2_20_compiles_with_the_unrolled_chain_body(one_chip, no_c
     assert "optimization_barrier" in body
     assert not body & {"scan", "while"}
     _check_row(chip_programs.compile_for(one_chip, prog))
+
+
+def _column_block_program(name: str) -> chip_programs.Program:
+    """The two programs of a Fulu block's 128 data column sidecars at 21
+    blobs (ops/das_batch), at the buckets the LIVE key functions give."""
+    from eth_consensus_specs_tpu.analysis import kernels
+    from eth_consensus_specs_tpu.ops import fr_fft, g1_msm
+    from eth_consensus_specs_tpu.serve import buckets
+
+    sidecars, blobs, points = 128, 21, 64
+    if name == "das_fft":
+        _, rows, n = buckets.fr_fft_key(sidecars * blobs, points)
+        stages = n.bit_length() - 1
+        assert (rows, n) == (4096, 64)
+        return chip_programs.Program(
+            name, lambda: (fr_fft._compiled_fft(n, stages), kernels._fr_fft_args(rows, n, stages)))
+    _, items, lanes = buckets.das_msm_key(2 * sidecars, blobs)
+    assert (items, lanes) == (256, 32)
+    return chip_programs.Program(
+        name, lambda: (g1_msm.msm_many_kernel, kernels._kzg_msm_args(items, lanes)), limb=True)
+
+
+@pytest.mark.parametrize(
+    "name",
+    # the limb kernel takes ~170 s to lower and compile here (the inverse FFT
+    # ~20 s): it is the one marked slow
+    ["das_fft", pytest.param("das_msm", marks=pytest.mark.slow)],
+)
+def test_the_data_column_programs_compile_at_a_blocks_buckets(one_chip, no_compile_cache, name):
+    """`peerdas_block_21.verify`'s two programs, the blob cell's kernels at
+    the opposite shape: 4,096 rows of 64 points where that cell has 8 of
+    4,096, 256 items x 32 lanes where it has 2 x 32."""
+    _check_row(chip_programs.compile_for(one_chip, _column_block_program(name)))
