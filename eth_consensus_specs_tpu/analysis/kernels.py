@@ -1185,7 +1185,7 @@ REGISTRY: tuple[KernelSpec, ...] = (
         dtypes=_LIMB_DTYPES,
         donation_waiver="lane arrays (N,13)x3 + bits (N,256) vs one Jacobian "
         "point (13,)x3 — no aval ever aliases an output",
-        wraps=limb_borrow_wraps("field_limbs.py", _MASK30),
+        wraps=lazy_lend_wraps(),
         build_variants=_g1_msm_variants,
     ),
     KernelSpec(
@@ -1195,7 +1195,7 @@ REGISTRY: tuple[KernelSpec, ...] = (
         dtypes=_LIMB_DTYPES,
         donation_waiver="committee lanes (I,L,13)x3 vs per-item points "
         "(I,13)x3 — shapes never alias",
-        wraps=limb_borrow_wraps("field_limbs.py", _MASK30),
+        wraps=lazy_lend_wraps(),
         build_variants=_bls_msm_variants,
         key_grid=_bls_msm_key_grid,
     ),
@@ -1207,7 +1207,7 @@ REGISTRY: tuple[KernelSpec, ...] = (
         donation_waiver="MSM lanes (I,L,13)x3 + bits (I,L,256) vs "
         "per-item Jacobian points (I,13)x3 — no aval ever aliases an "
         "output",
-        wraps=limb_borrow_wraps("field_limbs.py", _MASK30),
+        wraps=lazy_lend_wraps(),
         build_variants=_kzg_msm_variants,
         key_grid=_kzg_msm_key_grid,
     ),

@@ -2,7 +2,7 @@
 
 The limb kernels emulate 381-bit field arithmetic in u64 lanes, and their
 soundness rests on hand-reasoned magnitude bounds ("a column of 13 such
-products plus carries stays under 2^64", ops/field_limbs.py). This module
+products plus carries stays under 2^64", ops/limb_field.py). This module
 machine-checks those bounds: every jaxpr variable gets an integer interval
 ``[lo, hi]`` (exact python-int arithmetic — never numpy wraparound),
 seeded from the input domains the kernel registry declares, and propagated

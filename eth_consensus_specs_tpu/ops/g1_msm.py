@@ -10,14 +10,24 @@ tree reduction — the same shape as the merkle tree reduce, but over
 point adds (reference native analogue: arkworks `multiexp_unchecked`
 behind utils/bls.py:262-296).
 
-Doubling is dbl-2009-l (2M+5S), addition add-2007-bl (11M+5S); both are
-composed from ops/field_limbs Montgomery primitives, so one MSM lane is
-~20k u64 lane-multiplies per scalar bit — embarrassingly parallel across
-points, which is exactly what the VPU wants.
+Doubling is dbl-2009-l, addition add-2007-bl with a masked case
+analysis, both straight-line code over ops/lazy_limbs (15 x 26-bit
+limbs, every bound a Python integer checked at trace time): the field
+arithmetic has no loop of its own, so the only loops of a G1 program are
+the algorithm's (the 256-step bit loop, the strip scan of the committee
+sums, the levels of the tree). A scalar bit is 30 Montgomery multiplies
+(7 a doubling, 16 an addition, 7 more the doubling inside the addition
+for its equal-points lanes); additions and subtractions stay lazy in
+between, with a carry sweep only where a zero test, a doubled subtrahend
+or the loop boundary wants normalized limbs (five a bit) and conditional
+subtractions only where the accumulator crosses that boundary.
 
 Conversion boundary: affine crypto/curve.Point <-> Montgomery limb arrays
-on host; the single final Jacobian->affine inversion also stays host-side
-(one modular inverse per MSM, not worth a device Fermat chain yet).
+on host, 13 x 30-bit limbs (ops/field_limbs) into and out of every jitted
+program, which regroups the same 390 bits to 15 x 26 at entry and back at
+exit (one radix R = 2^390, so no multiply); the single final
+Jacobian->affine inversion also stays host-side (one modular inverse per
+MSM, not worth a device Fermat chain yet).
 """
 
 from __future__ import annotations
@@ -39,101 +49,157 @@ from eth_consensus_specs_tpu.obs import waterfall
 from eth_consensus_specs_tpu.crypto.curve import Point, B1, g1_infinity
 from eth_consensus_specs_tpu.crypto.fields import Fq, P as P_INT
 
+from . import lazy_limbs as lz
 from .field_limbs import (
+    LIMB_BITS as PACK_BITS,
     N_LIMBS,
     ONE_MONT,
     R_INT,
-    add_mod,
     from_mont_int,
-    is_zero,
-    mont_mul,
-    mont_sqr,
-    sub_mod,
     to_mont,
 )
+from .lazy_limbs import LF, lf
 from .limb_field import LimbField
 
 SCALAR_BITS = 256
 
+assert lz.R_INT == R_INT  # one Montgomery radix: the two limb forms hold the same integer
 
-def _dbl(X, Y, Z):
+
+def _regroup(a, bits_in: int, bits_out: int, n_out: int):
+    """The same integer in limbs of another width: u64[..., n] limbs of
+    `bits_in` bits (each below 2^bits_in) to `n_out` limbs of `bits_out`
+    bits, by static shifts and masks. Bits past the last input limb
+    read as zero, so the value is kept wherever it fits `n_out` limbs."""
+    n_in = a.shape[-1]
+    mask = jnp.uint64((1 << bits_out) - 1)
+    out = []
+    for j in range(n_out):
+        i, off = divmod(j * bits_out, bits_in)
+        limb = a[..., i] >> jnp.uint64(off)
+        have = bits_in - off
+        while have < bits_out and i + 1 < n_in:
+            i += 1
+            limb = limb | (a[..., i] << jnp.uint64(have))
+            have += bits_in
+        out.append(limb & mask)
+    return jnp.stack(out, axis=-1)
+
+
+def _to_lazy(a):
+    """A program's input, 13 x 30-bit Montgomery limbs in [0, 2p), as the
+    15 x 26-bit limbs the arithmetic runs on (canonical for `lazy_limbs`:
+    limbs < 2^26, value < 2p)."""
+    return _regroup(a, PACK_BITS, lz.LIMB_BITS, lz.N_LIMBS)
+
+
+def _from_lazy(a):
+    """Canonical 15 x 26-bit limbs back to the 13 x 30-bit limbs every
+    program returns (a value < 2p < 2^382 fits them)."""
+    return _regroup(a, lz.LIMB_BITS, PACK_BITS, N_LIMBS)
+
+
+def _to_lazy_point(X, Y, Z):
+    return _to_lazy(X), _to_lazy(Y), _to_lazy(Z)
+
+
+def _from_lazy_point(point):
+    X, Y, Z = point
+    return _from_lazy(X), _from_lazy(Y), _from_lazy(Z)
+
+
+# A point here is a tuple (X, Y, Z) of `LF`s. Inside a formula values stay
+# lazy, and a doubling is folded into a multiply's operand (2a * b for
+# 2ab): a product comes out canonical for free where a sum would need a
+# carry sweep and conditional subtractions. A point is made canonical
+# (`_canon`: limbs < 2^26, values < 2p) only where it leaves the traced
+# formulas as raw arrays: a loop carry, a program's result.
+
+
+def _wrap(X, Y, Z):
+    """Canonical limb arrays as a point."""
+    return lf(X), lf(Y), lf(Z)
+
+
+def _canon(point):
+    """A point's canonical limb arrays, so that `_wrap` on the other side
+    of a loop boundary tells the truth about them."""
+    return tuple(lz.shrink(c).v for c in point)
+
+
+def _dbl(point):
     """dbl-2009-l (a=0). Infinity (Z=0) and Y=0 both yield Z3=0."""
-    A = mont_sqr(X)
-    B = mont_sqr(Y)
-    C = mont_sqr(B)
-    t = mont_sqr(add_mod(X, B))
-    D = sub_mod(sub_mod(t, A), C)
-    D = add_mod(D, D)  # 2*((X+B)^2 - A - C)
-    E = add_mod(add_mod(A, A), A)  # 3A
-    F = mont_sqr(E)
-    X3 = sub_mod(F, add_mod(D, D))
-    C8 = add_mod(C, C)
-    C8 = add_mod(C8, C8)
-    C8 = add_mod(C8, C8)
-    Y3 = sub_mod(mont_mul(E, sub_mod(D, X3)), C8)
-    YZ = mont_mul(Y, Z)
-    Z3 = add_mod(YZ, YZ)
+    X, Y, Z = point
+    A = lz.mul(X, X)
+    B = lz.mul(Y, Y)
+    # D = 2*((X+B)^2 - A - C) is 4XB, and 8C is 8B^2
+    D = lz.mul(lz.dbl(X), lz.dbl(B))
+    C8 = lz.mul(lz.dbl(lz.dbl(B)), lz.dbl(B))
+    E = lz.add(lz.dbl(A), A)  # 3A
+    F = lz.mul(E, E)
+    X3 = lz.sub(lz.sub(F, D), D)
+    Y3 = lz.sub(lz.mul(E, lz.sub(D, X3)), C8)
+    Z3 = lz.mul(lz.dbl(Y), Z)
     return X3, Y3, Z3
 
 
-def _select(mask, a, b):
-    """Per-lane select over limb arrays: mask ? a : b."""
-    return jnp.where(mask[..., None], a, b)
+def _select(mask, a: LF, b: LF) -> LF:
+    """Per-lane select between two elements: mask ? a : b, under the
+    weaker of their bounds."""
+    return LF(jnp.where(mask[..., None], a.v, b.v), max(a.max, b.max), max(a.val, b.val))
 
 
-def _add(X1, Y1, Z1, X2, Y2, Z2):
+def _select_point(mask, p, q):
+    return tuple(_select(mask, a, b) for a, b in zip(p, q))
+
+
+def _add(p, q):
     """Complete Jacobian add via masked case analysis (add-2007-bl core)."""
-    Z1Z1 = mont_sqr(Z1)
-    Z2Z2 = mont_sqr(Z2)
-    U1 = mont_mul(X1, Z2Z2)
-    U2 = mont_mul(X2, Z1Z1)
-    S1 = mont_mul(mont_mul(Y1, Z2), Z2Z2)
-    S2 = mont_mul(mont_mul(Y2, Z1), Z1Z1)
-    H = sub_mod(U2, U1)
-    rr = sub_mod(S2, S1)
-    r2 = add_mod(rr, rr)
-    HH = add_mod(H, H)
-    I = mont_sqr(HH)
-    J = mont_mul(H, I)
-    V = mont_mul(U1, I)
-    X3 = sub_mod(sub_mod(mont_sqr(r2), J), add_mod(V, V))
-    SJ = mont_mul(S1, J)
-    Y3 = sub_mod(mont_mul(r2, sub_mod(V, X3)), add_mod(SJ, SJ))
-    ZZ = sub_mod(sub_mod(mont_sqr(add_mod(Z1, Z2)), Z1Z1), Z2Z2)
-    Z3 = mont_mul(ZZ, H)
+    X1, Y1, Z1 = p
+    X2, Y2, Z2 = q
+    Z1Z1 = lz.mul(Z1, Z1)
+    Z2Z2 = lz.mul(Z2, Z2)
+    U1 = lz.mul(X1, Z2Z2)
+    U2 = lz.mul(X2, Z1Z1)
+    S1 = lz.mul(lz.mul(Y1, Z2), Z2Z2)
+    S2 = lz.mul(lz.mul(Y2, Z1), Z1Z1)
+    H = lz.sub(U2, U1)
+    rr = lz.sub(S2, S1)
+    r2 = lz.dbl(rr)
+    HH = lz.dbl(H)
+    I = lz.mul(HH, HH)
+    J = lz.mul(H, I)
+    V2 = lz.mul(U1, lz.dbl(I))  # 2V
+    # swept here, since 2*X3 is subtracted below: a subtrahend's limbs
+    # set the multiple of p that covers them
+    X3 = lz.norm(lz.sub(lz.sub(lz.mul(r2, r2), J), V2))
+    SJ2 = lz.mul(lz.dbl(S1), J)  # 2*S1*J
+    # r2*(V - X3) as rr*(2V - 2*X3)
+    Y3 = lz.sub(lz.mul(rr, lz.sub(V2, lz.dbl(X3))), SJ2)
+    # (Z1+Z2)^2 - Z1Z1 - Z2Z2 is 2*Z1*Z2
+    Z3 = lz.mul(lz.mul(lz.dbl(Z1), Z2), H)
 
-    p1_inf = is_zero(Z1)
-    p2_inf = is_zero(Z2)
-    same_x = is_zero(H)
-    same_y = is_zero(rr)
-
-    dX, dY, dZ = _dbl(X1, Y1, Z1)
-
+    same_x = lz.is_zero(H)
+    same_y = lz.is_zero(rr)
     # default: generic add; same point: double; opposite points: infinity
-    outX = _select(same_x & same_y, dX, X3)
-    outY = _select(same_x & same_y, dY, Y3)
-    outZ = _select(same_x & same_y, dZ, _select(same_x, jnp.zeros_like(Z3), Z3))
+    opposite = same_x & ~same_y
+    out = (X3, Y3, _select(opposite, lz.zero_like(Z3), Z3))
+    out = _select_point(same_x & same_y, _dbl(p), out)
     # either input at infinity: pass the other through
-    outX = _select(p1_inf, X2, _select(p2_inf, X1, outX))
-    outY = _select(p1_inf, Y2, _select(p2_inf, Y1, outY))
-    outZ = _select(p1_inf, Z2, _select(p2_inf, Z1, outZ))
-    return outX, outY, outZ
+    out = _select_point(lz.is_zero(Z2), p, out)
+    return _select_point(lz.is_zero(Z1), q, out)
 
 
 def _scalar_mul_lane(bits, X, Y, Z):
     """Double-and-add over MSB-first `bits` (u64[256]) for one lane; runs
-    under vmap so every op broadcasts across lanes."""
+    under vmap so every op broadcasts across lanes. Takes and returns
+    canonical limb arrays, and the accumulator crosses the loop boundary
+    as such."""
+    base = _wrap(X, Y, Z)
 
     def body(i, acc):
-        aX, aY, aZ = acc
-        aX, aY, aZ = _dbl(aX, aY, aZ)
-        sX, sY, sZ = _add(aX, aY, aZ, X, Y, Z)
-        take = bits[i] != 0
-        return (
-            _select(take, sX, aX),
-            _select(take, sY, aY),
-            _select(take, sZ, aZ),
-        )
+        acc = _dbl(_wrap(*acc))
+        return _canon(_select_point(bits[i] != 0, _add(acc, base), acc))
 
     inf = (jnp.zeros_like(X), jnp.zeros_like(Y), jnp.zeros_like(Z))
     # i32 loop bounds: python-int bounds widen the bit counter to i64
@@ -142,30 +208,46 @@ def _scalar_mul_lane(bits, X, Y, Z):
 
 
 def _tree_sum(mX, mY, mZ):
-    """Pairwise point-sum of N (power-of-two) Jacobian lanes."""
+    """Pairwise point-sum of N (power-of-two) Jacobian lanes, canonical
+    limb arrays in and out. The tree's levels are passes of ONE add body
+    over N/2 lanes: a level of `width` live lanes adds lane i + width/2
+    onto lane i, and what the lanes from width/2 up then hold nothing
+    reads. An add unrolled a level was most of a program's code."""
     n = mX.shape[0]
-    while n > 1:
-        half = n // 2
-        mX, mY, mZ = _add(
-            mX[:half], mY[:half], mZ[:half], mX[half:], mY[half:], mZ[half:]
-        )
-        n = half
-    return mX[0], mY[0], mZ[0]
+    if n == 1:
+        return mX[0], mY[0], mZ[0]
+    half = n // 2
+
+    def level(lanes, width):
+        low = _wrap(*(c[:half] for c in lanes))
+        high = _wrap(*(lax.dynamic_slice_in_dim(c, width // 2, half) for c in lanes))
+        sums = _canon(_add(low, high))
+        return tuple(jnp.concatenate([s, c[half:]]) for s, c in zip(sums, lanes)), None
+
+    widths = np.array([n >> k for k in range(n.bit_length() - 1)], np.int32)
+    lanes, _ = lax.scan(level, (mX, mY, mZ), widths)
+    return tuple(c[0] for c in lanes)
+
+
+def _msm_lanes(bits, X, Y, Z):
+    """One item's MSM: vmapped double-and-add over its lanes + pairwise
+    tree reduce — the shared body of msm_kernel and the batched
+    per-item variant below."""
+    return _tree_sum(*jax.vmap(_scalar_mul_lane)(bits, X, Y, Z))
 
 
 @jax.jit
 def msm_kernel(bits, X, Y, Z):
     """MSM over N (power-of-two) lanes: bits u64[N,256], X/Y/Z u64[N,13]
     (Montgomery). Returns Jacobian (X,Y,Z) u64[13] of sum_i k_i * P_i."""
-    mX, mY, mZ = jax.vmap(_scalar_mul_lane)(bits, X, Y, Z)
-    return _tree_sum(mX, mY, mZ)
+    return _from_lazy_point(_msm_lanes(bits, *_to_lazy_point(X, Y, Z)))
 
 
 @jax.jit
 def sum_kernel(X, Y, Z):
     """Plain point sum over N (power-of-two) lanes — the unit-scalar MSM
     without the 256-bit double-and-add (aggregate-pubkey fast path)."""
-    return _tree_sum(X, Y, Z)
+    return _from_lazy_point(_tree_sum(*_to_lazy_point(X, Y, Z)))
 
 
 @jax.jit
@@ -173,7 +255,7 @@ def sum_many_kernel(X, Y, Z):
     """Per-item point sums over [I, L, 13] lane arrays (L a power of
     two): the batched aggregate-pubkey kernel — one dispatch sums every
     committee of a flush instead of one dispatch per item."""
-    return jax.vmap(_tree_sum)(X, Y, Z)
+    return _from_lazy_point(jax.vmap(_tree_sum)(*_to_lazy_point(X, Y, Z)))
 
 
 def _strip_sum(X, Y, Z, strip: int):
@@ -185,10 +267,10 @@ def _strip_sum(X, Y, Z, strip: int):
     lanes = X.shape[0]
     if strip >= lanes:
         return _tree_sum(X, Y, Z)
-    parts = [a.reshape(lanes // strip, strip, N_LIMBS) for a in (X, Y, Z)]
+    parts = [a.reshape(lanes // strip, strip, lz.N_LIMBS) for a in (X, Y, Z)]
 
     def step(acc, part):
-        return _add(*acc, *part), None
+        return _canon(_add(_wrap(*acc), _wrap(*part))), None
 
     acc, _ = lax.scan(step, tuple(a[0] for a in parts), tuple(a[1:] for a in parts))
     return _tree_sum(*acc)
@@ -197,8 +279,11 @@ def _strip_sum(X, Y, Z, strip: int):
 def _sum_indexed(table_x, table_y, index, strip: int):
     live = index >= 0
     at = jnp.where(live, index, 0)
-    Z = jnp.where(live[..., None], jnp.asarray(ONE_MONT), jnp.uint64(0))
-    return jax.vmap(partial(_strip_sum, strip=strip))(table_x[at], table_y[at], Z)
+    Z = jnp.where(live[..., None], jnp.asarray(lz.ONE_MONT), jnp.uint64(0))
+    sums = jax.vmap(partial(_strip_sum, strip=strip))(
+        _to_lazy(table_x[at]), _to_lazy(table_y[at]), Z
+    )
+    return _from_lazy_point(sums)
 
 
 @partial(jax.jit, static_argnames="strip")
@@ -211,13 +296,6 @@ def sum_indexed_kernel(table_x, table_y, index, strip: int):
     return _sum_indexed(table_x, table_y, index, strip)
 
 
-def _msm_lanes(bits, X, Y, Z):
-    """One item's MSM: vmapped double-and-add over its lanes + pairwise
-    tree reduce — the shared body of msm_kernel and the batched
-    per-item variant below."""
-    return _tree_sum(*jax.vmap(_scalar_mul_lane)(bits, X, Y, Z))
-
-
 @jax.jit
 def msm_many_kernel(bits, X, Y, Z):
     """Per-item full-scalar MSMs over [I, L, ...] lane arrays (L a power
@@ -228,7 +306,7 @@ def msm_many_kernel(bits, X, Y, Z):
     needs TWO independent MSMs (the proof lincomb and the commitment-
     minus-y + proof-z lincomb) and this kernel runs both in ONE
     dispatch instead of two msm_kernel round-trips."""
-    return jax.vmap(_msm_lanes)(bits, X, Y, Z)
+    return _from_lazy_point(jax.vmap(_msm_lanes)(bits, *_to_lazy_point(X, Y, Z)))
 
 
 # == mesh-sharded kernels ==================================================
@@ -244,13 +322,12 @@ def msm_many_kernel(bits, X, Y, Z):
 #     byte-identical points.
 
 
-def _cross_shard_tree_sum(rX, rY, rZ, axes):
-    """all_gather per-shard Jacobian partials ([..., 13] each) and
-    tree-sum them over the gathered shard axis; non-pow2 shard counts
-    pad with infinity lanes (Z = 0)."""
-    gX = lax.all_gather(rX, axes)
-    gY = lax.all_gather(rY, axes)
-    gZ = lax.all_gather(rZ, axes)
+def _cross_shard_tree_sum(point, axes):
+    """all_gather per-shard Jacobian partials (canonical lazy limbs here,
+    [..., 13] each as gathered and as returned) and tree-sum them over
+    the gathered shard axis; non-pow2 shard counts pad with infinity
+    lanes (Z = 0)."""
+    gX, gY, gZ = (lax.all_gather(a, axes) for a in _from_lazy_point(point))
     s = gX.shape[0]
     cap = 1 << max(s - 1, 0).bit_length()
     if cap != s:
@@ -258,7 +335,7 @@ def _cross_shard_tree_sum(rX, rY, rZ, axes):
         gX = jnp.pad(gX, pad)
         gY = jnp.pad(gY, pad)
         gZ = jnp.pad(gZ, pad)
-    return _tree_sum(gX, gY, gZ)
+    return _from_lazy_point(_tree_sum(*_to_lazy_point(gX, gY, gZ)))
 
 
 _SHARDED_FNS: dict[tuple, object] = {}
@@ -277,8 +354,9 @@ def _sharded_fn(mesh: Mesh, kind: str):
     if kind == "msm":
 
         def local(bits, X, Y, Z):
-            mX, mY, mZ = jax.vmap(_scalar_mul_lane)(bits, X, Y, Z)
-            return _cross_shard_tree_sum(*_tree_sum(mX, mY, mZ), BATCH_AXES)
+            return _cross_shard_tree_sum(
+                _msm_lanes(bits, *_to_lazy_point(X, Y, Z)), BATCH_AXES
+            )
 
         fn = jax.jit(
             shard_map(local, mesh=mesh, in_specs=spec, out_specs=P(), check_vma=False)
@@ -286,7 +364,7 @@ def _sharded_fn(mesh: Mesh, kind: str):
     elif kind == "sum":
 
         def local(X, Y, Z):
-            return _cross_shard_tree_sum(*_tree_sum(X, Y, Z), BATCH_AXES)
+            return _cross_shard_tree_sum(_tree_sum(*_to_lazy_point(X, Y, Z)), BATCH_AXES)
 
         fn = jax.jit(
             shard_map(local, mesh=mesh, in_specs=spec, out_specs=P(), check_vma=False)
@@ -300,8 +378,9 @@ def _sharded_fn(mesh: Mesh, kind: str):
         lane_spec = P(None, BATCH_AXES)
 
         def local(bits, X, Y, Z):
-            pX, pY, pZ = jax.vmap(_msm_lanes)(bits, X, Y, Z)
-            return _cross_shard_tree_sum(pX, pY, pZ, BATCH_AXES)
+            return _cross_shard_tree_sum(
+                jax.vmap(_msm_lanes)(bits, *_to_lazy_point(X, Y, Z)), BATCH_AXES
+            )
 
         fn = jax.jit(
             shard_map(
@@ -325,7 +404,7 @@ def _sharded_fn(mesh: Mesh, kind: str):
     else:  # "sum_many": item axis sharded, no collectives
 
         def local(X, Y, Z):
-            return jax.vmap(_tree_sum)(X, Y, Z)
+            return _from_lazy_point(jax.vmap(_tree_sum)(*_to_lazy_point(X, Y, Z)))
 
         fn = jax.jit(
             shard_map(local, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False)

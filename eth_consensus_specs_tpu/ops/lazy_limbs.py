@@ -1,10 +1,14 @@
 """Lazy-reduction BLS12-381 base-field limbs for the device (u64 lanes).
 
-The first-generation Fq kernel (ops/field_limbs.py, 13x30-bit limbs)
-normalizes limbs after EVERY add/sub — a ~130-node carry/borrow subgraph
-per operation that made pairing-sized XLA graphs take minutes to compile
-(measured: 53s for ONE Fq12 product, while a plain 400-op u64 chain
-compiles in 0.8s — node count is the whole story).
+The first-generation Fq kernel (13x30-bit limbs, a `lax.scan` per carry
+sweep; gone since G1 moved here) normalized limbs after EVERY add/sub — a
+~130-node carry/borrow subgraph per operation that made pairing-sized XLA
+graphs take minutes to compile (measured: 53s for ONE Fq12 product, while
+a plain 400-op u64 chain compiles in 0.8s), and on the chip each of its
+loop trips cost about what a whole fused multiply costs here. Every Fq
+kernel (G1, G2, the tower, the pairing, hash-to-curve) runs on this
+module; ops/field_limbs.py keeps only the 13x30-bit packed form that
+crosses a G1 program's boundary.
 
 This module keeps limbs LAZY, the way hand-written pairing libraries
 (blst/RELIC) do, with every bound tracked STATICALLY at trace time:
@@ -327,10 +331,11 @@ def mul(x: LF, y: LF) -> LF:
 
 
 def is_zero(x: LF):
-    """True iff x == 0 mod p, for x with value < 2p (mont outputs)."""
-    assert x.val <= 2 * P_INT - 1, "is_zero expects a reduced element"
+    """True iff x == 0 mod p: the normalized limbs are those of a
+    multiple of p below the static value bound (0 or p for a mont
+    output, value < 2p)."""
     n = norm(x)
-    p_vec = jnp.asarray(P_LIMBS)
-    exact_zero = jnp.all(n.v == 0, axis=-1)
-    exact_p = jnp.all(n.v == jnp.broadcast_to(p_vec, n.v.shape), axis=-1)
-    return exact_zero | exact_p
+    hit = jnp.all(n.v == 0, axis=-1)
+    for k in range(1, x.val // P_INT + 1):
+        hit = hit | jnp.all(n.v == jnp.asarray(int_to_limbs(k * P_INT)), axis=-1)
+    return hit

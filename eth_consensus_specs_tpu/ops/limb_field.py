@@ -1,11 +1,14 @@
 """Parameterized fixed-limb modular arithmetic for the device.
 
-Generalizes the proven Fq kernel structure (ops/field_limbs.py — see that
-module's docstring for the no-dot-general / redundant-range rationale) to
-any odd modulus: 30-bit limbs in uint64 lanes, Montgomery (SOS) multiply
-with a lax.scan reduction, values kept in [0, 2p).  The BLS *scalar*
-field instance (9x30-bit limbs for the 255-bit r) backs the DAS FFT
-kernel (ops/fr_fft.py); Fq keeps its dedicated module.
+30-bit limbs in uint64 lanes for any odd modulus: a 30x30-bit partial
+product is < 2^60 and a column of such products plus carries stays under
+2^64, so schoolbook accumulation never overflows a lane. The product is
+an unrolled pad-shift-add (NOT a dot/einsum: XLA:TPU cannot lower a u64
+dot_general), the Montgomery (SOS) reduction a lax.scan, values kept in
+the redundant range [0, 2p) (R > 4p, so a Montgomery output needs no
+conditional subtraction).  The BLS *scalar* field instance (9x30-bit
+limbs for the 255-bit r) backs the DAS FFT kernel (ops/fr_fft.py); Fq
+runs on ops/lazy_limbs.
 """
 
 from __future__ import annotations

@@ -142,7 +142,7 @@ def test_select_and_concat_transfer():
 
 
 def test_column_sum_proof_30_bits_clean_31_bits_fires():
-    """THE proof from the field_limbs comment, both directions: a column
+    """THE proof from the limb_field comment, both directions: a column
     of 13 products of 30-bit limbs plus carries stays under 2^64 — and
     at 31-bit limbs it does NOT, which must fire lane-overflow."""
 

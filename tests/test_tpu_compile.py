@@ -230,8 +230,8 @@ def _column_block_program(name: str) -> chip_programs.Program:
 
 @pytest.mark.parametrize(
     "name",
-    # the limb kernel takes ~170 s to lower and compile here (the inverse FFT
-    # ~20 s): it is the one marked slow
+    # the limb kernel takes ~80 s to lower and compile here (~170 s before G1
+    # ran on lazy limbs; the inverse FFT ~20 s): it is the one marked slow
     ["das_fft", pytest.param("das_msm", marks=pytest.mark.slow)],
 )
 def test_the_data_column_programs_compile_at_a_blocks_buckets(one_chip, no_compile_cache, name):
