@@ -377,19 +377,29 @@ def _merkle_inc_key_grid(mesh):
 def _shuffle_variants(mesh):
     from eth_consensus_specs_tpu.ops import shuffle
 
-    n, rounds = 512, 90
-    num_chunks = (n + 255) // 256
+    lanes, rounds = 512, 90
     return [
         Variant(
             "single",
-            shuffle._device_shuffle_kernel(n, rounds, num_chunks),
-            (_sds((rounds * num_chunks, 16), "uint32"), _sds((rounds,), "int32")),
+            shuffle.shuffle_rounds_kernel,
+            (
+                _sds((8,), "uint32"),
+                _sds((rounds,), "int32"),
+                _sds((), "int32"),
+                _sds((lanes,), "int32"),
+            ),
             domains=(
                 _WORDS32,
                 Domain(
                     "round pivots in [0, n)",
-                    hi=n - 1,
-                    corners=(("zero", 0), ("n-1", n - 1)),
+                    hi=lanes - 1,
+                    corners=(("zero", 0), ("n-1", lanes - 1)),
+                ),
+                Domain("active count in [1, lanes]", lo=1, hi=lanes, corners=(("one", 1), ("full", lanes))),
+                Domain(
+                    "registry indices below 2**31",
+                    hi=(1 << 31) - 1,
+                    corners=(("zero", 0), ("2**31-1", (1 << 31) - 1)),
                 ),
             ),
         )
@@ -1164,8 +1174,8 @@ REGISTRY: tuple[KernelSpec, ...] = (
         name="shuffle",
         help="whole-permutation swap-or-not shuffle (ops/shuffle)",
         dtypes=frozenset({"uint32", "int32", "bool"}),
-        donation_waiver="decision blocks and pivots are read-only; the index "
-        "plane lives in the loop carry, not an argument buffer",
+        donation_waiver="seed words and pivots are read-only; the padded list is "
+        "the caller's host array, and the loop carries its own copy",
         wraps=_SHA_WRAPS,
         build_variants=_shuffle_variants,
     ),

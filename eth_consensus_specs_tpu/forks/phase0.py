@@ -448,9 +448,10 @@ class Phase0Spec:
     def _shuffle_permutation(self, index_count: int, seed: bytes):
         """Whole permutation, cached by (seed, n). perm[i] ==
         compute_shuffled_index(i, n, seed). On an accelerator backend large
-        registries go through the device kernel (ops/shuffle.py
-        shuffle_permutation_device, bit-equal by test); small sets and CPU
-        runs keep the numpy host form."""
+        registries go through the device program (ops/shuffle.py
+        shuffle_permutation_device: one executable a lane bucket, the count
+        a traced number, bit-equal by test); small sets and CPU runs keep
+        the numpy host form."""
         key = (bytes(seed), index_count)
         if key not in self._shuffle_cache:
             perm = None
@@ -465,10 +466,8 @@ class Phase0Spec:
                             shuffle_permutation_device,
                         )
 
-                        perm = _np.asarray(
-                            shuffle_permutation_device(
-                                index_count, bytes(seed), self.SHUFFLE_ROUND_COUNT
-                            )
+                        perm = shuffle_permutation_device(
+                            index_count, bytes(seed), self.SHUFFLE_ROUND_COUNT
                         ).astype(_np.int64)
                 except Exception:
                     perm = None

@@ -60,10 +60,13 @@ CATALOG: tuple[Metric, ...] = (
     _c("merkle.trees", "merkle trees computed"),
     _s("merkle.subtree_root", "single-tree device merkleization"),
     _s("merkle.many_subtree_root", "vmapped multi-tree device merkleization"),
-    _c("shuffle.decision_hashes", "swap-or-not decision hashes"),
-    _c("shuffle.lanes", "shuffle lanes processed"),
+    _c("shuffle.decision_hashes", "swap-or-not decision hashes of live chunks"),
+    _c("shuffle.lanes", "live shuffle lanes processed (the active count, not its bucket)"),
     _c("shuffle.permutations", "full committee permutations"),
     _s("shuffle.permutation", "device shuffle permutation"),
+    _s("shuffle.pack", "leg: the rounds' pivots, the seed's words, the indices padded to the lane bucket"),
+    _s("shuffle.call", "leg: host clock round the synced shuffle program (transfer in, the list ready)"),
+    _s("shuffle.unpack", "leg: the shuffled list to the host, cut to the active count"),
     _c("state_root.real_hashes", "hashes in post-epoch state roots"),
     _c("state_root.chain_steps", "sequential hash steps of state roots' list tails"),
     _c("state_root.roots", "post-epoch state roots computed"),

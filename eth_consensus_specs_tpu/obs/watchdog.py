@@ -192,12 +192,15 @@ def _spec_shuffled_index(index: int, n: int, seed: bytes, rounds: int) -> int:
     return index
 
 
-def check_shuffle_slice(perm, n: int, seed: bytes, rounds: int) -> bool:
+def check_shuffle_slice(perm, n: int, seed: bytes, rounds: int, active=None) -> bool:
     """Sampled lanes of the device permutation vs the per-index spec loop
-    (only the sampled lanes cross to the host)."""
+    (only the sampled lanes cross to the host). With `active`, `perm` is
+    the shuffled list of those indices, `active[spec index]` a lane."""
     ok = True
     for i in _sample_rows(n, k=2):
         expect = _spec_shuffled_index(i, n, seed, rounds)
+        if active is not None:
+            expect = int(active[expect])
         got = int(np.asarray(perm[i]))
         if got != expect:
             ok = False

@@ -397,6 +397,14 @@ def das_msm_key(n_items: int, max_lanes: int) -> tuple:
     return ("das_msm", pow2_bucket(max(int(n_items), 1)), pow2_bucket(max(int(max_lanes), 1)))
 
 
+def shuffle_key(n: int) -> tuple:
+    """The compile/bucket/warmup key of an epoch's shuffle
+    (``ops/shuffle.shuffle_rounds_kernel``): the lane bucket, the power of
+    two at or above the active count, which the program takes as a traced
+    number. One chip holds the list, so the key is never mesh-signed."""
+    return ("shuffle", pow2_bucket(max(int(n), 1)))
+
+
 def g2_agg_key_from_profile(
     n_items: int, max_lanes: int, shards: int = 1, sig: str = ""
 ) -> tuple:
@@ -824,7 +832,9 @@ def precompile(
     ``bls_keysum`` programs gather from; their keys are skipped without
     it, or where it has another length than the key names. A
     ``das_msm`` key, and the ``fr_fft`` key at 64 points beside it, are
-    what sends a flush of data column sidecars to the device."""
+    what sends a flush of data column sidecars to the device; a
+    ``shuffle`` key does the same for committee requests of its lane
+    bucket."""
     import numpy as np
 
     warmed = 0
@@ -899,6 +909,19 @@ def precompile(
                 # warmed bucket's flushes go to the device (ops/das_batch.py)
                 with first_dispatch(op, *dims):
                     msm_g1_many_device([[g1_generator()]], [[1]], pad_shape=int_dims)
+            elif op == "shuffle" and len(int_dims) == 1 and mesh is None:
+                from eth_consensus_specs_tpu.ops.shuffle import (
+                    mainnet_rounds,
+                    shuffled_indices_device,
+                )
+
+                # one live lane under the bucket: the count is a traced
+                # number, so this is the program every count below it runs
+                with first_dispatch(op, *dims):
+                    shuffled_indices_device(
+                        np.zeros(1, np.int32), bytes(32), mainnet_rounds(),
+                        lanes=int_dims[0],
+                    )
             elif op == "fr_fft" and len(int_dims) == 2:
                 from eth_consensus_specs_tpu.crypto.kzg import compute_roots_of_unity
                 from eth_consensus_specs_tpu.ops.fr_fft import batch_fft_field

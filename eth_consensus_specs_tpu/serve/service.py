@@ -1,6 +1,6 @@
 """The in-process async verification service.
 
-Seven submit verbs return ``concurrent.futures.Future``s:
+Eight submit verbs return ``concurrent.futures.Future``s:
 
   * ``submit_bls_aggregate(pubkeys, message, signature) -> Future[bool]``
     (the signers as 48-byte keys, or as indices into the registry that
@@ -20,6 +20,11 @@ Seven submit verbs return ``concurrent.futures.Future``s:
   * ``submit_hash_tree_root(chunks) -> Future[bytes]`` (32-byte root)
   * ``submit_state_root(arrays, meta, balances, eff_bal, inact, just)
     -> Future[np.ndarray]`` (u32[8] root words)
+  * ``submit_committees(active_indices, seed) -> Future[np.ndarray]``
+    (an epoch's shuffled committee list, int32[n]:
+    ``active_indices[compute_shuffled_index(i, n, seed)]`` for every i,
+    ONE execution of a program compiled a lane bucket with the active
+    count a traced number — ops/shuffle)
   * ``submit_slot(SlotRequest) -> Future[SlotResult]`` (the whole-slot
     state-transition pipeline: verify → aggregate → column updates →
     incremental re-root against this service's resident slot world —
@@ -271,6 +276,31 @@ class VerifyService:
             (arrays, meta, balances, effective_balance, inactivity_scores, just),
             cost,
         )
+
+    def submit_committees(self, active_indices: np.ndarray, seed: bytes) -> Future:
+        """An epoch's committees as ONE list: resolves to int32[n]
+        ``shuffled`` with ``shuffled[i] ==
+        active_indices[compute_shuffled_index(i, n, seed)]`` for every i
+        under the mainnet preset's 90 rounds, exact and from the request's
+        own bytes alone; committee ``k`` of ``count`` is
+        ``shuffled[n * k // count : n * (k + 1) // count]``
+        (``compute_committee``). ``active_indices`` is a one-dimensional
+        integer array of n >= 1 registry indices below 2**31, ``seed`` the
+        caller's ``get_seed(state, epoch, DOMAIN_BEACON_ATTESTER)``
+        (ValueError otherwise). The list is made on the device for a lane
+        bucket ``precompile`` has compiled (``("shuffle", lanes)``), by the
+        host's numpy form otherwise. Admission accounts the indices' 4
+        bytes each."""
+        if (not isinstance(active_indices, np.ndarray) or active_indices.ndim != 1
+                or active_indices.dtype.kind not in "iu" or active_indices.size == 0):
+            raise ValueError("active indices: a one-dimensional integer array, not empty")
+        if int(active_indices.min()) < 0 or int(active_indices.max()) >= 1 << 31:
+            raise ValueError("active indices: registry indices below 2**31")
+        seed = bytes(seed)
+        if len(seed) != 32:
+            raise ValueError("seed: 32 bytes")
+        active = active_indices.astype(np.int32)
+        return self._submit("shuffle", (active, seed), int(active.nbytes))
 
     def submit_slot(self, req) -> Future:
         """One whole slot (ops/slot_pipeline.SlotRequest: attestations +
@@ -670,6 +700,18 @@ class VerifyService:
                 r.slot_phases = phases
                 results[id(r)] = result
 
+        shuffle_reqs = [r for r in reqs if r.kind == "shuffle"]
+        if shuffle_reqs:
+            from eth_consensus_specs_tpu.ops import shuffle
+
+            rounds = shuffle.mainnet_rounds()
+            if not device:
+                obs.count("serve.degraded_items", len(shuffle_reqs))
+            # the op takes the device only for a compiled lane bucket
+            route = shuffle.shuffled_indices if device else shuffle.shuffled_indices_host
+            for r in shuffle_reqs:
+                results[id(r)] = route(*r.payload, rounds)
+
         for r in reqs:
             if r.kind != "state_root":
                 continue
@@ -793,7 +835,9 @@ class VerifyService:
         a warmed bucket's sums go to the device (ops/bls_batch.py). A
         ``("das_msm", items, lanes)`` key and the ``("fr_fft", rows, 64)``
         key beside it do the same for a flush of data column sidecars
-        (ops/das_batch.py)."""
+        (ops/das_batch.py), and a ``("shuffle", lanes)`` key for the
+        committee requests whose active count that lane bucket holds
+        (ops/shuffle.py)."""
         return buckets.precompile(
             keys, path=path, chips=self.config.mesh_chips or None, key_table=self._keys
         )

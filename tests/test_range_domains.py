@@ -164,23 +164,25 @@ def test_merkle_inc_corners_vs_hashlib():
 
 
 def test_shuffle_corners_stay_bijective():
-    """Swap-or-not at every (decision-word, pivot) corner pair: whatever
-    the digest bits say, the output must remain a permutation — the
-    property the consensus shuffle's invertibility rests on."""
-    from eth_consensus_specs_tpu.ops.shuffle import _device_shuffle_kernel
+    """Swap-or-not at every (seed-word, pivot, count) corner: whatever the
+    digest bits say, the live part of the list must remain a rearrangement
+    of itself — the property the consensus shuffle's invertibility rests
+    on — and the lanes past the count must stay where they were."""
+    from eth_consensus_specs_tpu.ops.shuffle import shuffle_rounds_kernel
 
     v = _variant("shuffle")
-    words_dom, pivot_dom = v.domains
-    n = int(pivot_dom.hi) + 1  # declared: pivots in [0, n)
+    words_dom, pivot_dom, count_dom, _ = v.domains
+    lanes = v.args[3].shape[0]
     rounds = v.args[1].shape[0]
-    num_chunks = v.args[0].shape[0] // rounds
-    kern = _device_shuffle_kernel(n, rounds, num_chunks)
+    active = np.arange(lanes, dtype=np.int32)[::-1].copy()
     for wlab, w in _corners(words_dom):
         for plab, pv in _corners(pivot_dom):
-            blocks = np.full((rounds * num_chunks, 16), w, np.uint32)
-            pivots = np.full((rounds,), pv, np.int32)
-            idx = np.asarray(kern(jnp.asarray(blocks), jnp.asarray(pivots)))
-            assert sorted(idx.tolist()) == list(range(n)), (wlab, plab)
+            for clab, n in _corners(count_dom):
+                seed_words = np.full((8,), w, np.uint32)
+                pivots = np.full((rounds,), min(pv, n - 1), np.int32)
+                out = np.asarray(shuffle_rounds_kernel(seed_words, pivots, np.int32(n), active))
+                assert sorted(out[:n].tolist()) == sorted(active[:n].tolist()), (wlab, plab, clab)
+                assert (out[n:] == active[n:]).all(), (wlab, plab, clab)
 
 
 @pytest.mark.slow  # two full post-epoch tree compiles, ~90 s on CPU

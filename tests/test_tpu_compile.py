@@ -239,3 +239,27 @@ def test_the_data_column_programs_compile_at_a_blocks_buckets(one_chip, no_compi
     the opposite shape: 4,096 rows of 64 points where that cell has 8 of
     4,096, 256 items x 32 lanes where it has 2 x 32."""
     _check_row(chip_programs.compile_for(one_chip, _column_block_program(name)))
+
+
+def test_the_shuffle_program_compiles_at_the_2_20_lane_bucket(one_chip, no_compile_cache):
+    """`committees_2p20.shuffle`'s one program at the bucket the LIVE key
+    function gives a mainnet active set: the count a traced scalar, the
+    368,640 decision blocks made inside it and hashed by the unrolled
+    body, and 90 rounds that rotate the list and gather nothing."""
+    from eth_consensus_specs_tpu.analysis import kernels
+    from eth_consensus_specs_tpu.ops import shuffle
+    from eth_consensus_specs_tpu.serve import buckets
+
+    _, lanes = buckets.shuffle_key((1 << 20) - 4096 - 77)
+    assert lanes == 1 << 20
+    args = (kernels._sds((8,), "uint32"), kernels._sds((90,), "int32"),
+            kernels._sds((), "int32"), kernels._sds((lanes,), "int32"))
+    with chip_programs.as_accelerator():
+        jaxpr = shuffle.shuffle_rounds_kernel.trace(*args).jaxpr
+    names = [e.primitive.name for e in _eqns(jaxpr.jaxpr)]
+    assert names.count("while") == 1 and "optimization_barrier" in names
+    assert "gather" not in names and "scan" not in names
+    row = chip_programs.compile_for(
+        one_chip, chip_programs.Program("shuffle", lambda: (shuffle.shuffle_rounds_kernel, args)))
+    _check_row(row)
+    assert row["argument_bytes"] < 4 * lanes + 4096  # the list, the seed, the pivots, the count
