@@ -67,6 +67,10 @@ CATALOG: tuple[Metric, ...] = (
     _s("shuffle.pack", "leg: the rounds' pivots, the seed's words, the indices padded to the lane bucket"),
     _s("shuffle.call", "leg: host clock round the synced shuffle program (transfer in, the list ready)"),
     _s("shuffle.unpack", "leg: the shuffled list to the host, cut to the active count"),
+    _c("g1_msm.scalar_steps",
+       "sequential trips of G1 scalar loops (table steps, then windows), an execution"),
+    _c("g1_msm.field_muls",
+       "Montgomery multiplies a lane of G1 scalar loops, from the static shape"),
     _c("state_root.real_hashes", "hashes in post-epoch state roots"),
     _c("state_root.chain_steps", "sequential hash steps of state roots' list tails"),
     _c("state_root.roots", "post-epoch state roots computed"),

@@ -4,8 +4,8 @@ The hot BLS reductions (aggregate-pubkey sums, KZG commitment MSMs, the
 RLC batch-verification combine) are all sum_i k_i * P_i over G1. Here the
 whole MSM runs on device: branchless Jacobian point arithmetic (a = 0
 short-Weierstrass, infinity encoded as Z = 0, every case handled by
-`where` masks so there is no data-dependent control flow), a vmapped
-256-bit double-and-add per (scalar, point) lane, then a log2 pairwise
+`where` masks so there is no data-dependent control flow), a fixed-window
+scalar loop over every (scalar, point) lane at once, then a log2 pairwise
 tree reduction — the same shape as the merkle tree reduce, but over
 point adds (reference native analogue: arkworks `multiexp_unchecked`
 behind utils/bls.py:262-296).
@@ -14,13 +14,24 @@ Doubling is dbl-2009-l, addition add-2007-bl with a masked case
 analysis, both straight-line code over ops/lazy_limbs (15 x 26-bit
 limbs, every bound a Python integer checked at trace time): the field
 arithmetic has no loop of its own, so the only loops of a G1 program are
-the algorithm's (the 256-step bit loop, the strip scan of the committee
-sums, the levels of the tree). A scalar bit is 30 Montgomery multiplies
-(7 a doubling, 16 an addition, 7 more the doubling inside the addition
-for its equal-points lanes); additions and subtractions stay lazy in
-between, with a carry sweep only where a zero test, a doubled subtrahend
-or the loop boundary wants normalized limbs (five a bit) and conditional
-subtractions only where the accumulator crosses that boundary.
+the algorithm's (the scalar loop's table steps and windows, a window's
+doublings, the strip scan of the committee sums, the levels of the
+tree). A lane first makes its table 0, P, 2P, ... 15P by 14 complete
+additions, then walks its scalar in 64 windows of four bits, most
+significant first: four doublings and ONE complete addition of the table
+entry at the window's digit, chosen a lane by selects; table steps and
+windows are trips of one loop, so the program holds one addition body
+beside the tree's. A doubling is 7
+Montgomery multiplies and a complete addition 23 (16, and 7 more for the
+doubling its equal-points lanes take), so a 256-bit scalar is 256 x 7 +
+78 x 23 = 3,586 multiplies a lane where a bit-by-bit double-and-add is
+256 x 30 = 7,680. The addition stays COMPLETE: scalars run to 2^256,
+above the group order, so a window can meet its equal-points case (the
+scalar r + 30: the accumulator is 15 P when the table's 15 P is added)
+and its opposite-points case (the scalar r). Additions and subtractions
+stay lazy inside a formula, with a carry sweep only where a zero test, a
+doubled subtrahend or a loop boundary wants normalized limbs, and
+conditional subtractions only where a point crosses such a boundary.
 
 Conversion boundary: affine crypto/curve.Point <-> Montgomery limb arrays
 on host, 13 x 30-bit limbs (ops/field_limbs) into and out of every jitted
@@ -62,6 +73,28 @@ from .lazy_limbs import LF, lf
 from .limb_field import LimbField
 
 SCALAR_BITS = 256
+# bits a window of the scalar loop (`_scalar_loop`). The program alone on
+# one v5e, ms a call at 2 x 32 and at 256 x 32 lanes (PERF.md section 6,
+# PR 34; 157.3 and 492.5 a bit at a time): window 4 91.5 and 244.2; window
+# 5 87.4 and 241.5, for a table of 32 rows and 3,706 multiplies; the entry
+# taken by a gather along the table axis in place of the selects 82.2 and
+# 265.9, by one masked reduce over the table axis 91.9 and 242.0. With the
+# table built by a loop of its own (a second addition body): four copies
+# of the doubling in place of their loop 88.7 against 94.8 and 247.2
+# against 244.5, for 25 and 80 s more of compile. Window 5's 4.5 % and
+# 1.1 % cost a table twice the size (94 MB at 8,192 lanes) and a first
+# window padded to its width: not taken, and open (PERF.md section 7)
+WINDOW_BITS = 4
+WINDOWS = SCALAR_BITS // WINDOW_BITS
+TABLE = 1 << WINDOW_BITS
+# Montgomery multiplies of `_dbl`, and of `_add` with the doubling its
+# equal-points lanes take
+DBL_MULS = 7
+ADD_MULS = 16 + DBL_MULS
+# what one execution of the scalar loop is from its static shape: the
+# sequential trips (table steps, then windows) and the multiplies a lane
+SCALAR_STEPS = TABLE - 2 + WINDOWS
+SCALAR_FIELD_MULS = SCALAR_BITS * DBL_MULS + SCALAR_STEPS * ADD_MULS
 
 assert lz.R_INT == R_INT  # one Montgomery radix: the two limb forms hold the same integer
 
@@ -190,21 +223,70 @@ def _add(p, q):
     return _select_point(lz.is_zero(Z1), q, out)
 
 
-def _scalar_mul_lane(bits, X, Y, Z):
-    """Double-and-add over MSB-first `bits` (u64[256]) for one lane; runs
-    under vmap so every op broadcasts across lanes. Takes and returns
-    canonical limb arrays, and the accumulator crosses the loop boundary
-    as such."""
+def _table_entries(tables, digit):
+    """The point tables[digit] a lane, by selects over the TABLE entries:
+    a per-lane choice with no gather and no data-dependent control flow."""
+    entry = _wrap(*(c[0] for c in tables))
+    for d in range(1, TABLE):
+        entry = _select_point(digit == d, _wrap(*(c[d] for c in tables)), entry)
+    return entry
+
+
+def _scalar_loop(bits, X, Y, Z):
+    """Fixed-window scalar multiplication of every lane: MSB-first `bits`
+    u64[..., 256] times the points u64[..., 15] a coordinate, every op
+    broadcasting across the leading axes. ONE loop of SCALAR_STEPS trips
+    whose step is "k doublings, add an operand, store", so that the
+    program holds ONE doubling body and ONE complete addition body (a
+    second addition body was 5 MB of code and 25 s of every process's
+    set-up: PERF.md section 6, PR 34):
+
+    * TABLE - 2 table steps (k = 0, the operand the lane's point P): the
+      running point walks P, 2P (the addition's equal-points case), ...
+      15P, each stored as its table entry; entry 0 is infinity (Z = 0),
+      and a padded lane (Z = 0) gets TABLE infinities;
+    * WINDOWS window steps, most significant first (k = WINDOW_BITS trips
+      of the doubling body, the operand each lane's table entry at its
+      window's digit, made here from the window's bits); the first starts
+      from infinity, and a window's sum goes to a spare last table row
+      that nothing reads.
+
+    Takes canonical limb arrays and returns the products and the tables
+    (u64[TABLE + 1, ..., 15] a coordinate) as such; the running point
+    crosses every loop boundary canonical."""
     base = _wrap(X, Y, Z)
+    weights = jnp.asarray([1 << k for k in reversed(range(WINDOW_BITS))], jnp.uint64)
+    digits = (bits.reshape(*bits.shape[:-1], WINDOWS, WINDOW_BITS) * weights).sum(axis=-1)
 
-    def body(i, acc):
-        acc = _dbl(_wrap(*acc))
-        return _canon(_select_point(bits[i] != 0, _add(acc, base), acc))
+    def double(_, acc):
+        return _canon(_dbl(_wrap(*acc)))
 
-    inf = (jnp.zeros_like(X), jnp.zeros_like(Y), jnp.zeros_like(Z))
-    # i32 loop bounds: python-int bounds widen the bit counter to i64
-    # under the package-wide x64 flag (jaxlint x64-drift)
-    return lax.fori_loop(jnp.int32(0), jnp.int32(SCALAR_BITS), body, inf)
+    def step(t, carry):
+        acc, tables = carry
+        window = t - jnp.int32(TABLE - 2)  # negative while the table is built
+        building = window < 0
+        # the table's steps leave 15P behind: the windows start from infinity
+        acc = (acc[0], acc[1], jnp.where(window == 0, jnp.uint64(0), acc[2]))
+        # i32 loop bounds: python-int bounds widen the counter to i64
+        # under the package-wide x64 flag (jaxlint x64-drift)
+        doublings = jnp.where(building, jnp.int32(0), jnp.int32(WINDOW_BITS))
+        acc = lax.fori_loop(jnp.int32(0), doublings, double, acc)
+        digit = lax.dynamic_index_in_dim(digits, jnp.maximum(window, 0), axis=-1, keepdims=False)
+        operand = _select_point(building, base, _table_entries(tables, digit))
+        acc = _canon(_add(_wrap(*acc), operand))
+        row = jnp.where(building, t + 2, jnp.int32(TABLE))
+        tables = tuple(
+            lax.dynamic_update_index_in_dim(c, a, row, 0) for c, a in zip(tables, acc)
+        )
+        return acc, tables
+
+    def rows(c):  # infinity, P, and the rows the table steps fill
+        return jnp.concatenate(
+            [jnp.zeros_like(c)[None], c[None], jnp.zeros((TABLE - 1, *c.shape), c.dtype)]
+        )
+
+    start = (X, Y, Z), tuple(rows(c) for c in (X, Y, Z))
+    return lax.fori_loop(jnp.int32(0), jnp.int32(SCALAR_STEPS), step, start)
 
 
 def _tree_sum(mX, mY, mZ):
@@ -230,10 +312,12 @@ def _tree_sum(mX, mY, mZ):
 
 
 def _msm_lanes(bits, X, Y, Z):
-    """One item's MSM: vmapped double-and-add over its lanes + pairwise
-    tree reduce — the shared body of msm_kernel and the batched
-    per-item variant below."""
-    return _tree_sum(*jax.vmap(_scalar_mul_lane)(bits, X, Y, Z))
+    """The MSM of one item's lanes ([L, ...] arrays) or of each item of a
+    batch ([I, L, ...]): the windowed scalar loop over every lane at
+    once, then a pairwise tree reduce an item: the shared body of
+    msm_kernel, msm_many_kernel and their sharded forms."""
+    tree = _tree_sum if X.ndim == 2 else jax.vmap(_tree_sum)
+    return tree(*_scalar_loop(bits, X, Y, Z)[0])
 
 
 @jax.jit
@@ -246,7 +330,7 @@ def msm_kernel(bits, X, Y, Z):
 @jax.jit
 def sum_kernel(X, Y, Z):
     """Plain point sum over N (power-of-two) lanes — the unit-scalar MSM
-    without the 256-bit double-and-add (aggregate-pubkey fast path)."""
+    without the scalar loop (aggregate-pubkey fast path)."""
     return _from_lazy_point(_tree_sum(*_to_lazy_point(X, Y, Z)))
 
 
@@ -306,7 +390,7 @@ def msm_many_kernel(bits, X, Y, Z):
     needs TWO independent MSMs (the proof lincomb and the commitment-
     minus-y + proof-z lincomb) and this kernel runs both in ONE
     dispatch instead of two msm_kernel round-trips."""
-    return _from_lazy_point(jax.vmap(_msm_lanes)(bits, *_to_lazy_point(X, Y, Z)))
+    return _from_lazy_point(_msm_lanes(bits, *_to_lazy_point(X, Y, Z)))
 
 
 # == mesh-sharded kernels ==================================================
@@ -371,7 +455,7 @@ def _sharded_fn(mesh: Mesh, kind: str):
         )
     elif kind == "msm_many":
         # per-item MSMs with the LANE axis (axis 1) sharded: each shard
-        # double-and-adds + tree-sums its lane slice of every item, then
+        # runs the scalar loop + tree-sums its lane slice of every item, then
         # ONE gather combines the [I, 13] partials — the per-item sums
         # ride the same cross-shard Jacobian reduce as the single MSM,
         # so results are byte-identical at any shard count
@@ -379,7 +463,7 @@ def _sharded_fn(mesh: Mesh, kind: str):
 
         def local(bits, X, Y, Z):
             return _cross_shard_tree_sum(
-                jax.vmap(_msm_lanes)(bits, *_to_lazy_point(X, Y, Z)), BATCH_AXES
+                _msm_lanes(bits, *_to_lazy_point(X, Y, Z)), BATCH_AXES
             )
 
         fn = jax.jit(
@@ -508,9 +592,16 @@ def _pad_lanes(arrs, n: int, cap: int):
     ]
 
 
+def _count_scalar_loop() -> None:
+    """One execution of the scalar loop, from its static shape: the
+    sequential depth and the work show without a trace."""
+    obs.count("g1_msm.scalar_steps", SCALAR_STEPS)
+    obs.count("g1_msm.field_muls", SCALAR_FIELD_MULS)
+
+
 def msm_g1_device(points: list, scalars: list[int], mesh: Mesh | None = None) -> Point:
     """Device MSM entry: sum_i scalars[i] * points[i] over G1. With a
-    multi-device `mesh` the lanes shard over it (per-shard double-and-add
+    multi-device `mesh` the lanes shard over it (per-shard scalar loop
     + local tree sum, then the cross-shard Jacobian reduction) — the
     affine result is byte-identical to the single-device dispatch."""
     assert len(points) == len(scalars)
@@ -537,6 +628,7 @@ def msm_g1_device(points: list, scalars: list[int], mesh: Mesh | None = None) ->
         bits = _scalars_to_bits(scalars)
         bits, X, Y, Z = _pad_lanes([bits, X, Y, Z], len(points), cap)
         args = (jnp.asarray(bits), jnp.asarray(X), jnp.asarray(Y), jnp.asarray(Z))
+        _count_scalar_loop()
         if mesh is not None:
             obs.count("mesh.dispatches", 1)
             obs.count("mesh.sharded_items", len(points))
@@ -593,6 +685,7 @@ def msm_g1_many_device(
             )
             bits[item, lane] = _scalars_to_bits([s for scalars in scalar_lists for s in scalars])
         args = (jnp.asarray(bits), jnp.asarray(X), jnp.asarray(Y), jnp.asarray(Z))
+    _count_scalar_loop()
     # host clock round a synced device call: launch, the MSM program,
     # transfer out
     with waterfall.leg("g1_msm.call"):

@@ -1,7 +1,8 @@
 """G1 point arithmetic on lazy limbs (ops/g1_msm over ops/lazy_limbs):
 the formulas against crypto/curve.Point on their corner lanes, the
 13 x 30 <-> 15 x 26 regrouping at a program's boundary, the batched MSM
-program against the host MSM, and the shape of its bit loop."""
+program against the host MSM on the corners of its four-bit windows, a
+lane's table, and the shape and the counters of its scalar loop."""
 
 import random
 
@@ -139,8 +140,108 @@ def test_msm_many_kernel_equals_the_host_msm(lengths):
     assert got == [msm_g1(p, k) for p, k in zip(points, scalars)]
 
 
+# scalars that walk the window's corners: the ends of a digit, a carry
+# into the next window, every digit full, one live digit, and the lanes
+# where a window's addition meets its special cases: r (the last window
+# adds the opposite of the accumulator), r + 30 (it adds 15 P to 15 P)
+WINDOW_CORNERS = [
+    0, 1, 15, 16, 17, (1 << 256) - 1, 0xF << 252, 0xF,
+    int("1" * 64, 16), R - 1, R, R + 30, R + 15, 2 * R, 2 * R + 30,
+]
+
+
+@pytest.mark.parametrize("scalar", WINDOW_CORNERS, ids=hex)
+def test_msm_kernel_on_the_windows_corner_scalars(scalar):
+    """One compiled shape for every case: lane 0 the corner scalar, an
+    infinity point under a full scalar beside it, two live lanes."""
+    points = [G.mul(7), INF, G.mul(R - 5), G.mul(12345)]
+    scalars = [scalar, (1 << 256) - 1, scalar ^ 0xF0F, 3]
+    assert gm.msm_g1_device(points, scalars) == msm_g1(points, scalars)
+
+
+def test_all_corner_scalars_in_one_batch_with_infinity_lanes():
+    """Every corner scalar as a lane of ONE execution (the 2 x 32 shape
+    above): on live points, on live points with infinity points among
+    them, on infinity points alone, on one point."""
+    rng = random.Random(34)
+    n = len(WINDOW_CORNERS)
+    live = [G.mul(rng.randrange(1, R)) for _ in range(n)]
+    holes = [INF if i % 4 == 2 else p for i, p in enumerate(live)]
+    points = [live + holes, [INF] * n + [G] * n]
+    scalars = [WINDOW_CORNERS * 2] * 2
+    got = gm.msm_g1_many_device(points, scalars)
+    assert got == [msm_g1(p, k) for p, k in zip(points, scalars)]
+    assert gm.msm_g1_many_device([[INF] * n, live], [WINDOW_CORNERS] * 2, pad_shape=(2, 32)) == [
+        INF,
+        msm_g1(live, WINDOW_CORNERS),
+    ]
+
+
+def test_a_lanes_table_is_the_multiples_of_its_point():
+    p = G.mul(5)
+    lanes = [_jacobian(p), _jacobian(p, z=7, lift=(1, 1, 1)), _jacobian(INF), (0, 0, 0)]
+    bits = jnp.asarray(gm._scalars_to_bits([0, 1, R + 30, 16]))
+    products, tables = jax.jit(gm._scalar_loop)(bits, *_lanes(lanes))
+    assert _points(*products) == [INF, p, INF, INF]
+    assert all(t.shape == (gm.TABLE + 1, len(lanes), lz.N_LIMBS) for t in tables)
+    assert _canonical([t.reshape(-1, lz.N_LIMBS) for t in tables])
+    multiples = [p.mul(d) if d else INF for d in range(gm.TABLE)]
+    for lane, want in enumerate([multiples, multiples, [INF] * gm.TABLE, [INF] * gm.TABLE]):
+        assert _points(*(t[: gm.TABLE, lane] for t in tables)) == want
+
+
+def test_a_table_entry_is_chosen_a_lane():
+    rng = np.random.default_rng(34)
+    tables = tuple(
+        jnp.asarray(rng.integers(0, 1 << 26, (gm.TABLE, 3, 5, lz.N_LIMBS), dtype=np.uint64))
+        for _ in range(3)
+    )
+    digit = rng.integers(0, gm.TABLE, (3, 5))
+    digit[0, :2] = (0, gm.TABLE - 1)
+    got = gm._table_entries(tables, jnp.asarray(digit, jnp.uint64))
+    for g, t in zip(got, tables):
+        want = np.take_along_axis(np.asarray(t), digit[None, ..., None], axis=0)[0]
+        assert np.array_equal(np.asarray(g.v), want)
+
+
+def test_the_formulas_multiplies_are_the_counted_ones(monkeypatch):
+    calls = []
+    mul = lz.mul
+    monkeypatch.setattr(lz, "mul", lambda x, y: calls.append(1) or mul(x, y))
+    point = gm._wrap(*(jnp.zeros((lz.N_LIMBS,), jnp.uint64),) * 3)
+    gm._dbl(point)
+    assert len(calls) == gm.DBL_MULS == 7
+    del calls[:]
+    gm._add(point, point)
+    assert len(calls) == gm.ADD_MULS == 23
+    assert (gm.WINDOW_BITS, gm.WINDOWS, gm.TABLE) == (4, 64, 16)
+    assert (gm.SCALAR_STEPS, gm.SCALAR_FIELD_MULS) == (78, 3586)
+
+
+def test_an_execution_counts_its_steps_and_its_multiplies():
+    """`g1_msm.scalar_steps` and `g1_msm.field_muls` are one execution's
+    sequential trips and multiplies a lane, whatever its items and lanes;
+    a unit-scalar sum runs no scalar loop and counts nothing."""
+    from eth_consensus_specs_tpu import obs
+
+    def counters():
+        c = obs.snapshot()["counters"]
+        return c.get("g1_msm.scalar_steps", 0), c.get("g1_msm.field_muls", 0)
+
+    points = [G.mul(7), INF, G.mul(R - 5), G.mul(12345)]
+    steps0, muls0 = counters()
+    gm.msm_g1_many_device([points, points[:2]], [[2, 3, 5, 7], [11, 13]], pad_shape=(2, 32))
+    steps1, muls1 = counters()
+    assert (steps1 - steps0, muls1 - muls0) == (78, 3586)
+    gm.msm_g1_device(points, [2, 3, 5, 7])
+    steps2, muls2 = counters()
+    assert (steps2 - steps1, muls2 - muls1) == (78, 3586)
+    gm.sum_g1_device(points)
+    assert counters() == (steps2, muls2)
+
+
 def _loops(jaxpr, depth=0):
-    """(primitive name, depth among loops, body jaxprs) of every loop."""
+    """(primitive name, depth among loops, equation) of every loop."""
     found = []
     for eqn in jaxpr.eqns:
         subs = [
@@ -157,13 +258,30 @@ def _loops(jaxpr, depth=0):
     return found
 
 
-def test_the_bit_loop_has_no_loop_inside():
-    """A scalar bit is straight-line code: the field arithmetic under
-    the doubling and the addition brings no loop of its own."""
+def _trips(eqn):
+    """Trip count of a `scan`, or of a counted `while` (fori_loop's
+    bounds lead its carry); None where the upper bound is computed."""
+    if eqn.primitive.name == "scan":
+        return eqn.params["length"]
+    lo, hi = eqn.invars[eqn.params["cond_nconsts"] + eqn.params["body_nconsts"] :][:2]
+    return int(hi.val) - int(lo.val) if hasattr(hi, "val") else None
+
+
+def test_the_scalar_loop_holds_no_loop_but_its_doublings():
+    """The program's loops are the algorithm's: ONE scalar loop (the
+    table's steps, then the windows) with the doublings' loop inside,
+    and the tree's levels. A step holds no other loop, and no loop
+    anywhere comes from the field arithmetic under the doubling and the
+    addition."""
     sds = jax.ShapeDtypeStruct
     args = (sds((2, 4, gm.SCALAR_BITS), jnp.uint64),) + (sds((2, 4, 13), jnp.uint64),) * 3
     loops = _loops(jax.make_jaxpr(gm.msm_many_kernel)(*args).jaxpr)
-    bit_loops = [eqn for name, depth, eqn in loops if name == "while" and depth == 0]
-    assert len(bit_loops) == 1
-    assert _loops(bit_loops[0].params["body_jaxpr"].jaxpr) == []
-    assert all(depth == 0 for _, depth, _ in loops)
+    assert sorted((name, depth, _trips(eqn)) for name, depth, eqn in loops) == [
+        ("scan", 0, 2),  # the tree over 4 lanes
+        ("while", 0, gm.SCALAR_STEPS),
+        ("while", 1, None),  # a step's doublings: none for the table, WINDOW_BITS a window
+    ]
+    (steps,) = [eqn for name, depth, eqn in loops if name == "while" and depth == 0]
+    (doublings,) = _loops(steps.params["body_jaxpr"].jaxpr)
+    assert _loops(doublings[2].params["body_jaxpr"].jaxpr) == []
+    assert gm.TABLE - 2 + gm.WINDOWS == gm.SCALAR_STEPS == 78
