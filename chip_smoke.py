@@ -94,56 +94,44 @@ def phase_device(chips: int) -> dict:
     check(dev["count"] == chips, f"asked for {chips} chip(s), JAX found {dev}")
 
     from eth_consensus_specs_tpu import native
+    from eth_consensus_specs_tpu.obs import xprof
     from eth_consensus_specs_tpu.utils.cache import enable_persistent_cache
 
     # a C core that cannot be built would leave pure Python, some 60x
     # slower: at 512-key committees that is an hour, not a slower run
     cores = {"sha": native.get_lib() is not None, "bls": native.get_bls_lib() is not None}
     check(all(cores.values()), f"C cores did not build/load: {cores}")
-    CompileLog.install()
+    xprof.install_compile_listener()
     emit("device", t0, **dev, jax=jax.__version__, cache_dir=enable_persistent_cache(),
          c_cores=cores)
     return dev
 
 
-class CompileLog:
-    """What XLA really compiled in this process, from JAX's own monitoring
-    events: the serve layer counts first sightings of a shape key, which a
-    warm persistent cache turns into reads. JAX's backend-compile event
-    spans the cache lookup, so a hit shows there with the seconds it took
-    to read and load the executable; the reads are reported beside it."""
+def compile_summary() -> dict:
+    """What XLA really compiled in this process, from the program's own
+    listener (obs/xprof): the serve layer counts first sightings of a shape
+    key, which a warm persistent cache turns into reads. JAX's
+    backend-compile event spans the cache lookup, so a hit shows there with
+    the seconds it took to read and load the executable; the reads are
+    reported beside it. Sums and counts are the histograms' exact ones
+    (`cache_hits` counts the reads); the counts over a second come from the
+    ring's `xla.compile` events, which holds the last 10,000 events, many
+    times what this script raises. With `ETH_SPECS_OBS=0` the program
+    records nothing and every number here reads 0."""
+    from eth_consensus_specs_tpu import obs
 
-    backend_s: list[float] = []
-    cache_read_s: list[float] = []
-    cache_hits = 0
-
-    @classmethod
-    def install(cls) -> None:
-        import jax.monitoring as mon
-
-        def on_duration(name, seconds, **_):
-            if name.endswith("backend_compile_duration"):
-                cls.backend_s.append(float(seconds))
-            elif name.endswith("cache_retrieval_time_sec"):
-                cls.cache_read_s.append(float(seconds))
-
-        def on_event(name, **_):
-            if name.endswith("compilation_cache/cache_hits"):
-                cls.cache_hits += 1
-
-        mon.register_event_duration_secs_listener(on_duration)
-        mon.register_event_listener(on_event)
-
-    @classmethod
-    def summary(cls) -> dict:
-        return {
-            "xla_compiles": len(cls.backend_s),
-            "xla_compile_s": round(sum(cls.backend_s), 1),
-            "xla_compiles_over_1s": sum(1 for s in cls.backend_s if s > 1.0),
-            "cache_hits": cls.cache_hits,
-            "cache_read_s": round(sum(cls.cache_read_s), 1),
-            "cache_reads_over_1s": sum(1 for s in cls.cache_read_s if s > 1.0),
-        }
+    hists = obs.snapshot()["histograms"]
+    compiles = [h for name, h in hists.items() if name.startswith("xla.compile_ms.")]
+    reads = [h for name, h in hists.items() if name.startswith("xla.cache_read_ms.")]
+    events = [e for e in obs.get_registry().events if e["kind"] == "xla.compile"]
+    return {
+        "xla_compiles": sum(h["count"] for h in compiles),
+        "xla_compile_s": round(sum(h["sum"] for h in compiles) / 1e3, 1),
+        "xla_compiles_over_1s": sum(1 for e in events if e["ms"] > 1e3),
+        "cache_hits": sum(h["count"] for h in reads),
+        "cache_read_s": round(sum(h["sum"] for h in reads) / 1e3, 1),
+        "cache_reads_over_1s": sum(1 for e in events if e["cache_read_ms"] > 1e3),
+    }
 
 
 # ------------------------------------------------------------------- boot --
@@ -420,7 +408,7 @@ def phase_no_fallback(svc, sizes: Sizes, counters_at_start: dict) -> None:
     emit("no_fallback", t0, degraded=0, forest_rebuilds=0, resident_on=platform,
          serve_compiles=int(counters.get("serve.compiles", 0)
                             - counters_at_start.get("serve.compiles", 0)),
-         families=sorted(families), **CompileLog.summary())
+         families=sorted(families), **compile_summary())
 
 
 # ------------------------------------------------------------- four chips --

@@ -908,8 +908,8 @@ def rule_obs_discipline(mi: ModuleInfo, catalog) -> list[Finding]:
         attr = fn.attr if isinstance(fn, ast.Attribute) else (
             fn.id if isinstance(fn, ast.Name) else None
         )
-        if attr in _DERIVED_EMITTERS and mi.modname != "serve.buckets":
-            # serve.buckets itself is the helper's home: its internal
+        if attr in _DERIVED_EMITTERS and (attr == "leg" or mi.modname != "serve.buckets"):
+            # serve.buckets itself is the compile helpers' home: its internal
             # obs.observe(...) literals are scanned by the branch below
             names = _literal_names(node.args[0]) if node.args else []
             for (kind, template), op in itertools.product(_DERIVED_EMITTERS[attr], names):

@@ -79,60 +79,66 @@ def _ensure_shared(
     for consensus-critical code. Links to a per-process temp name, then
     atomically renames: concurrent first-use compilations (pytest-xdist,
     parallel imports) must never let a reader dlopen a partial object."""
-    # The stamp encodes source content AND the build variant AND the CPU
-    # capability the variant relies on: a checkout (or baked image) moved
-    # to a CPU without BMI2/ADX must MISS the stamp, re-enter the flag
-    # ladder, and let the crash-isolated probe reject the ISA build —
-    # never dlopen a mulx/adcx object into the importing process blind.
-    want = f"{_src_digest(*srcs)}:{opt}:{_cpu_isa_token()}"
-    stamp = out + ".sha256"
-    try:
-        with open(stamp) as f:
-            if f.read().strip() == want and os.path.exists(out):
-                return True
-    except OSError:
-        pass
-    cc = os.environ.get("CC") or sysconfig.get_config_var("CC") or "cc"
-    tmp = f"{out}.{os.getpid()}.tmp"
-    built = False
-    candidates = [opt.split(), [opt.split()[0]]]
-    if candidates[1] == candidates[0]:
-        candidates.pop()  # single-flag opt: no distinct fallback to try
-    for flags in candidates:
-        # first choice may carry ISA-extension flags (BMI2/ADX measurably
-        # speed the Montgomery carry chains); retry with the bare -O level
-        # for compilers that reject them or CPUs that trap on the opcodes
-        # (the probe below catches the latter in a crash-isolated child)
-        cmd = cc.split() + flags + ["-fPIC", "-shared", "-o", tmp, srcs[0]]
+    # here, not at the top: obs pulls in the package, whose import may be
+    # what asked for this core
+    from eth_consensus_specs_tpu import obs
+
+    # a fresh object costs the digest, a stale one `cc` for seconds
+    with obs.span("native.load", core=os.path.basename(out)):
+        # The stamp encodes source content AND the build variant AND the CPU
+        # capability the variant relies on: a checkout (or baked image) moved
+        # to a CPU without BMI2/ADX must MISS the stamp, re-enter the flag
+        # ladder, and let the crash-isolated probe reject the ISA build —
+        # never dlopen a mulx/adcx object into the importing process blind.
+        want = f"{_src_digest(*srcs)}:{opt}:{_cpu_isa_token()}"
+        stamp = out + ".sha256"
         try:
-            subprocess.run(cmd, check=True, capture_output=True, timeout=timeout)
-        except (OSError, subprocess.SubprocessError):
+            with open(stamp) as f:
+                if f.read().strip() == want and os.path.exists(out):
+                    return True
+        except OSError:
+            pass
+        cc = os.environ.get("CC") or sysconfig.get_config_var("CC") or "cc"
+        tmp = f"{out}.{os.getpid()}.tmp"
+        built = False
+        candidates = [opt.split(), [opt.split()[0]]]
+        if candidates[1] == candidates[0]:
+            candidates.pop()  # single-flag opt: no distinct fallback to try
+        for flags in candidates:
+            # first choice may carry ISA-extension flags (BMI2/ADX measurably
+            # speed the Montgomery carry chains); retry with the bare -O level
+            # for compilers that reject them or CPUs that trap on the opcodes
+            # (the probe below catches the latter in a crash-isolated child)
+            cmd = cc.split() + flags + ["-fPIC", "-shared", "-o", tmp, srcs[0]]
             try:
-                os.unlink(tmp)
-            except OSError:
-                pass
-            continue
-        if probe_symbol is not None and not _probe_ok(tmp, probe_symbol):
-            try:
-                os.unlink(tmp)
-            except OSError:
-                pass
-            continue
-        os.replace(tmp, out)
-        built = True
-        break
-    if not built:
-        return False
-    # Stamp failure must not discard a successfully installed library —
-    # worst case the next process recompiles once more.
-    try:
-        stamp_tmp = f"{stamp}.{os.getpid()}.tmp"
-        with open(stamp_tmp, "w") as f:
-            f.write(want)
-        os.replace(stamp_tmp, stamp)
-    except OSError:
-        pass
-    return True
+                subprocess.run(cmd, check=True, capture_output=True, timeout=timeout)
+            except (OSError, subprocess.SubprocessError):
+                try:
+                    os.unlink(tmp)
+                except OSError:
+                    pass
+                continue
+            if probe_symbol is not None and not _probe_ok(tmp, probe_symbol):
+                try:
+                    os.unlink(tmp)
+                except OSError:
+                    pass
+                continue
+            os.replace(tmp, out)
+            built = True
+            break
+        if not built:
+            return False
+        # Stamp failure must not discard a successfully installed library —
+        # worst case the next process recompiles once more.
+        try:
+            stamp_tmp = f"{stamp}.{os.getpid()}.tmp"
+            with open(stamp_tmp, "w") as f:
+                f.write(want)
+            os.replace(stamp_tmp, stamp)
+        except OSError:
+            pass
+        return True
 
 
 def _compile() -> bool:

@@ -220,6 +220,21 @@ CATALOG: tuple[Metric, ...] = (
     _s("serve.dispatch", "one batched device dispatch"),
     _s("serve.batch_wait", "the batch thread waiting for a request or its deadline"),
     _s("serve.prep", "host prep of one flush on the batch thread"),
+    # set-up: what a process pays before its first request, beside XLA's
+    # phases (xla.*_ms below). The two histograms are the walls that no
+    # phase of XLA's holds, for the benchmark's setup_keys_s
+    _h("serve.setup_ms.register_pubkeys",
+       "register_pubkeys wall ms: the registry decoded, KeyValidated, indexed"),
+    _h("serve.setup_ms.key_table.to_device",
+       "the registry's Montgomery limbs made and placed on the device, ms"),
+    _s("serve.register_pubkeys", "the registry's public keys handed over (keys=n)"),
+    _s("key_table.validate", "leg: KeyValidate of every key of the registry"),
+    _s("key_table.to_device",
+       "leg: limb split and device_put of the key table at the first device_limbs, waited on"),
+    _s("precompile.*",
+       "leg: one key of precompile(), per op; an op's own leg further in keeps its compiles"),
+    _s("native.load",
+       "a C core's shared object found fresh by its digest, or built with cc (core=)"),
     # ---------------------------------------------------------------- hbm --
     _g("hbm.resident_bytes.*", "ledger-registered device bytes per owner"),
     _g("hbm.resident_bytes_total", "ledger-registered device bytes, all owners"),
@@ -301,11 +316,20 @@ CATALOG: tuple[Metric, ...] = (
     _g("xprof.*.*", "per-kernel XLA cost/memory attribution (flops, bytes_accessed, peak_bytes, ...)"),
     _h("xprof.compile_ms", "AOT compile wall ms"),
     _h("xprof.compile_ms.*", "AOT compile wall ms per kernel"),
-    # each sample also emits an `xla.compile` event (fun_name, leg, ms,
-    # cache_hit) into the ring and the JSONL
+    # the four phases of a compile as jax.monitoring reports them, by the
+    # waterfall leg open on the compiling thread (none outside one); each
+    # compile_ms sample also emits an `xla.compile` event (fun_name, leg, ms,
+    # cache_hit, trace_ms, lower_ms, cache_read_ms) into the ring and the JSONL
+    _h("xla.trace_ms.*",
+       "jaxpr traces, ms: the outermost trace on a thread alone (a nested jit's "
+       "lies inside its caller's)"),
+    _h("xla.lower_ms.*", "jaxpr to MLIR module lowerings, ms"),
+    _h("xla.cache_read_ms.*",
+       "persistent-cache hits, ms: read, deserialise and load of the executable "
+       "(inside the hit's xla.compile_ms sample)"),
     _h("xla.compile_ms.*",
-       "XLA backend-compile events (persistent-cache hits among them), ms, by "
-       "the waterfall leg open on the compiling thread (none outside one)"),
+       "XLA backend-compile events (persistent-cache hits among them), ms; "
+       "compiled anew is this less xla.cache_read_ms"),
     # ------------------------------------------------------------ flight --
     _c("flight.dumps", "postmortem bundles written"),
     # ---------------------------------------------------------- lockwatch --

@@ -117,6 +117,7 @@ class _NullSpan:
     lifetime and race across threads."""
 
     __slots__ = ("result",)
+    seconds = 0.0  # as a live span's after its exit: a caller may read it either way
 
     def __init__(self):
         self.result = None

@@ -40,11 +40,19 @@ the floor; the TPU unrolled form sits near 1×). So:
 above times compiles this module asks for. What the process compiles on
 its own (a first dispatch, an eager op traced anew in every flush) JAX
 reports through ``jax.monitoring``, with the function's name: the
-listener files each backend-compile event's milliseconds under
-``xla.compile_ms.<leg>``, the innermost ``waterfall.leg`` open on the
-compiling thread (``none`` outside one), and emits an ``xla.compile``
-event (``fun_name``, ``leg``, ``ms``, ``cache_hit``). JAX's event spans
-the persistent cache's lookup, so a hit counts as an event and says so.
+listener files the milliseconds of each of a compile's four phases under
+the innermost ``waterfall.leg`` open on the compiling thread (``none``
+outside one): ``xla.trace_ms.<leg>`` (the OUTERMOST trace alone: JAX
+times every nested ``jit`` inside its caller's trace),
+``xla.lower_ms.<leg>``, ``xla.cache_read_ms.<leg>`` (a persistent-cache
+hit: read, deserialise and load of the executable) and
+``xla.compile_ms.<leg>``, every backend-compile event. JAX's
+backend-compile event spans the cache's lookup, so a hit counts as one
+and what XLA compiled anew is ``xla.compile_ms`` less
+``xla.cache_read_ms``. Each backend compile also emits an ``xla.compile``
+event (``fun_name``, ``leg``, ``ms``, ``cache_hit``, and ``trace_ms``,
+``lower_ms``, ``cache_read_ms`` of the same function). A warm process
+pays trace and lowering in full and the read in place of the compile.
 ``serve.compiles`` (serve/buckets.py) counts the FIRST SIGHTING of a
 shape key, what the program believes it compiled; these count XLA's own
 events.
@@ -70,9 +78,15 @@ from .registry import get_registry, obs_enabled
 _SEEN_LOCK = threading.Lock()
 _SEEN: set[tuple] = set()
 _LISTENING = False
-# JAX reports a persistent-cache hit as an event of its own, on the
-# compiling thread, before the backend-compile event that spans it
+# JAX reports each phase of a compile on the compiling thread, in order:
+# trace, lowering, and the backend compile, which on a persistent-cache hit
+# spans a `cache_hits` event and the read's own duration
 _COMPILING = threading.local()
+_TRACE = "jaxpr_trace_duration"
+_LOWER = "jaxpr_to_mlir_module_duration"
+_CACHE_READ = "cache_retrieval_time_sec"
+_COMPILE = "backend_compile_duration"
+_PHASES = (_TRACE, _LOWER, _CACHE_READ, _COMPILE)
 
 _DEFAULT_TOL = 0.25
 
@@ -111,22 +125,85 @@ def reset_for_tests() -> None:
 # ------------------------------------------------------- compile listener --
 
 
+class _Compiling:
+    """One thread's compile in flight. JAX times every nested ``jit`` and
+    every eager primitive traced inside a caller, each inside its caller's
+    duration: `depth` counts the phases open on the thread, and a trace that
+    closes inside another phase is left to the duration that spans it. (A
+    lowering or a compile is filed wherever it closes, as every backend
+    compile always was; one inside a trace, an eager op on concrete values
+    run while its caller is traced, lies in that trace's milliseconds too.)
+    `trace` and `lower` are (fun_name, ms) of the last phase of each kind,
+    `cache_read_ms` and `cache_hit` the persistent cache's, all for the next
+    compile's event."""
+
+    __slots__ = ("depth", "trace", "lower", "cache_read_ms", "cache_hit")
+
+    def __init__(self):
+        self.depth = 0
+        self.forget()
+
+    def forget(self) -> None:
+        self.trace = self.lower = ("", 0.0)
+        self.cache_read_ms = 0.0
+        self.cache_hit = False
+
+
+def _compiling() -> _Compiling:
+    st = getattr(_COMPILING, "state", None)
+    if st is None:
+        st = _COMPILING.state = _Compiling()
+    return st
+
+
+def _phase_ms(phase: tuple, fun_name: str) -> float:
+    """The milliseconds of a pending phase if it was `fun_name`'s: JAX names
+    the trace ``f`` and the module ``jit(f)``."""
+    name, ms = phase
+    return round(ms, 3) if name == fun_name or fun_name.endswith(f"({name})") else 0.0
+
+
+def _on_scalar(name: str, _value, **_) -> None:
+    # JAX records a phase's start as a scalar at its entry, the duration
+    # at its exit (the cache's read has no entry: it is no phase of JAX's)
+    if name.rsplit("/", 1)[-1] in _PHASES:
+        _compiling().depth += 1
+
+
 def _on_event(name: str, **_) -> None:
     if name.endswith("compilation_cache/cache_hits"):
-        _COMPILING.cache_hit = True
+        _compiling().cache_hit = True
 
 
 def _on_duration(name: str, seconds: float, fun_name: str = "", **_) -> None:
-    if not name.endswith("backend_compile_duration"):
+    phase = name.rsplit("/", 1)[-1]
+    if phase not in _PHASES:
         return
-    cache_hit = getattr(_COMPILING, "cache_hit", False)
-    _COMPILING.cache_hit = False
+    st = _compiling()
+    fun_name, ms = str(fun_name), float(seconds) * 1e3
+    if phase != _CACHE_READ:
+        st.depth = max(st.depth - 1, 0)
+        if st.depth and phase == _TRACE:
+            return
     leg = waterfall.current_leg() or "none"
-    ms = float(seconds) * 1e3
     reg = get_registry()
-    reg.observe(f"xla.compile_ms.{leg}", ms)
-    reg.emit({"kind": "xla.compile", "fun_name": str(fun_name), "leg": leg,
-              "ms": round(ms, 3), "cache_hit": cache_hit})
+    if phase == _TRACE:
+        st.trace = (fun_name, ms)
+        reg.observe(f"xla.trace_ms.{leg}", ms)
+    elif phase == _LOWER:
+        st.lower = (fun_name, ms)
+        reg.observe(f"xla.lower_ms.{leg}", ms)
+    elif phase == _CACHE_READ:
+        st.cache_read_ms = ms  # inside the backend-compile event that follows
+        reg.observe(f"xla.cache_read_ms.{leg}", ms)
+    else:
+        reg.observe(f"xla.compile_ms.{leg}", ms)
+        reg.emit({"kind": "xla.compile", "fun_name": fun_name, "leg": leg,
+                  "ms": round(ms, 3), "cache_hit": st.cache_hit,
+                  "trace_ms": _phase_ms(st.trace, fun_name),
+                  "lower_ms": _phase_ms(st.lower, fun_name),
+                  "cache_read_ms": round(st.cache_read_ms, 3)})
+        st.forget()
 
 
 def install_compile_listener() -> None:
@@ -139,6 +216,7 @@ def install_compile_listener() -> None:
         _LISTENING = True
     import jax.monitoring as mon
 
+    mon.register_scalar_listener(_on_scalar)
     mon.register_event_listener(_on_event)
     mon.register_event_duration_secs_listener(_on_duration)
 

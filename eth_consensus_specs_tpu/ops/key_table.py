@@ -20,10 +20,12 @@ from __future__ import annotations
 
 import numpy as np
 
+from eth_consensus_specs_tpu import obs
 from eth_consensus_specs_tpu.crypto import native_bridge as nb
 from eth_consensus_specs_tpu.crypto.curve import B1, Point, g1_from_bytes
 from eth_consensus_specs_tpu.crypto.fields import Fq
 from eth_consensus_specs_tpu.crypto.fields import P as P_INT
+from eth_consensus_specs_tpu.obs import waterfall
 
 from .field_limbs import LIMB_BITS, MASK, N_LIMBS, R_INT
 
@@ -88,7 +90,8 @@ class KeyTable:
             raise ValueError("a public key is 48 bytes")
         blob = b"".join(pubkeys)
         self.compressed = np.frombuffer(blob, np.uint8).reshape(len(pubkeys), 48)
-        self.affine = _validated_affine(blob)
+        with waterfall.leg("key_table.validate", keys=len(pubkeys)):
+            self.affine = _validated_affine(blob)
         self.index_of = {blob[at : at + 48]: at // 48 for at in range(0, len(blob), 48)}
         self._limbs: dict = {}  # by mesh (None: the default device)
 
@@ -117,14 +120,16 @@ class KeyTable:
 
     def device_limbs(self, mesh=None):
         """(X, Y) u64[N, 13] on the device, or replicated over `mesh`;
-        placed at the first call."""
+        placed at the first call, which waits until they are there."""
         if mesh not in self._limbs:
             import jax
             from jax.sharding import NamedSharding, PartitionSpec
 
             where = None if mesh is None else NamedSharding(mesh, PartitionSpec())
-            self._limbs[mesh] = tuple(
-                jax.device_put(_mont_limbs(self.affine[:, part]), where)
-                for part in (slice(0, 48), slice(48, 96))
-            )
+            with waterfall.leg("key_table.to_device", keys=len(self)) as sp:
+                sp.result = self._limbs[mesh] = tuple(
+                    jax.device_put(_mont_limbs(self.affine[:, part]), where)
+                    for part in (slice(0, 48), slice(48, 96))
+                )
+            obs.observe("serve.setup_ms.key_table.to_device", sp.seconds * 1e3)
         return self._limbs[mesh]

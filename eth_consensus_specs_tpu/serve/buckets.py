@@ -31,6 +31,7 @@ steady-state buckets.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import threading
@@ -38,6 +39,7 @@ import time
 
 from eth_consensus_specs_tpu import obs
 from eth_consensus_specs_tpu.analysis import lockwatch
+from eth_consensus_specs_tpu.obs import waterfall
 
 # Above this many leaf chunks PER DISPATCH the device tree kernel beats
 # per-level hashlib (measured crossover, see ops/merkle.py's module doc
@@ -814,6 +816,16 @@ def _key_mesh(dims: tuple, chips: int | None = None):
     return tuple(int(d) for d in dims[:-1]), mesh, True
 
 
+@contextlib.contextmanager
+def _warming(op: str, *dims):
+    """One key of :func:`precompile`: its first dispatch, inside the leg
+    ``precompile.<op>``. The leg names what XLA traced, lowered, read or
+    compiled for the key (``xla.*_ms.precompile.<op>``) where the op's own
+    code opens no leg further in (``bls_keysum``)."""
+    with waterfall.leg(f"precompile.{op}"), first_dispatch(op, *dims):
+        yield
+
+
 def precompile(
     keys: list[tuple] | None = None, path: str | None = None, chips: int | None = None,
     key_table=None,
@@ -834,7 +846,7 @@ def precompile(
     ``das_msm`` key, and the ``fr_fft`` key at 64 points beside it, are
     what sends a flush of data column sidecars to the device; a
     ``shuffle`` key does the same for committee requests of its lane
-    bucket."""
+    bucket. A key is warmed inside the leg ``precompile.<op>``."""
     import numpy as np
 
     warmed = 0
@@ -857,7 +869,7 @@ def precompile(
                 zero = np.zeros((1, 8), np.uint32)
                 # warmup compiles are first dispatches like any other:
                 # their wall time lands in serve.compile_ms too
-                with first_dispatch(op, *dims):
+                with _warming(op, *dims):
                     merkleize_many_device([zero], depth, pad_batch=batch, mesh=mesh)
             elif op == "bls_msm" and len(int_dims) in (1, 2):
                 from eth_consensus_specs_tpu.crypto.curve import g1_generator
@@ -873,7 +885,7 @@ def precompile(
                 # throwaway point at exactly the padded shape: the sum is
                 # discarded, only the kernel compile matters
                 items, lanes = (1, int_dims[0]) if len(int_dims) == 1 else int_dims
-                with first_dispatch(op, *dims):
+                with _warming(op, *dims):
                     sum_g1_many_device(
                         [[g1_generator()]], mesh=mesh, pad_shape=(items, lanes)
                     )
@@ -883,7 +895,7 @@ def precompile(
                 items, lanes, registry = int_dims
                 if key_table is None or len(key_table) != registry:
                     continue  # another registry's program
-                with first_dispatch(op, *dims):
+                with _warming(op, *dims):
                     sum_indexed_device(
                         key_table.device_limbs(mesh), [np.zeros(1, np.int32)],
                         (items, lanes), mesh=mesh,
@@ -896,7 +908,7 @@ def precompile(
                 # lane shape: results discarded, only the 2-item
                 # multi-MSM kernel compile matters
                 lanes = int_dims[0]
-                with first_dispatch(op, *dims):
+                with _warming(op, *dims):
                     msm_g1_many_device(
                         [[g1_generator()]] * 2, [[1]] * 2,
                         mesh=mesh, pad_shape=(2, lanes),
@@ -907,7 +919,7 @@ def precompile(
 
                 # one throwaway lane at exactly the padded shape; only a
                 # warmed bucket's flushes go to the device (ops/das_batch.py)
-                with first_dispatch(op, *dims):
+                with _warming(op, *dims):
                     msm_g1_many_device([[g1_generator()]], [[1]], pad_shape=int_dims)
             elif op == "shuffle" and len(int_dims) == 1 and mesh is None:
                 from eth_consensus_specs_tpu.ops.shuffle import (
@@ -917,7 +929,7 @@ def precompile(
 
                 # one live lane under the bucket: the count is a traced
                 # number, so this is the program every count below it runs
-                with first_dispatch(op, *dims):
+                with _warming(op, *dims):
                     shuffled_indices_device(
                         np.zeros(1, np.int32), bytes(32), mainnet_rounds(),
                         lanes=int_dims[0],
@@ -930,7 +942,7 @@ def precompile(
                 # inverse and forward tables share one executable
                 # (twiddles are traced args), so either direction warms
                 batch, nfft = int_dims
-                with first_dispatch(op, *dims):
+                with _warming(op, *dims):
                     batch_fft_field(
                         [[0] * nfft], compute_roots_of_unity(nfft),
                         inv=True, mesh=mesh, pad_batch=batch,
@@ -943,7 +955,7 @@ def precompile(
                 # sums are discarded, only the (items, lanes[, mesh])
                 # kernel compile matters
                 items, lanes = int_dims
-                with first_dispatch(op, *dims):
+                with _warming(op, *dims):
                     sum_g2_many_device(
                         [[g2_generator()] * lanes] * items,
                         mesh=mesh,
@@ -955,7 +967,9 @@ def precompile(
                 # AOT lower+compile of the fused slot-apply executable
                 # (no live forest touched); skips — not fails — when the
                 # key's forest-plan caps don't match this build
-                if not serve_slot.precompile_key((op, *int_dims), mesh=mesh):
+                with waterfall.leg("precompile.slot_apply"):
+                    done = serve_slot.precompile_key((op, *int_dims), mesh=mesh)
+                if not done:
                     continue
             else:
                 continue

@@ -191,7 +191,9 @@ class VerifyService:
         decoded as before."""
         from eth_consensus_specs_tpu.ops.key_table import KeyTable
 
-        self._keys = KeyTable(pubkeys)
+        with obs.span("serve.register_pubkeys", keys=len(pubkeys)) as sp:
+            self._keys = KeyTable(pubkeys)
+        obs.observe("serve.setup_ms.register_pubkeys", sp.seconds * 1e3)
 
     def submit_bls_aggregate(self, pubkeys, message: bytes, signature: bytes,
                              canary: bool = False) -> Future:

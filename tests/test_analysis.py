@@ -413,6 +413,36 @@ def test_obs_discipline_compile_ms_call_sites(tmp_path):
     assert [f.symbol for f in findings] == ["grammar:serve.compile_ms.Rogue-Op"]
 
 
+def test_obs_discipline_checks_a_leg_in_the_compile_helpers_own_module(tmp_path):
+    """serve/buckets.py is exempt for the compile helpers it defines, not for
+    the legs it opens: `leg(f"precompile.{op}")` is held to the catalog's
+    family row like a leg anywhere else."""
+
+    class _PrecompileOnly:
+        def declared(self, kind, name):
+            return kind != "span" or name == "precompile.*"
+
+    findings = _lint(
+        tmp_path,
+        {
+            "serve/__init__.py": "",
+            "serve/buckets.py": """\
+            from eth_consensus_specs_tpu.obs import waterfall
+
+            def precompile(keys):
+                for op in keys:
+                    with waterfall.leg(f"precompile.{op}"), first_dispatch(op, 4):
+                        pass
+                with waterfall.leg("warmup.alien"):
+                    pass
+            """,
+        },
+        {"obs-discipline"},
+        catalog=_PrecompileOnly(),
+    )
+    assert [f.symbol for f in findings] == ["undeclared:warmup.alien"]
+
+
 def test_obs_discipline_compile_ms_undeclared(tmp_path):
     class _NoCat:
         def declared(self, kind, name):
