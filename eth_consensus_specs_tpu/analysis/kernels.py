@@ -419,6 +419,25 @@ def _fr_fft_args(batch: int, n: int, stages: int):
     )
 
 
+def _das_fold_args(rows: int, segments: int, n: int, stages: int):
+    """ops/fr_fft.fold_program's arguments: a flush's cells as 32-bit
+    words, a weight and a segment id a row, ``enter``, the coset unshift
+    table (a row a column index, 128) and a row of it a segment, the
+    twiddles."""
+    from eth_consensus_specs_tpu.ops import fr_fft
+
+    limbs = fr_fft.FR.n_limbs
+    return (
+        _sds((rows, 8 * n), "uint32"),
+        _sds((rows, limbs), "uint64"),
+        _sds((rows,), "int32"),
+        _sds((limbs,), "uint64"),
+        _sds((128, n, limbs), "uint64"),
+        _sds((segments,), "int32"),
+        *(_sds((1 << i, limbs), "uint64") for i in range(stages)),
+    )
+
+
 def _fr_fft_variants(mesh):
     from eth_consensus_specs_tpu.ops import fr_fft
     from eth_consensus_specs_tpu.parallel import mesh_ops

@@ -161,10 +161,15 @@ CATALOG: tuple[Metric, ...] = (
     _c("das.flushes", "DAS bench blob-verification flushes"),
     _c("das.columns_verified", "data column sidecars through verify_many_columns"),
     _c("das.fft_rows", "cells interpolated, rows of a flush's one inverse FFT of 64 points"),
+    _c("das.fold_rows_device", "cells weighted and added a sidecar inside the device program"),
+    _c("das.boundary_ints",
+       "Python integers made from or for the interpolation's arrays, counted from shapes"),
     _c("das.isolated_invalid", "invalid data column sidecars isolated by bisection"),
     _s("das.verify_many", "batched data column sidecar verification with bisection"),
-    _s("das.fold", "leg: dedup, Fiat-Shamir challenge and powers, the cells as the FFT's rows"),
-    _s("das.interp_fold", "leg: a sidecar's weighted sum of interpolation coefficients, coset unshift"),
+    _s("das.fold", "leg: dedup, Fiat-Shamir challenge and powers, the cells' bytes as one array"),
+    _s("das.interp_fold",
+       "leg: a sidecar's weighted sum of interpolation coefficients and coset unshift: the device "
+       "program's weights, segment ids and column indices, or the fold itself on the host route"),
     _s("das.check", "leg: one check of a run of sidecars: sums, RLC, RLI, the pairing"),
     _h("das.msm_call_ms",
        "a flush's per-sidecar proof sums, ms: ONE multi-MSM execution (a sample an execution)"),

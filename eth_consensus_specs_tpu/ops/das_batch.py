@@ -14,12 +14,23 @@ costs no second device execution:
 
   1. **fold** (leg ``das.fold``): the flush's commitments deduplicated,
      the spec's Fiat-Shamir challenge over every cell in request order,
-     its powers ``r^k``, and the cells' evaluations as rows.
-  2. **interpolation**: every cell is a row of ONE batched inverse FFT of
-     64 points (``ops/fr_fft``); the coset unshift ``h^-t`` is shared by a
-     sidecar's cells (one column index), so a sidecar's interpolation
-     polynomial folds to ``h^-t * sum_k r^k c_k[t]`` (leg
-     ``das.interp_fold``, host integers).
+     its powers ``r^k``, and the cells' evaluations as ONE array of the
+     bytes they arrived in, ``uint8[cells, 64, 32]``: a cell's 2,048
+     bytes are its 64 canonical elements in bit-reversed order, which is
+     the order the transform's butterflies take, so no integer is made
+     of an element.
+  2. **interpolation**: only ``h^-t * sum_k r^k c_k[t]`` a sidecar is
+     ever read of the cells' interpolation polynomials ``c_k`` (the coset
+     unshift ``h^-t`` is shared by a sidecar's cells: one column index),
+     and the inverse FFT is linear, so ONE device program
+     (``ops/fr_fft.fold_program``) cuts the limbs from the bytes, weights
+     every row by its ``r^k``, adds a sidecar's rows, transforms the 64
+     points of a row a SIDECAR and scales it by ``h^-t / 64``. The leg
+     ``das.interp_fold`` is that program's host side (the ``r^k`` as
+     limbs, a segment id a row, a column index a sidecar); 64 integers a
+     sidecar come back. On the host route (below) the cells become
+     integers, each row goes through the host's FFT and the same leg
+     folds the rows as host integers (:func:`_interp_fold`).
   3. **the multi-MSM**: ONE ``ops/g1_msm.msm_many_kernel`` execution whose
      items are the sidecars, two each: ``A_j = sum_k r^k pi_k`` and
      ``B_j = sum_k r^k h_j^64 pi_k`` over the sidecar's proofs.
@@ -35,8 +46,10 @@ costs no second device execution:
      again.
 
 Routing is by what the code observes: the two programs run on the device
-for buckets that ``serve.buckets.precompile`` has compiled (minutes each
-for the limb kernels), and through the host's FFT and the C core's MSM
+for buckets that ``serve.buckets.precompile`` has compiled (minutes for
+the limb kernel; its ``das_msm`` key warms the multi-MSM and, under
+``serve.buckets.das_fold_key``, the folding interpolation of a block
+that fills the bucket), and through the host's FFT and the C core's MSM
 otherwise, so no flush compiles on the thread that serves it. Verdicts
 are the same either way: every value between the legs is an exact
 integer or group element.
@@ -50,6 +63,7 @@ are the caller's.
 
 from __future__ import annotations
 
+import os
 import time
 from functools import lru_cache
 from typing import NamedTuple
@@ -212,15 +226,15 @@ class _Fold(NamedTuple):
     starts: list  # cell k of sidecar j is starts[j] + its row
     r_powers: list
     weights: list  # per sidecar: {distinct commitment: sum of its cells' r^k}
-    rows: list  # per cell: its 64 evaluations in natural (g^e) order
+    cells: np.ndarray  # uint8[cells, 64, 32]: a cell's elements as its own bytes
 
 
 def _fold(columns: list) -> _Fold:
     """Dedup, the spec's challenge
     (``compute_verify_cell_kzg_proof_batch_challenge`` over every cell of
     the flush in request order), its powers, the commitment weights a
-    sidecar, and the FFT's rows. A cell's evaluations are canonical, so
-    ``bls_field_to_bytes`` of each is the cell's own bytes."""
+    sidecar, and the cells as one array. A cell's evaluations are
+    canonical, so ``bls_field_to_bytes`` of each is the cell's own bytes."""
     place: dict[bytes, int] = {}
     points = []
     for col in columns:
@@ -252,20 +266,28 @@ def _fold(columns: list) -> _Fold:
         for row, c in enumerate(col.commitments):
             w[place[c]] = w.get(place[c], 0) + r_powers[start + row]
         weights.append(w)
-    # a cell holds its coset's evaluations in bit-reversed order: natural
-    # order is what interpolates by an inverse FFT over the subgroup
-    evals = np.frombuffer(b"".join(cell for col in columns for cell in col.cells), np.uint8)
-    evals = evals.reshape(total, N_CELL, 32)[:, _BRP_CELL]
-    view = memoryview(evals.tobytes())
+    cells = np.frombuffer(b"".join(cell for col in columns for cell in col.cells), np.uint8)
+    return _Fold(points, starts, r_powers, weights, cells.reshape(total, N_CELL, 32))
+
+
+def _host_coefficients(fold: _Fold) -> list:
+    """The host route's inverse FFT, a transform a cell. A cell holds its
+    coset's evaluations in bit-reversed order: natural order is what the
+    host's transform interpolates from, and integers are what it takes."""
+    view = memoryview(fold.cells[:, _BRP_CELL].tobytes())
     flat = [int.from_bytes(view[i : i + 32], "big") for i in range(0, len(view), 32)]
-    rows = [flat[i : i + N_CELL] for i in range(0, len(flat), N_CELL)]
-    return _Fold(points, starts, r_powers, weights, rows)
+    obs.count("das.boundary_ints", len(flat))
+    roots = kzg.compute_roots_of_unity(N_CELL)
+    return [
+        das.fft_field(flat[i : i + N_CELL], roots, inv=True) for i in range(0, len(flat), N_CELL)
+    ]
 
 
 def _interp_fold(columns: list, fold: _Fold, coeff_rows: list) -> list:
     """Per sidecar, the 64 coefficients of ``sum_k r^k I_k``: the inverse
     FFT's rows weighted and added, then the coset unshift ``h^-t`` once a
-    sidecar (its cells share the column index)."""
+    sidecar (its cells share the column index). The host route's; the
+    device route's program does the same (:func:`_device_interp`)."""
     tables = _coset_tables()
     out = []
     for col, start in zip(columns, fold.starts):
@@ -319,18 +341,67 @@ def _sum_points(points: list) -> Point:
     return total
 
 
-def _coefficients(fold: _Fold, fft_key: tuple, device: bool) -> list:
-    """The inverse FFT of every cell of the flush: ONE device execution,
-    or the host's transform a row."""
-    roots = kzg.compute_roots_of_unity(N_CELL)
-    obs.count("das.fft_rows", len(fold.rows))
-    if not device:
-        return [das.fft_field(row, roots, inv=True) for row in fold.rows]
-    from eth_consensus_specs_tpu.ops.fr_fft import batch_fft_field
+@lru_cache(maxsize=1)
+def _device_unshift():
+    """u64[128, 64, L] on the device, resident like the twiddles: for each
+    column index the plain limbs of ``h^-t / 64``, the coset unshift and
+    the inverse transform's scale in the one multiply that leaves
+    Montgomery form."""
+    import jax.numpy as jnp
+
+    from eth_consensus_specs_tpu.obs import ledger
+    from eth_consensus_specs_tpu.ops.fr_fft import FR
+
+    n_inv = pow(N_CELL, -1, BLS_MODULUS)
+    flat = [u * n_inv % BLS_MODULUS for _, unshift in _coset_tables() for u in unshift]
+    table = jnp.asarray(FR.ints_to_limbs_batch(flat).reshape(NUMBER_OF_COLUMNS, N_CELL, -1))
+    ledger.register("trusted_setup", "das_coset_unshift", int(table.nbytes))
+    return table
+
+
+# fork-safety, as ops/fr_fft's twiddles: the table references the parent's device
+os.register_at_fork(after_in_child=_device_unshift.cache_clear)
+
+
+def _device_interp(columns: list, fold: _Fold, key: tuple) -> list:
+    """Per sidecar, the 64 coefficients of ``h^-t * sum_k r^k I_k`` from
+    ONE device execution over the flush's bytes: what
+    :func:`_interp_fold` makes of the host's transforms."""
+    from eth_consensus_specs_tpu.ops import fr_fft
     from eth_consensus_specs_tpu.serve import buckets
 
-    with buckets.first_dispatch(*fft_key):
-        return batch_fft_field(fold.rows, roots, inv=True, pad_batch=fft_key[1])
+    _, rows, segments = key
+    total = len(fold.cells)
+    with waterfall.leg("das.interp_fold"):
+        weights = np.zeros((rows, fr_fft.FR.n_limbs), np.uint64)
+        weights[:total] = fr_fft.FR.ints_to_limbs_batch(fold.r_powers)
+        # padded rows weigh 0 whatever their segment; padded segments stay 0
+        segment_of = np.full(rows, len(columns) - 1, np.int32)
+        segment_of[:total] = np.repeat(
+            np.arange(len(columns), dtype=np.int32), [len(col.proofs) for col in columns]
+        )
+        scale_rows = np.zeros(segments, np.int32)
+        scale_rows[: len(columns)] = [col.index for col in columns]
+    obs.count("das.fold_rows_device", total)
+    obs.count("das.boundary_ints", len(columns) * N_CELL)
+    with buckets.first_dispatch(*key):
+        return fr_fft.batch_ifft_folded(
+            fold.cells, kzg.compute_roots_of_unity(N_CELL), weights, segment_of,
+            _device_unshift(), scale_rows, live=len(columns),
+        )
+
+
+def warm_fold(rows: int, segments: int) -> None:
+    """Compile (or load) the folding interpolation at (rows, segments):
+    one zero cell through :func:`_device_interp`'s program, the answer
+    discarded. ``serve.buckets.precompile``'s."""
+    from eth_consensus_specs_tpu.ops import fr_fft
+
+    fr_fft.batch_ifft_folded(
+        np.zeros((1, N_CELL, 32), np.uint8), kzg.compute_roots_of_unity(N_CELL),
+        np.zeros((rows, fr_fft.FR.n_limbs), np.uint64), np.zeros(rows, np.int32),
+        _device_unshift(), np.zeros(segments, np.int32), live=1,
+    )
 
 
 def _partial_sums(columns: list, fold: _Fold, msm_key: tuple, device: bool) -> tuple[list, list]:
@@ -430,12 +501,17 @@ def verify_many_columns(items: list, parsed: list | None = None) -> list[bool]:
     with obs.span("das.verify_many", items=len(columns)):
         obs.count("das.columns_verified", len(columns))
         fft_key, msm_key = _bucket_keys(columns)
-        device = buckets.is_compiled(*fft_key) and buckets.is_compiled(*msm_key)
+        interp_key = buckets.das_fold_key(fft_key[1], len(columns))
+        device = buckets.is_compiled(*interp_key) and buckets.is_compiled(*msm_key)
         with waterfall.leg("das.fold"):
             fold = _fold(columns)
-        coeff_rows = _coefficients(fold, fft_key, device)
-        with waterfall.leg("das.interp_fold"):
-            interp = _interp_fold(columns, fold, coeff_rows)
+        obs.count("das.fft_rows", len(fold.cells))
+        if device:
+            interp = _device_interp(columns, fold, interp_key)
+        else:
+            coeff_rows = _host_coefficients(fold)
+            with waterfall.leg("das.interp_fold"):
+                interp = _interp_fold(columns, fold, coeff_rows)
         a_sums, b_sums = _partial_sums(columns, fold, msm_key, device)
         flush = _Flush(fold.commitments, fold.weights, interp, a_sums, b_sums)
         for i, v in zip(live, _bisect(flush, 0, len(columns))):

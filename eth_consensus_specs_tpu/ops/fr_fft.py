@@ -17,6 +17,14 @@ a plain constant, which is also where an inverse transform's ``1/n``
 goes. The host (:func:`batch_fft_field`) only reduces ``% r`` and splits
 or joins bits, as array operations over the whole flush.
 
+Two boundaries stand over that one program. :func:`batch_fft_field` takes
+integers and returns every element as an integer (the blob path, a
+single vector). :func:`batch_ifft_folded` takes a data column flush's
+cells as the bytes they arrived in and returns one folded row a sidecar
+(:func:`fold_program`): the transform is linear, so ``sum_k w_k
+IFFT(row_k)`` is ``IFFT(sum_k w_k row_k)``, the rows are weighted and
+added BEFORE the stages, and the stages run over a row a sidecar.
+
 Bit-exact with the host oracle crypto/das.fft_field (same DIT butterfly
 order: both equal the textbook DFT in exact modular arithmetic)."""
 
@@ -141,6 +149,52 @@ def _compiled_fft(n: int, n_stages: int):
     return run
 
 
+def fold_program(words, weights, segments, enter, scale, scale_rows, twiddles, n: int):
+    """A data column flush's device program: a row a segment, ``scale[j] *
+    IFFT(sum of w_k row_k over the rows k of segment j)``, as canonical
+    plain limbs.
+
+    words: u32[B, 8 n], row k's n elements as eight little-endian 32-bit
+    words each, canonical, in bit-reversed order; weights: u64[B, L] plain
+    limbs below r, zero for a padded row; segments: i32[B] ascending;
+    scale: u64[T, n, L] plain limbs below r (an inverse transform's 1/n is
+    the caller's to put in them), of which segment j takes row
+    ``scale_rows[j]``; enter: ``R^2 mod r``.
+
+    The weights are lifted like the values (one multiply by ``enter``), so
+    a weighted element is the plain product in [0, 2r). A segment's rows
+    are added as unreduced limbs (each below 2^30, so a lane holds 2^34 of
+    them) and swept once: a segment has at most B rows, and ``B * 2r < R``
+    is what keeps the sum inside the L limbs and inside what one multiply
+    by ``enter`` reduces (``a * b / R + r < 2r`` for ``a < R``, ``b <
+    r``). From there it is :func:`fft_program` as every caller runs it,
+    over a row a segment, its ``leave`` the segment's scale row."""
+    rows = words.shape[0]
+    assert rows * 2 * BLS_MODULUS < FR.r_int
+    vals = FR.words_to_limbs(words.reshape(rows, n, -1))
+    weighted = FR.mont_mul(vals, FR.mont_mul(weights, enter)[:, None, :])
+    sums, _carry = FR._carry_sweep(
+        jax.ops.segment_sum(
+            weighted, segments, num_segments=scale_rows.shape[0], indices_are_sorted=True
+        )
+    )
+    return fft_program(sums, enter, jnp.take(scale, scale_rows, axis=0), twiddles, n)
+
+
+@lru_cache(maxsize=None)
+def _compiled_fold(n: int, n_stages: int):
+    """One executable per (rows, segments) shape of :func:`fold_program`.
+    The function is called ``run`` like :func:`_compiled_fft`'s: in a
+    process that serves data column flushes it is the one program of that
+    name a flush executes."""
+
+    @jax.jit
+    def run(words, weights, segments, enter, scale, scale_rows, *twiddles):
+        return fold_program(words, weights, segments, enter, scale, scale_rows, list(twiddles), n)
+
+    return run
+
+
 # -- mesh-sharded variant: rows of a batched FFT are independent, so the
 # BATCH axis shards with NO collectives (every shard runs the identical
 # program over its rows) — byte-identical to the single-device dispatch
@@ -258,3 +312,54 @@ def batch_fft_field(
 def fft_field_device(vals, roots_of_unity, inv: bool = False) -> list[int]:
     """Drop-in device twin of crypto/das.fft_field (single vector)."""
     return batch_fft_field([list(vals)], roots_of_unity, inv=inv)[0]
+
+
+def cells_to_words(cells: np.ndarray, pad_batch: int) -> np.ndarray:
+    """uint8[b, n, 32] big-endian field elements -> u32[pad_batch, 8 n]:
+    each element as eight little-endian 32-bit words, least first, zero
+    rows up to the bucket. What :func:`fold_program` cuts its limbs from:
+    no integer is made of an element."""
+    b, n, _ = cells.shape
+    assert pad_batch >= b
+    words = np.zeros((pad_batch, n, 8), "<u4")
+    words[:b] = cells.view(">u4")[:, :, ::-1]
+    return words.reshape(pad_batch, 8 * n)
+
+
+def batch_ifft_folded(
+    cells: np.ndarray,
+    roots_of_unity,
+    weights: np.ndarray,
+    segments: np.ndarray,
+    scale,
+    scale_rows: np.ndarray,
+    live: int,
+) -> list[list[int]]:
+    """Per segment j < live, ``scale[scale_rows[j]] * IFFT(sum_k w_k
+    row_k)`` over the rows of the segment, as integers: bit-exact with
+    crypto/das.fft_field(inv=True) a row, weighted, added and scaled on
+    the host.
+
+    cells: uint8[b, n, 32], row k's elements big-endian, canonical, in
+    BIT-REVERSED order (a cell's own bytes); weights: u64[B, L] plain
+    limbs, B the row bucket, zero beyond b; segments: i32[B] ascending;
+    scale: a resident u64[T, n, L] table of plain limbs that carry the
+    1/n; scale_rows: i32[S], S the segment bucket. Bytes go in and S x n
+    elements come back: the only integers made are the live ones of
+    those."""
+    roots = tuple(int(r) for r in roots_of_unity)
+    n = len(roots)
+    assert cells.shape[1:] == (n, 32) and n & (n - 1) == 0
+    with waterfall.leg("fr_fft.pack"):
+        words = cells_to_words(cells, weights.shape[0])
+    with waterfall.leg("fr_fft.call"):
+        twiddles = _device_twiddles((roots[0],) + roots[:0:-1], n)
+        enter = FR.int_to_limbs(FR.r_int * FR.r_int % BLS_MODULUS)
+        out = np.asarray(
+            _compiled_fold(n, len(twiddles))(
+                words, weights, segments, enter, scale, scale_rows, *twiddles
+            )
+        )
+    with waterfall.leg("fr_fft.unpack"):
+        flat = FR.limbs_to_ints_batch(out[:live])
+        return [flat[i * n : (i + 1) * n] for i in range(live)]

@@ -79,25 +79,38 @@ class LimbField:
 
     # The array forms of int_to_limbs / limbs_to_int: a limb is a bit
     # field of the integer, so a whole batch is cut from (joined into)
-    # little-endian 64-bit words with one shift and mask a limb. A limb
-    # never reaches past the last word (n_words covers all the limbs).
+    # little-endian words with one shift and mask a limb.
+
+    def words_to_limbs(self, words):
+        """[..., k] little-endian words of 32 or 64 bits, a NumPy or a
+        device array -> [..., n_limbs] u64 limbs: limb i is bits
+        [30 i, 30 i + 30) of the integer the words spell. The ONE cutter of
+        every boundary: the host's integers pass through it as 64-bit
+        words (:meth:`ints_to_limbs_batch`), a data column flush's bytes
+        as 32-bit words inside its device program (ops/fr_fft.py). Bits
+        the words do not hold are zero; the caller answers for bits above
+        the last limb."""
+        bits = 8 * words.dtype.itemsize
+        word = words.dtype.type
+        limbs = []
+        for i in range(self.n_limbs):
+            q, o = divmod(i * LIMB_BITS, bits)
+            limb = words[..., q] >> word(o)
+            if o + LIMB_BITS > bits and q + 1 < words.shape[-1]:
+                limb = limb | (words[..., q + 1] << word(bits - o))
+            limbs.append((limb & word(MASK)).astype(np.uint64, copy=False))
+        xp = np if isinstance(words, np.ndarray) else jnp
+        return xp.stack(limbs, axis=-1)
 
     def ints_to_limbs_batch(self, values) -> np.ndarray:
         """Flat sequence of ints in [0, 2^(30 * n_limbs)) -> [len, n_limbs]
         u64 limbs, row i equal to ``int_to_limbs(values[i])``."""
         buf = b"".join([v.to_bytes(8 * self.n_words, "little") for v in values])
         words = np.frombuffer(buf, dtype="<u8").reshape(-1, self.n_words)
-        out = np.empty((words.shape[0], self.n_limbs), np.uint64)
-        for k in range(self.n_limbs):
-            q, o = divmod(k * LIMB_BITS, 64)
-            limb = words[:, q] >> np.uint64(o)
-            if o + LIMB_BITS > 64:
-                limb = limb | (words[:, q + 1] << np.uint64(64 - o))
-            out[:, k] = limb & np.uint64(MASK)
         # int_to_limbs' `assert x == 0`: no bit above the last limb
         top = LIMB_BITS * self.n_limbs - 64 * (self.n_words - 1)
         assert top == 64 or not (words[:, -1] >> np.uint64(top)).any()
-        return out
+        return self.words_to_limbs(words)
 
     def limbs_to_ints_batch(self, limbs) -> list[int]:
         """[..., n_limbs] limbs, each below 2^30 -> flat list of ints,

@@ -394,9 +394,18 @@ def das_msm_key(n_items: int, max_lanes: int) -> tuple:
     items a sidecar and a lane a proof, each pow2-bucketed. Another family
     than ``kzg``, whose program is the same kernel at two items: a block
     of 128 sidecars of 21 blobs is 256 x 32. One chip holds the block, so
-    the key is never mesh-signed. The flush's inverse FFT runs under
-    :func:`fr_fft_key` at 64 points."""
+    the key is never mesh-signed. The flush's interpolation runs under
+    :func:`das_fold_key`."""
     return ("das_msm", pow2_bucket(max(int(n_items), 1)), pow2_bucket(max(int(max_lanes), 1)))
+
+
+def das_fold_key(cells: int, sidecars: int) -> tuple:
+    """The key of a data column flush's folding interpolation
+    (``ops/fr_fft.fold_program`` through ``ops/das_batch``): the row
+    bucket of its cells, :func:`fr_fft_key`'s at 64 points, and the
+    sidecar bucket, half of :func:`das_msm_key`'s items. A block of 128
+    sidecars of 21 blobs is 4,096 x 128."""
+    return ("das_fold", pow2_bucket(max(int(cells), 1)), pow2_bucket(max(int(sidecars), 1)))
 
 
 def shuffle_key(n: int) -> tuple:
@@ -826,6 +835,13 @@ def _warming(op: str, *dims):
         yield
 
 
+def _warm_das_fold(rows: int, segments: int) -> None:
+    from eth_consensus_specs_tpu.ops import das_batch
+
+    with _warming("das_fold", rows, segments):
+        das_batch.warm_fold(rows, segments)
+
+
 def precompile(
     keys: list[tuple] | None = None, path: str | None = None, chips: int | None = None,
     key_table=None,
@@ -843,8 +859,11 @@ def precompile(
     service's registry of public keys (ops/key_table.py), which the
     ``bls_keysum`` programs gather from; their keys are skipped without
     it, or where it has another length than the key names. A
-    ``das_msm`` key, and the ``fr_fft`` key at 64 points beside it, are
-    what sends a flush of data column sidecars to the device; a
+    ``das_msm`` key is what sends a flush of data column sidecars to the
+    device: it warms the multi-MSM and, under its own ``das_fold`` key,
+    the folding interpolation of the block that fills the bucket (every
+    sidecar as wide as the lane bucket; a ``das_fold`` key warms that
+    program at any other shape); a
     ``shuffle`` key does the same for committee requests of its lane
     bucket. A key is warmed inside the leg ``precompile.<op>``."""
     import numpy as np
@@ -921,6 +940,10 @@ def precompile(
                 # warmed bucket's flushes go to the device (ops/das_batch.py)
                 with _warming(op, *dims):
                     msm_g1_many_device([[g1_generator()]], [[1]], pad_shape=int_dims)
+                items, lanes = int_dims
+                _warm_das_fold(*das_fold_key(items // 2 * lanes, items // 2)[1:])
+            elif op == "das_fold" and len(int_dims) == 2 and mesh is None:
+                _warm_das_fold(*int_dims)
             elif op == "shuffle" and len(int_dims) == 1 and mesh is None:
                 from eth_consensus_specs_tpu.ops.shuffle import (
                     mainnet_rounds,

@@ -54,9 +54,10 @@ def main(argv: list[str] | None = None) -> int:
         return 2
 
     from benchmark.reference import das_ref
-    from eth_consensus_specs_tpu.crypto import das, kzg
+    from eth_consensus_specs_tpu.crypto import kzg
     from eth_consensus_specs_tpu.crypto import native_bridge as nb
-    from eth_consensus_specs_tpu.ops import das_batch, fr_fft
+    from eth_consensus_specs_tpu.obs import waterfall
+    from eth_consensus_specs_tpu.ops import das_batch
     from eth_consensus_specs_tpu.serve import buckets
     from eth_consensus_specs_tpu.utils.cache import enable_persistent_cache
 
@@ -89,21 +90,30 @@ def main(argv: list[str] | None = None) -> int:
 
     # ---- the fold
     fold, first, best = timed(lambda: das_batch._fold(columns), args.repeat)
-    emit("das.fold", "host", first, best, cells=len(fold.rows))
+    emit("das.fold", "host", first, best, cells=len(fold.cells))
 
-    # ---- interpolation
+    # ---- interpolation: a transform a cell and the fold on the host, or the
+    # ONE device program from the cells' bytes to a folded row a sidecar
     fft_key, msm_key = das_batch._bucket_keys(columns)
-    roots = kzg.compute_roots_of_unity(das_batch.N_CELL)
-    want_rows, first, best = timed(
-        lambda: [das.fft_field(row, roots, inv=True) for row in fold.rows], 1)
-    emit("interpolation", "host, a transform a row", first, best)
-    got_rows, first, best = timed(
-        lambda: fr_fft.batch_fft_field(fold.rows, roots, inv=True, pad_batch=fft_key[1]),
-        args.repeat)
-    emit("interpolation", f"device, {fft_key[1]} x {fft_key[2]}", first, best,
-         equal=got_rows == want_rows)
-    interp, first, best = timed(lambda: das_batch._interp_fold(columns, fold, got_rows), args.repeat)
-    emit("das.interp_fold", "host", first, best)
+    interp_key = buckets.das_fold_key(fft_key[1], len(columns))
+    host_rows, first, best = timed(lambda: das_batch._host_coefficients(fold), 1)
+    emit("interpolation", "host, integers from bytes and a transform a cell", first, best)
+    want_interp, first, best = timed(
+        lambda: das_batch._interp_fold(columns, fold, host_rows), args.repeat)
+    emit("das.interp_fold", "host, over the host's rows", first, best)
+    legs = ("das.interp_fold", "fr_fft.pack", "fr_fft.call", "fr_fft.unpack")
+
+    def device_interp():
+        ledger = waterfall.open_flush()
+        try:
+            return das_batch._device_interp(columns, fold, interp_key), dict(ledger)
+        finally:
+            waterfall.close_flush()
+
+    (interp, _), first, best = timed(device_interp, args.repeat)
+    emit("interpolation", f"device, {interp_key[1]} rows folded into {interp_key[2]} sidecars",
+         first, best, equal=interp == want_interp,
+         legs_ms={k: round(v, 3) for k, v in device_interp()[1].items() if k in legs})
 
     # ---- the proof sums
     want_sums, first, best = timed(
@@ -134,7 +144,7 @@ def main(argv: list[str] | None = None) -> int:
     buckets.reset_for_tests()
     host, first, best = timed(lambda: das_batch.verify_many_columns(items, parsed=columns), 1)
     emit("flush", "host route", first, best)
-    for key in (fft_key, msm_key):
+    for key in (interp_key, msm_key):
         buckets.note_dispatch(*key)  # both programs ran above: compiled
     got, first, best = timed(lambda: das_batch.verify_many_columns(items, parsed=columns),
                              args.repeat)

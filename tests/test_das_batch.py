@@ -8,7 +8,9 @@ The sidecars come from the testing setup's public trapdoor: a cell's proof
 is ``[(f(tau) - I(tau)) / (tau^64 - h^64)] G``, with ``I`` from the host's
 own coset interpolation, so nothing here costs an FK20 run (a minute a
 blob). The device route compiles the two programs at 8 x 64 and 8 x 2 for
-XLA:CPU, a few seconds: nothing here is marked slow.
+XLA:CPU, a few seconds: nothing here is marked slow. The interpolation's
+program (``ops/fr_fft.fold_program``, bytes in and a folded row a sidecar
+out) is warmed by the ``das_msm`` key at 8 rows x 4 sidecars.
 """
 
 from __future__ import annotations
@@ -20,6 +22,7 @@ from eth_consensus_specs_tpu import obs
 from eth_consensus_specs_tpu.crypto import das, kzg, kzg_setup
 from eth_consensus_specs_tpu.crypto.curve import g1_generator, g1_to_bytes
 from eth_consensus_specs_tpu.crypto.fields import R
+from eth_consensus_specs_tpu.obs import waterfall
 from eth_consensus_specs_tpu.ops import das_batch
 from eth_consensus_specs_tpu.serve import buckets
 from eth_consensus_specs_tpu.serve.config import ServeConfig
@@ -84,6 +87,22 @@ def wrong_in_each_half(blobs, block):
     return bad
 
 
+def _with_cell(sidecar, row: int, cell: bytes) -> tuple:
+    index, column, commitments, proofs = sidecar
+    return (index, [*column[:row], cell, *column[row + 1 :]], commitments, proofs)
+
+
+@pytest.fixture(scope="module")
+def wrong_cell_in_each_half(blobs, block):
+    """Canonical bytes that are another blob's cell of the same column, in
+    one sidecar of each half: what only the folded interpolation rows can
+    tell from the right ones."""
+    bad = list(block)
+    bad[0] = _with_cell(bad[0], 1, blobs[2][1][COLUMNS[0]][0])
+    bad[2] = _with_cell(bad[2], 0, blobs[2][1][COLUMNS[2]][0])
+    return bad
+
+
 def _oracle(sidecar) -> bool:
     index, column, commitments, proofs = sidecar
     return das.verify_cell_kzg_proof_batch(commitments, [index] * len(column), column, proofs)
@@ -95,6 +114,34 @@ def warmed():
     of this module's bucket take the device route from here on."""
     assert buckets.precompile(WARM) == len(WARM)
     return WARM
+
+
+@pytest.fixture
+def route(request):
+    """"device": this module's bucket warmed. "host": nothing compiled as
+    far as the routing can see, for this test alone."""
+    if request.param == "device":
+        request.getfixturevalue("warmed")
+        yield request.param
+        return
+    seen = buckets.seen_shapes()
+    buckets.reset_for_tests()
+    yield request.param
+    with buckets._SEEN_LOCK:
+        buckets._SEEN_SHAPES.update(seen)
+
+
+FOLD_LEGS = ("das.fold", "fr_fft.pack", "fr_fft.call", "fr_fft.unpack", "das.interp_fold")
+FOLD_COUNTERS = ("das.boundary_ints", "das.fold_rows_device", "das.fft_rows")
+
+
+def _fold_reads() -> dict:
+    """What the benchmark's per-layer metrics of the interpolation read."""
+    snap = obs.snapshot()
+    return {
+        **{name: snap["spans"].get(name, {}).get("count", 0) for name in FOLD_LEGS},
+        **{name: snap["counters"].get(name, 0) for name in FOLD_COUNTERS},
+    }
 
 
 def _counts() -> dict:
@@ -188,6 +235,98 @@ def test_verdicts_equal_the_oracles_and_a_flush_is_one_fft_and_one_msm(
         assert took["das.rlc_check_ms"] >= 1 + 2 * rejects and took["compiles"] == 0
     assert das_batch.verify_many_columns([]) == []
     assert das_batch.verify_many_columns([malformed]) == [False]
+
+
+@pytest.mark.parametrize("route", ["host", "device"], indirect=True)
+def test_a_wrong_cell_in_each_half_is_refused_alone_on_either_route(
+        route, block, wrong_cell_in_each_half):
+    """The bisection reads the per-sidecar folded rows it already has: on
+    the device route those came back folded from the one execution."""
+    assert [_oracle(s) for s in wrong_cell_in_each_half] == [False, True, False, True]
+    before = _counts()
+    assert das_batch.verify_many_columns(wrong_cell_in_each_half) == [False, True, False, True]
+    took = _delta(before)
+    on_device = int(route == "device")
+    assert (took["fr_fft.call"], took["g1_msm.call"], took["compiles"]) == (on_device, on_device, 0)
+    assert took["das.msm_call_ms"] == 1 and took["das.rlc_check_ms"] == 7  # 1 + 2 + 4
+
+
+@pytest.mark.parametrize(
+    "shape",
+    {
+        "unequal_widths_repeated_columns": [(5, (0, 1, 2)), (5, (0,)), (127, (1, 2))],
+        "padded_rows_and_segments": [(64, (2,)), (0, (0, 1))],
+        "a_full_bucket": [(0, (0, 1)), (127, (1, 2)), (5, (2, 0)), (5, (1, 1))],
+        "one_sidecar": [(6, (0, 1, 2, 0, 1))],
+    }.items(),
+    ids=lambda item: item[0],
+)
+def test_the_device_fold_equals_the_hosts_fold_over_the_hosts_transforms(shape, blobs, warmed):
+    """`_device_interp` (bytes in, ONE execution, a folded row a sidecar
+    out) against `_interp_fold` over `das.fft_field` a cell, at the bucket
+    this module warmed: 8 rows x 4 sidecars."""
+    _, sidecars = shape
+    flush = [_sidecar(blobs, col, rows=rows) for col, rows in sidecars]
+    columns = das_batch.prepare_columns(flush)
+    assert None not in columns
+    fold = das_batch._fold(columns)
+    assert fold.cells.dtype == np.uint8 and fold.cells.shape == (len(fold.r_powers), 64, 32)
+    want = das_batch._interp_fold(columns, fold, das_batch._host_coefficients(fold))
+    key = buckets.das_fold_key(8, 4)
+    assert buckets.is_compiled(*key)
+    before = _counts()
+    got = das_batch._device_interp(columns, fold, key)
+    assert got == want and [len(row) for row in got] == [64] * len(flush)
+    assert all(type(x) is int and 0 <= x < R for row in got for x in row)
+    assert _delta(before)["compiles"] == 0
+
+
+@pytest.mark.parametrize("route", ["host", "device"], indirect=True)
+def test_every_leg_opens_once_a_flush_and_the_counters_follow_the_shapes(route, block):
+    """The five legs the data column cell's metrics read, and the two
+    counters: 64 integers a sidecar on the device route (8,192 a block of
+    128) with every cell folded there (2,688 a block of 21 blobs), 64 a
+    cell on the host route."""
+    before = _fold_reads()
+    ledger = waterfall.open_flush()
+    try:
+        assert das_batch.verify_many_columns(block) == [True] * 4
+    finally:
+        waterfall.close_flush()
+    took = {k: v - before[k] for k, v in _fold_reads().items()}
+    cells = sum(len(s[1]) for s in block)
+    legs = dict.fromkeys(FOLD_LEGS, 1)
+    if route == "host":
+        legs.update({"fr_fft.pack": 0, "fr_fft.call": 0, "fr_fft.unpack": 0})
+        counters = {"das.boundary_ints": 64 * cells, "das.fold_rows_device": 0}
+    else:
+        counters = {"das.boundary_ints": 64 * len(block), "das.fold_rows_device": cells}
+    assert took == {**legs, **counters, "das.fft_rows": cells}
+    assert all(name in ledger for name, n in legs.items() if n)
+
+
+def test_a_second_flush_at_the_same_bucket_compiles_nothing(block, wrong_in_each_half, warmed):
+    from benchmark.compile_log import CompileLog
+
+    log = CompileLog().install()
+    das_batch.verify_many_columns(block)
+    mark, before = log.mark(), _counts()
+    assert das_batch.verify_many_columns(wrong_in_each_half) == [True, False, True, False]
+    assert CompileLog.since(mark, log.mark())["compiles"] == 0
+    assert _delta(before)["compiles"] == 0
+
+
+def test_the_das_msm_key_warms_the_fold_of_the_block_that_fills_its_bucket(warmed):
+    """`fulu_peerdas_cells.json`'s warm-up keys stand: the `das_msm` key
+    compiles the multi-MSM and, under its own key, the folding
+    interpolation, and `is_compiled` says so of each."""
+    assert buckets.das_fold_key(2688, 128) == ("das_fold", 4096, 128)
+    assert buckets.das_fold_key(256 // 2 * 32, 256 // 2) == ("das_fold", 4096, 128)
+    assert buckets.is_compiled("das_fold", 8, 4) and buckets.is_compiled("das_msm", 8, 2)
+    assert ("das_fold", 8, 4) in buckets.seen_shapes()
+    assert not buckets.is_compiled("das_fold", 4, 2)
+    # a warm-up artifact replays the key as it was noted
+    assert buckets.precompile([("das_fold", 4, 2)]) == 1 and buckets.is_compiled("das_fold", 4, 2)
 
 
 def test_an_uncompiled_bucket_goes_to_the_host_and_compiles_nothing(block, warmed):

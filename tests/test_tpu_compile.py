@@ -217,11 +217,12 @@ def _column_block_program(name: str) -> chip_programs.Program:
 
     sidecars, blobs, points = 128, 21, 64
     if name == "das_fft":
-        _, rows, n = buckets.fr_fft_key(sidecars * blobs, points)
-        stages = n.bit_length() - 1
-        assert (rows, n) == (4096, 64)
+        _, rows, segments = buckets.das_fold_key(sidecars * blobs, sidecars)
+        stages = points.bit_length() - 1
+        assert (rows, segments) == (4096, 128)
         return chip_programs.Program(
-            name, lambda: (fr_fft._compiled_fft(n, stages), kernels._fr_fft_args(rows, n, stages)))
+            name, lambda: (fr_fft._compiled_fold(points, stages),
+                           kernels._das_fold_args(rows, segments, points, stages)))
     _, items, lanes = buckets.das_msm_key(2 * sidecars, blobs)
     assert (items, lanes) == (256, 32)
     return chip_programs.Program(
@@ -235,9 +236,10 @@ def _column_block_program(name: str) -> chip_programs.Program:
     ["das_fft", pytest.param("das_msm", marks=pytest.mark.slow)],
 )
 def test_the_data_column_programs_compile_at_a_blocks_buckets(one_chip, no_compile_cache, name):
-    """`peerdas_block_21.verify`'s two programs, the blob cell's kernels at
-    the opposite shape: 4,096 rows of 64 points where that cell has 8 of
-    4,096, 256 items x 32 lanes where it has 2 x 32."""
+    """`peerdas_block_21.verify`'s two programs: the folding interpolation
+    (4,096 rows of 64 points cut from 32-bit words, weighted and added into
+    128 sidecars, a transform a sidecar) where the blob cell has 8 rows of
+    4,096 points, 256 items x 32 lanes where it has 2 x 32."""
     _check_row(chip_programs.compile_for(one_chip, _column_block_program(name)))
 
 
