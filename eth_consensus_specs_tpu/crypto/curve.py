@@ -322,3 +322,28 @@ def g2_from_bytes(data: bytes, subgroup_check: bool = True) -> Point:
     if subgroup_check and not in_subgroup(p):
         raise ValueError("G2 point not in the prime-order subgroup")
     return p
+
+
+def g2_from_bytes_many(encodings: list[bytes]) -> list[Point | None]:
+    """:func:`g2_from_bytes` of each encoding, None where it raises: the
+    native core decides the 96-byte ones in ONE call, shared out over the
+    host's cores."""
+    from eth_consensus_specs_tpu.crypto import native_bridge as nb
+
+    out: list[Point | None] = [None] * len(encodings)
+    if not nb.enabled():
+        for k, data in enumerate(encodings):
+            try:
+                out[k] = g2_from_bytes(data)
+            except ValueError:
+                pass
+        return out
+    sized = [k for k, data in enumerate(encodings) if len(data) == 96]
+    points, status = nb.g2_decompress_many(b"".join(encodings[k] for k in sized))
+    for k, raw, flag in zip(sized, points, status):
+        if flag == 2:
+            out[k] = g2_infinity()
+        elif flag == 1:
+            (x0, x1), (y0, y1) = raw
+            out[k] = Point(Fq2(Fq(x0), Fq(x1)), Fq2(Fq(y0), Fq(y1)), B2)
+    return out

@@ -238,15 +238,40 @@ def hash_to_g2(msg: bytes, dst: bytes = DST_G2) -> Point:
 
     u0, u1 = hash_to_field_fq2(msg, 2, dst)
     if nb.enabled():
-        if not nb.g2_map_params_sent():
-            nb.g2_map_set_params(_native_map_params_blob())
-        raw = nb.g2_map_from_fields((u0.c0.n, u0.c1.n), (u1.c0.n, u1.c1.n))
-        if raw is None:
-            return Point.infinity(B2)
-        (x0, x1), (y0, y1) = raw
-        return Point(Fq2(Fq(x0), Fq(x1)), Fq2(Fq(y0), Fq(y1)), B2)
+        _send_map_params()
+        return _e2_point(nb.g2_map_from_fields((u0.c0.n, u0.c1.n), (u1.c0.n, u1.c1.n)))
     q = map_to_curve_g2(u0) + map_to_curve_g2(u1)
     return clear_cofactor_g2(q)
+
+
+def hash_to_g2_many(msgs: list[bytes], dst: bytes = DST_G2) -> list[Point]:
+    """:func:`hash_to_g2` of each message: hash_to_field on this thread,
+    the map stage of all of them in ONE native call shared out over the
+    host's cores."""
+    from eth_consensus_specs_tpu.crypto import native_bridge as nb
+
+    if not nb.enabled():
+        return [hash_to_g2(m, dst) for m in msgs]
+    fields = []
+    for m in msgs:
+        u0, u1 = hash_to_field_fq2(m, 2, dst)
+        fields.append(((u0.c0.n, u0.c1.n), (u1.c0.n, u1.c1.n)))
+    _send_map_params()
+    return [_e2_point(raw) for raw in nb.g2_map_from_fields_many(fields)]
+
+
+def _send_map_params() -> None:
+    from eth_consensus_specs_tpu.crypto import native_bridge as nb
+
+    if not nb.g2_map_params_sent():
+        nb.g2_map_set_params(_native_map_params_blob())
+
+
+def _e2_point(raw) -> Point:
+    if raw is None:
+        return Point.infinity(B2)
+    (x0, x1), (y0, y1) = raw
+    return Point(Fq2(Fq(x0), Fq(x1)), Fq2(Fq(y0), Fq(y1)), B2)
 
 
 # == import-time structural validation =====================================

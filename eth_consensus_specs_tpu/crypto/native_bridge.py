@@ -17,8 +17,12 @@ be called from anywhere inside it without cycles.  Points at infinity are
 from __future__ import annotations
 
 import ctypes
+import os
+import threading
+from concurrent.futures import ThreadPoolExecutor
 from contextlib import contextmanager
 
+from eth_consensus_specs_tpu.analysis import lockwatch
 from eth_consensus_specs_tpu.native import get_bls_lib
 
 _enabled: bool | None = None
@@ -75,12 +79,15 @@ def _g1_out(out, inf) -> tuple[int, int] | None:
     return int.from_bytes(raw[:48], "big"), int.from_bytes(raw[48:], "big")
 
 
+def _g2_at(raw: bytes, at: int):
+    v = [int.from_bytes(raw[at + i * 48 : at + (i + 1) * 48], "big") for i in range(4)]
+    return (v[0], v[1]), (v[2], v[3])
+
+
 def _g2_out(out, inf):
     if inf.value:
         return None
-    raw = bytes(out)
-    v = [int.from_bytes(raw[i * 48 : (i + 1) * 48], "big") for i in range(4)]
-    return (v[0], v[1]), (v[2], v[3])
+    return _g2_at(bytes(out), 0)
 
 
 def _buf(data: bytes):
@@ -156,16 +163,73 @@ def g1_aggregate_affine(points: bytes) -> tuple[int, int] | None:
     return _g1_out(out, inf)
 
 
-def _over_threads(n: int, run) -> list:
-    """`run(at, count)` over slices of `n` items, one a thread (the core
-    runs without the GIL): the slices' starts and what each returned."""
-    import os
-    from concurrent.futures import ThreadPoolExecutor
+# --- slices over the host's cores -------------------------------------------
+#
+# The core runs without the GIL (ctypes.CDLL), so a call over many
+# independent items is cut into slices, each ONE C call on a thread of the
+# process's pool. How many follows what the code observes: the items, the
+# CPUs this process may run on, and the least a slice holds for a thread to
+# pay off, set by an item's cost. A call of one slice runs on the calling
+# thread, as it did before the pool. Counter `native.slices.<op>`: the
+# slices each call ran on.
 
-    step = max(-(-n // (os.cpu_count() or 1)), 256)
-    starts = range(0, n, step)
-    with ThreadPoolExecutor(max_workers=len(starts) or 1) as pool:
-        return list(zip(starts, pool.map(lambda at: run(at, min(step, n - at)), starts)))
+_SLICE_FLOOR = {
+    # a Miller loop ~0.5 ms; a slice adds 63 fp12 squarings and inversions
+    "pairing": 8,
+    "g2_decompress": 8,  # sqrt and the psi subgroup check, ~0.3 ms
+    "g2_map": 4,  # two SSWU maps, the isogeny, cofactor clearing, ~0.7 ms
+    # a Pippenger sum's buckets cost a slice ~1.5 ms whatever it holds
+    "g2_msm": 32,
+    "g1_key_validate": 256,  # KeyValidate, ~35 us
+    "g1_decompress": 256,
+}
+
+_POOL: ThreadPoolExecutor | None = None
+_POOL_LOCK = lockwatch.wrap(threading.Lock(), "crypto.native_bridge._POOL_LOCK")
+
+
+def _forget_pool_in_child() -> None:
+    # fork-safety: a child has none of the parent's pool threads, and may
+    # have been forked while another thread held the lock
+    global _POOL, _POOL_LOCK
+    _POOL = None
+    _POOL_LOCK = lockwatch.wrap(threading.Lock(), "crypto.native_bridge._POOL_LOCK")
+
+
+os.register_at_fork(after_in_child=_forget_pool_in_child)
+
+
+def _cpus() -> int:
+    try:
+        return len(os.sched_getaffinity(0))
+    except (AttributeError, OSError):
+        return os.cpu_count() or 1
+
+
+def _pool() -> ThreadPoolExecutor:
+    global _POOL
+    with _POOL_LOCK:
+        if _POOL is None:
+            _POOL = ThreadPoolExecutor(max_workers=_cpus(), thread_name_prefix="bls-core")
+        return _POOL
+
+
+def _slices(op: str, n: int) -> list[tuple[int, int]]:
+    """(start, count) of each slice of `n` items of `op`: a CPU's share of
+    the items a slice, but never fewer than the op's floor (the last slice
+    holds what is left); one slice where `n` is the floor or less."""
+    step = max(-(-n // _cpus()), _SLICE_FLOOR[op])
+    return [(at, min(step, n - at)) for at in range(0, n, step)] or [(0, 0)]
+
+
+def _over_threads(op: str, parts: list[tuple[int, int]], run) -> list:
+    """`run(at, count)` for each slice: what each returned, in order."""
+    from eth_consensus_specs_tpu import obs
+
+    obs.count(f"native.slices.{op}", len(parts))
+    if len(parts) == 1:
+        return [run(*parts[0])]
+    return list(_pool().map(lambda part: run(*part), parts))
 
 
 def g1_key_validate_many(keys: bytes) -> tuple[bytes, int]:
@@ -178,9 +242,10 @@ def g1_key_validate_many(keys: bytes) -> tuple[bytes, int]:
     out = ctypes.create_string_buffer(96 * n)
     src = ctypes.create_string_buffer(keys, len(keys))
     base_in, base_out = ctypes.addressof(src), ctypes.addressof(out)
-    ends = _over_threads(n, lambda at, count: (
-        count, lib.bls_g1_key_validate_many(count, base_in + 48 * at, base_out + 96 * at)))
-    bad = min((at + good for at, (count, good) in ends if good < count), default=n)
+    parts = _slices("g1_key_validate", n)
+    goods = _over_threads("g1_key_validate", parts, lambda at, count: lib.bls_g1_key_validate_many(
+        count, base_in + 48 * at, base_out + 96 * at))
+    bad = min((at + good for (at, count), good in zip(parts, goods) if good < count), default=n)
     return out.raw, bad
 
 
@@ -196,9 +261,27 @@ def g1_decompress_many(points: bytes) -> tuple[bytes, bytes]:
     status = ctypes.create_string_buffer(n)
     src = ctypes.create_string_buffer(points, len(points))
     base = [ctypes.addressof(b) for b in (src, out, status)]
-    _over_threads(n, lambda at, count: lib.bls_g1_decompress_many(
-        count, base[0] + 48 * at, base[1] + 96 * at, base[2] + at))
+    _over_threads("g1_decompress", _slices("g1_decompress", n), lambda at, count: (
+        lib.bls_g1_decompress_many(count, base[0] + 48 * at, base[1] + 96 * at, base[2] + at)))
     return out.raw, status.raw
+
+
+def g2_decompress_many(points: bytes) -> tuple[list, bytes]:
+    """The 96-byte compressed G2 points laid end to end, each decided as
+    :func:`g2_decompress` decides it: (the affine point of each, None where
+    it is not a point of the subgroup; a status byte each: 1 such a point,
+    2 the point at infinity in its one encoding, 0 neither). A flush's
+    signatures are shared out over threads."""
+    lib = get_bls_lib()
+    n = len(points) // 96
+    out = ctypes.create_string_buffer(192 * n)
+    status = ctypes.create_string_buffer(n)
+    src = ctypes.create_string_buffer(points, len(points))
+    base = [ctypes.addressof(b) for b in (src, out, status)]
+    _over_threads("g2_decompress", _slices("g2_decompress", n), lambda at, count: (
+        lib.bls_g2_decompress_many(count, base[0] + 96 * at, base[1] + 192 * at, base[2] + at)))
+    raw, flags = out.raw, status.raw
+    return [_g2_at(raw, 192 * k) if flags[k] == 1 else None for k in range(n)], flags
 
 
 def g2_aggregate(points):
@@ -234,6 +317,8 @@ def g1_msm(points, scalars) -> tuple[int, int] | None:
 
 
 def g2_msm(points, scalars):
+    """Pippenger's sum over each slice of the terms, the slices' sums then
+    added: the same point, whatever the slices."""
     lib = get_bls_lib()
     n = len(points)
     bufs = bytearray()
@@ -244,9 +329,24 @@ def g2_msm(points, scalars):
         bufs += b
         infs.append(i)
         scs += (int(s) % (1 << 256)).to_bytes(32, "big")
+    src = [ctypes.create_string_buffer(bytes(b), len(b)) for b in (bufs, infs, scs)]
+    base = [ctypes.addressof(b) for b in src]
+
+    def run(at, count):
+        out = (ctypes.c_uint8 * 192)()
+        inf = ctypes.c_uint8()
+        lib.bls_g2_msm(count, base[0] + 192 * at, base[1] + at, base[2] + 32 * at, out,
+                       ctypes.byref(inf))
+        return bytes(out), inf.value
+
+    sums = _over_threads("g2_msm", _slices("g2_msm", n), run)
+    if len(sums) == 1:
+        raw, inf = sums[0]
+        return None if inf else _g2_at(raw, 0)
     out = (ctypes.c_uint8 * 192)()
     inf = ctypes.c_uint8()
-    lib.bls_g2_msm(n, _buf(bytes(bufs)), _buf(bytes(infs)), _buf(bytes(scs)), out, ctypes.byref(inf))
+    lib.bls_g2_aggregate(len(sums), _buf(b"".join(raw for raw, _ in sums)),
+                         _buf(bytes(i for _, i in sums)), out, ctypes.byref(inf))
     return _g2_out(out, inf)
 
 
@@ -317,11 +417,18 @@ def fq2_sqrt(c0: int, c1: int) -> tuple[int, int] | None:
 # --- pairing ---------------------------------------------------------------
 
 
+_FP12_BYTES = 576
+
+
 def pairing_check_raw(pairs) -> bool:
     """pairs: list of (g1, g2) with g1 = (x, y) | None and
     g2 = ((x0, x1), (y0, y1)) | None."""
-    lib = get_bls_lib()
-    n = len(pairs)
+    return _pairing_check(_pairing_src(pairs), _slices("pairing", len(pairs)))
+
+
+def _pairing_src(pairs) -> list:
+    """The pairs as the core reads them: G1 points, G2 points, a flags
+    byte each (bit 0 the G1 point at infinity, bit 1 the G2 point)."""
     g1s = bytearray()
     g2s = bytearray()
     flags = bytearray()
@@ -331,9 +438,28 @@ def pairing_check_raw(pairs) -> bool:
         g1s += b1
         g2s += b2
         flags.append(i1 | (i2 << 1))
-    return bool(
-        lib.bls_pairing_check(n, _buf(bytes(g1s)), _buf(bytes(g2s)), _buf(bytes(flags)))
-    )
+    return [ctypes.create_string_buffer(bytes(b), len(b)) for b in (g1s, g2s, flags)]
+
+
+def _pairing_check(src: list, parts: list[tuple[int, int]]) -> bool:
+    """The check of the pairs in `src` (G1 points, G2 points, flags) over
+    the slices `parts`: ONE call where there is one slice; else each
+    slice's Miller value on a thread and their product through ONE final
+    exponentiation. A slice that cannot run fails the check CLOSED."""
+    lib = get_bls_lib()
+    g1, g2, flags = (ctypes.addressof(b) for b in src)
+    if len(parts) == 1:
+        return bool(_over_threads("pairing", parts, lambda at, count: lib.bls_pairing_check(
+            count, g1 + 96 * at, g2 + 192 * at, flags + at))[0])
+
+    def miller(at, count):
+        f = ctypes.create_string_buffer(_FP12_BYTES)
+        return lib.bls_pairing_miller(count, g1 + 96 * at, g2 + 192 * at, flags + at, f), f.raw
+
+    millers = _over_threads("pairing", parts, miller)
+    if not all(ok for ok, _ in millers):
+        return False
+    return bool(lib.bls_pairing_final_check(len(millers), b"".join(f for _, f in millers)))
 
 
 def g2_prepare_many(points) -> "np.ndarray | None":
@@ -421,6 +547,25 @@ def g2_map_from_fields(u0: tuple[int, int], u1: tuple[int, int]):
     if rc != 0:
         raise RuntimeError("bls_g2_map_from_fields called before set_params")
     return _g2_out(out, inf)
+
+
+def g2_map_from_fields_many(fields: list) -> list:
+    """:func:`g2_map_from_fields` of each (u0, u1), shared out over
+    threads; the parameters must have been sent before."""
+    lib = get_bls_lib()
+    n = len(fields)
+    data = b"".join(_b48(u0[0]) + _b48(u0[1]) + _b48(u1[0]) + _b48(u1[1]) for u0, u1 in fields)
+    src = ctypes.create_string_buffer(data, len(data))
+    out = ctypes.create_string_buffer(192 * n)
+    inf = ctypes.create_string_buffer(n)
+    base = [ctypes.addressof(b) for b in (src, out, inf)]
+    rcs = _over_threads("g2_map", _slices("g2_map", n), lambda at, count: (
+        lib.bls_g2_map_from_fields_many(count, base[0] + 192 * at, base[1] + 192 * at,
+                                        base[2] + at)))
+    if any(rcs):
+        raise RuntimeError("bls_g2_map_from_fields_many called before set_params")
+    raw, infs = out.raw, inf.raw
+    return [None if infs[k] else _g2_at(raw, 192 * k) for k in range(n)]
 
 
 def g2_decompress(data: bytes):

@@ -239,7 +239,7 @@ def get_bls_lib() -> ctypes.CDLL | None:
     lib.bls_g1_aggregate.argtypes = [c.c_uint64, u8p, u8p, u8p, u8p]
     lib.bls_g2_aggregate.argtypes = [c.c_uint64, u8p, u8p, u8p, u8p]
     lib.bls_g1_msm.argtypes = [c.c_uint64, u8p, u8p, u8p, u8p, u8p]
-    lib.bls_g2_msm.argtypes = [c.c_uint64, u8p, u8p, u8p, u8p, u8p]
+    lib.bls_g2_msm.argtypes = [c.c_uint64, c.c_void_p, c.c_void_p, c.c_void_p, u8p, u8p]
     lib.bls_g1_in_subgroup.argtypes = [u8p]
     lib.bls_g1_in_subgroup.restype = c.c_int
     lib.bls_g2_in_subgroup.argtypes = [u8p]
@@ -251,15 +251,23 @@ def get_bls_lib() -> ctypes.CDLL | None:
     lib.bls_g2_clear_cofactor.argtypes = [u8p, u8p, u8p]
     lib.bls_g2_decompress.argtypes = [u8p, u8p, u8p]
     lib.bls_g2_decompress.restype = c.c_int
+    lib.bls_g2_decompress_many.argtypes = [c.c_uint64, c.c_void_p, c.c_void_p, c.c_void_p]
+    lib.bls_g2_decompress_many.restype = None
     lib.bls_g2_map_set_params.argtypes = [u8p]
     lib.bls_g2_map_from_fields.argtypes = [u8p, u8p, u8p]
     lib.bls_g2_map_from_fields.restype = c.c_int
+    lib.bls_g2_map_from_fields_many.argtypes = [c.c_uint64, c.c_void_p, c.c_void_p, c.c_void_p]
+    lib.bls_g2_map_from_fields_many.restype = c.c_int
     lib.bls_g1_on_curve.argtypes = [u8p]
     lib.bls_g1_on_curve.restype = c.c_int
     lib.bls_g2_on_curve.argtypes = [u8p]
     lib.bls_g2_on_curve.restype = c.c_int
-    lib.bls_pairing_check.argtypes = [c.c_uint64, u8p, u8p, u8p]
+    lib.bls_pairing_check.argtypes = [c.c_uint64, c.c_void_p, c.c_void_p, c.c_void_p]
     lib.bls_pairing_check.restype = c.c_int
+    lib.bls_pairing_miller.argtypes = [c.c_uint64, c.c_void_p, c.c_void_p, c.c_void_p, c.c_void_p]
+    lib.bls_pairing_miller.restype = c.c_int
+    lib.bls_pairing_final_check.argtypes = [c.c_uint64, c.c_void_p]
+    lib.bls_pairing_final_check.restype = c.c_int
     lib.bls_g2_prepare_many.argtypes = [c.c_uint64, u8p, c.POINTER(c.c_uint64)]
     lib.bls_g2_prepare_many.restype = c.c_uint64
     lib.bls_pairing.argtypes = [u8p, u8p, u8p]

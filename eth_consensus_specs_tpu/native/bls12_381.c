@@ -1733,6 +1733,20 @@ int bls_g2_decompress(const uint8_t in[96], uint8_t out[192], uint8_t *out_inf) 
     return 1;
 }
 
+/* n compressed G2 points (96 bytes each) as bls_g2_decompress decides
+ * them: status[k] takes 1 for a point of the subgroup (its affine form in
+ * out), 2 for the point at infinity in its one encoding, 0 for bytes that
+ * are neither; every point is decided, so one bad signature costs its own
+ * item only. One call a slice of a flush's signatures. */
+void bls_g2_decompress_many(uint64_t n, const uint8_t *in, uint8_t *out, uint8_t *status) {
+    ensure_init();
+    for (uint64_t k = 0; k < n; k++) {
+        uint8_t inf = 0;
+        int ok = bls_g2_decompress(in + 96 * k, out + 192 * k, &inf);
+        status[k] = (uint8_t)(ok ? (inf ? 2 : 1) : 0);
+    }
+}
+
 /* --------------------- RFC 9380 G2 map stage (SSWU + 3-isogeny) ---------
  * The hash-to-field half (expand_message_xmd) stays in Python (hashlib's
  * C SHA-256 is already fast); this entry performs everything after it:
@@ -1904,6 +1918,19 @@ int bls_g2_map_from_fields(const uint8_t u_in[192], uint8_t out[192],
     return 0;
 }
 
+/* bls_g2_map_from_fields over n pairs of field elements laid end to end
+ * (192 bytes each): out takes a point each, out_inf a flag each. Returns
+ * -1 if the parameters were never set, 0 otherwise. One call a slice of
+ * a flush's new messages. */
+int bls_g2_map_from_fields_many(uint64_t n, const uint8_t *u_in, uint8_t *out,
+                                uint8_t *out_inf) {
+    ensure_init();
+    if (!map_params_set) return -1;
+    for (uint64_t k = 0; k < n; k++)
+        bls_g2_map_from_fields(u_in + 192 * k, out + 192 * k, out_inf + k);
+    return 0;
+}
+
 int bls_g1_on_curve(const uint8_t in[96]) {
     ensure_init();
     fp x, y, lhs, rhs, b;
@@ -1959,9 +1986,11 @@ static void fp2_batch_inv(fp2 *vals, fp2 *scratch, uint64_t m) {
     vals[0] = inv;
 }
 
-int bls_pairing_check(uint64_t n, const uint8_t *g1s, const uint8_t *g2s,
-                      const uint8_t *inf_flags) {
-    ensure_init();
+/* The shared-accumulator Miller walk over n pairs: f takes the product of
+ * their Miller values, before the x<0 conjugation. Returns 1, or 0 where
+ * a step degenerated or an allocation failed: its callers fail CLOSED. */
+static int miller_multi(fp12 *f, uint64_t n, const uint8_t *g1s, const uint8_t *g2s,
+                        const uint8_t *inf_flags) {
     mpair stack_pairs[16];
     fp2 stack_den[2 * 16];
     uint64_t stack_idx[16];
@@ -1987,11 +2016,10 @@ int bls_pairing_check(uint64_t n, const uint8_t *g1s, const uint8_t *g2s,
         m->q.inf = 0;
         m->t = m->q;
     }
-    fp12 f;
-    fp12_one(&f);
+    fp12_one(f);
     int degenerate = 0;
     for (int bit = 62; bit >= 0; bit--) {
-        fp12_sqr(&f, &f);
+        fp12_sqr(f, f);
         /* gather 2y denominators of the still-finite accumulators; a
          * y==0 accumulator (order-2 point, unreachable for subgroup
          * inputs) would poison the whole batch inversion — fail CLOSED */
@@ -2012,20 +2040,56 @@ int bls_pairing_check(uint64_t n, const uint8_t *g1s, const uint8_t *g2s,
             fp2_add(&num, &t3, &num); /* 3 x^2 */
             fp2_mul(&lam, &num, &den[j]);
             tx = p->t.x;
-            miller_apply(&f, &p->t, &lam, &tx, &p->px, &p->py);
+            miller_apply(f, &p->t, &lam, &tx, &p->px, &p->py);
         }
         if ((BLS_X_ABS >> bit) & 1) {
             for (uint64_t i = 0; i < live; i++) {
                 mpair *p = &pairs[i];
-                miller_step_add(&f, &p->t, &p->q, &p->px, &p->py);
+                miller_step_add(f, &p->t, &p->q, &p->px, &p->py);
             }
         }
     }
     if (pairs != stack_pairs) free(pairs);
     if (den != stack_den) free(den);
     if (idx != stack_idx) free(idx);
-    if (degenerate) return 0;
-    fp12 c;
+    return !degenerate;
+}
+
+int bls_pairing_check(uint64_t n, const uint8_t *g1s, const uint8_t *g2s,
+                      const uint8_t *inf_flags) {
+    ensure_init();
+    fp12 f, c;
+    if (!miller_multi(&f, n, g1s, g2s, inf_flags)) return 0;
+    fp12_conj(&c, &f);
+    return final_exp_is_one_fast(&c);
+}
+
+_Static_assert(sizeof(fp12) == 576, "an fp12 crosses the boundary as 576 bytes");
+
+/* One slice of a pairing check: the Miller value of its n pairs into out,
+ * the fp12 as the core holds it, for bls_pairing_final_check alone.
+ * Returns 0 where bls_pairing_check would fail CLOSED. */
+int bls_pairing_miller(uint64_t n, const uint8_t *g1s, const uint8_t *g2s,
+                       const uint8_t *inf_flags, uint8_t *out) {
+    ensure_init();
+    fp12 f;
+    int ok = miller_multi(&f, n, g1s, g2s, inf_flags);
+    memcpy(out, &f, sizeof f);
+    return ok;
+}
+
+/* The check over k slices' Miller values laid end to end: their product
+ * is the Miller value of all their pairs (the walk's squarings and lines
+ * multiply through), so this is bls_pairing_check's verdict over the
+ * pairs of every slice, with ONE final exponentiation. */
+int bls_pairing_final_check(uint64_t k, const uint8_t *fs) {
+    ensure_init();
+    fp12 f, t, c;
+    fp12_one(&f);
+    for (uint64_t i = 0; i < k; i++) {
+        memcpy(&t, fs + sizeof t * i, sizeof t);
+        fp12_mul(&f, &f, &t);
+    }
     fp12_conj(&c, &f);
     return final_exp_is_one_fast(&c);
 }

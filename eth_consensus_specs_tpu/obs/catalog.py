@@ -240,6 +240,8 @@ CATALOG: tuple[Metric, ...] = (
        "leg: one key of precompile(), per op; an op's own leg further in keeps its compiles"),
     _s("native.load",
        "a C core's shared object found fresh by its digest, or built with cc (core=)"),
+    _c("native.slices.*",
+       "slices a C-core call over many items ran on, one C call a thread; 1 a call on one"),
     # ---------------------------------------------------------------- hbm --
     _g("hbm.resident_bytes.*", "ledger-registered device bytes per owner"),
     _g("hbm.resident_bytes_total", "ledger-registered device bytes, all owners"),

@@ -39,9 +39,9 @@ from eth_consensus_specs_tpu.crypto.curve import (
     Point,
     g1_generator,
     g1_infinity,
-    g2_from_bytes,
+    g2_from_bytes_many,
 )
-from eth_consensus_specs_tpu.crypto.hash_to_curve import DST_G2, hash_to_g2
+from eth_consensus_specs_tpu.crypto.hash_to_curve import DST_G2, hash_to_g2, hash_to_g2_many
 from eth_consensus_specs_tpu.crypto.pairing import pairing_check
 from eth_consensus_specs_tpu.obs import watchdog, waterfall
 
@@ -91,8 +91,8 @@ def _prime_h2g2_cache(msgs: list[bytes], batch_fn, dst: bytes = DST_G2) -> None:
         fresh = [m for m in msgs if (dst, m) not in _H2G2_CACHE]
     if not fresh:
         return
-    # the batched dispatch runs OUTSIDE the lock (it can be slow; two
-    # racing primes at worst both compute — idempotent, never corrupt)
+    # the batched map runs OUTSIDE the lock (it can be slow; two racing
+    # primes at worst both compute — idempotent, never corrupt)
     points = batch_fn(fresh, dst)
     with _H2G2_LOCK:
         # re-check the bound at insert time: N racing primes could each
@@ -188,14 +188,12 @@ def batch_verify_aggregates(
     return ok
 
 
-def _parse_item(item: tuple, keys=None):
-    """(signers, message, signature) -> (signers, msg, sig, r), or None on
-    any malformed/empty input. The signers come as 48-byte public keys
-    or as an array of registry indices; with the registry's key table
-    (``ops/key_table.KeyTable``) both resolve to an index array, without
-    one the keys are decoded to points (cached: ``signature._load_pk``
-    refuses malformed AND infinity keys)."""
-    pks, msg, sig_b = item
+def _signers(pks, keys=None):
+    """An item's signers, or None where they are empty or malformed. They
+    come as 48-byte public keys or as an array of registry indices; with
+    the registry's key table (``ops/key_table.KeyTable``) both resolve to
+    an index array, without one the keys are decoded to points (cached:
+    ``signature._load_pk`` refuses malformed AND infinity keys)."""
     if len(pks) == 0:
         return None
     signers = keys.resolve(pks) if keys is not None else None
@@ -203,14 +201,7 @@ def _parse_item(item: tuple, keys=None):
         if isinstance(pks, np.ndarray):
             return None  # indices into a registry this caller was not given
         signers = _decode_keys(pks)
-        if signers is None:
-            return None
-    try:
-        sig = g2_from_bytes(bytes(sig_b))
-    except ValueError:
-        return None
-    r = secrets.randbits(64) | 1
-    return (signers, bytes(msg), sig, r)
+    return signers
 
 
 def _decode_keys(pks: list) -> list | None:
@@ -248,8 +239,19 @@ def warm_keys(items: list) -> None:
 
 
 def _parse_flush(items: list, keys=None) -> list:
+    """(signers, message, signature) triples -> (signers, msg, sig, r), or
+    None for an item with malformed or empty input: signers, message and
+    the draw of r on this thread, the signatures of the items left in ONE
+    batched decompression."""
     with _decode_clock():
-        return [_parse_item(it, keys) for it in items]
+        signers = [_signers(pks, keys) for pks, _, _ in items]
+    live = [i for i, s in enumerate(signers) if s is not None]
+    sigs = g2_from_bytes_many([bytes(items[i][2]) for i in live])
+    parsed = [None] * len(items)
+    for i, sig in zip(live, sigs):
+        if sig is not None:
+            parsed[i] = (signers[i], bytes(items[i][1]), sig, secrets.randbits(64) | 1)
+    return parsed
 
 
 def _signer_points(signers, keys) -> list:
@@ -260,12 +262,9 @@ def _batch_verify_impl(
     items: list[tuple[list[bytes], bytes, bytes]],
     mesh=None,
 ) -> tuple[bool, list | None]:
-    parsed = []
-    for item in items:
-        p = _parse_item(item)
-        if p is None:
-            return False, None
-        parsed.append(p)
+    parsed = _parse_flush(items)
+    if any(p is None for p in parsed):
+        return False, None
     rpk = _rlc_pubkey_terms(parsed, mesh=mesh)
     pairing = functools.partial(_pairing_check_routed, mesh=mesh)
     return _rlc_check(parsed, rpk, pairing), parsed
@@ -345,7 +344,11 @@ def _rlc_pubkey_terms(parsed: list, mesh=None) -> list:
 # behind it for minutes; off it, a caller who handed a mesh asked for the
 # devices and waits for the compile itself. Hash-to-G2 and the pairing
 # have device programs that lose at a block's shape (or cannot be built on
-# the chip's host) and the G2 fold has none: those legs take the core.
+# the chip's host) and the G2 fold has none: those legs take the core,
+# which shares each leg's items out over the host's cores
+# (`crypto/native_bridge._over_threads`): the signatures' decompression,
+# the new messages' map stage, the fold's terms and the pairing's Miller
+# loops, a slice a C call, with ONE final exponentiation.
 
 
 def _takes_device(key: tuple, mesh) -> bool:
@@ -415,10 +418,6 @@ def _served_pubkey_terms(parsed: list, keys=None, mesh=None) -> list:
         return [s.mul(p[3]) for s, p in zip(sums, parsed)]
 
 
-def _host_hash_many(msgs: list[bytes], dst: bytes) -> list:
-    return [hash_to_g2(m, dst) for m in msgs]
-
-
 def _merge_by_message(parsed: list, rpk: list) -> dict:
     """Same-message items merged into one pairing input (block
     attestations often share AttestationData): k items with m distinct
@@ -430,8 +429,9 @@ def _merge_by_message(parsed: list, rpk: list) -> dict:
 
 
 def _fold_signatures(parsed: list):
-    """sum_i r_i * sig_i in ONE native Pippenger MSM (64-bit scalars are
-    always < r, so the reduced path is exact); multi_exp falls back to
+    """sum_i r_i * sig_i by the native Pippenger MSM, a sum a slice of the
+    terms on the host's cores and the slices' sums added (64-bit scalars
+    are always < r, so the reduced path is exact); multi_exp falls back to
     the bit-exact per-point path without the native core."""
     from eth_consensus_specs_tpu.utils.bls import multi_exp
 
@@ -449,7 +449,7 @@ def _rlc_check(parsed: list, rpk: list, pairing) -> bool:
     with waterfall.leg("bls.h2c"):
         # kept for the subsets a bisection checks again and for the block
         # after this one
-        _prime_h2g2_cache(list(merged), _host_hash_many)
+        _prime_h2g2_cache(list(merged), hash_to_g2_many)
         pairs = [(rp, _h2g2(msg)) for msg, rp in merged.items()]
     with waterfall.leg("bls.g2_fold"):
         pairs.append((-g1_generator(), _fold_signatures(parsed)))

@@ -8,6 +8,11 @@ load included), then the least of `--repeat` calls.
     python scripts/bls_legs_chip.py [--items 128] [--lanes 512] [--keys 1048576]
         [--strips 64] [--skip h2c] [--budget-s 1200]
 
+The C core's legs (signature decompression, the G2 fold, hash-to-G2, the
+pairing) print twice: as ONE call, then sliced over the host's cores as
+the served path runs them, with the slices and the threads used. `--strips ""`
+leaves out the device sums.
+
 The device hash-to-G2 is skipped unless `--skip ""` is given: compiling it met
 the 40 GiB of a one-chip machine's host and the call was killed (PR 27). The
 device pairing takes ~12 minutes to compile the first time.
@@ -64,9 +69,9 @@ def main(argv: list[str] | None = None) -> int:
 
     from eth_consensus_specs_tpu.crypto import native_bridge as nb
     from eth_consensus_specs_tpu.crypto.curve import (
-        g1_generator, g1_to_bytes, g2_from_bytes, g2_generator, g2_to_bytes,
+        g1_generator, g1_to_bytes, g2_from_bytes_many, g2_generator, g2_to_bytes,
     )
-    from eth_consensus_specs_tpu.crypto.hash_to_curve import hash_to_g2
+    from eth_consensus_specs_tpu.crypto.hash_to_curve import hash_to_g2_many
     from eth_consensus_specs_tpu.crypto.pairing import pairing_check
     from eth_consensus_specs_tpu.ops import g1_msm
     from eth_consensus_specs_tpu.ops.key_table import KeyTable
@@ -116,7 +121,8 @@ def main(argv: list[str] | None = None) -> int:
     index = np.full((args.items, args.lanes), -1, np.int32)
     for i, r in enumerate(rows):
         index[i, : len(r)] = r
-    for strip in (int(s) for s in args.strips.split(",")):
+    got = None
+    for strip in (int(s) for s in filter(None, args.strips.split(","))):
         if not in_budget(f"g1_sum strip {strip}"):
             continue
 
@@ -130,23 +136,42 @@ def main(argv: list[str] | None = None) -> int:
                     for q, w in zip(points, want))
         emit("g1_sum", f"device, strip {strip}", first, best, equal=equal)
 
-    def unpack():
-        return [p.mul(k) for p, k in zip(g1_msm._jacobian_to_points(*got), scalars)]
-
     scalars = [secrets.randbits(64) | 1 for _ in range(args.items)]
-    terms, first, best = timed(unpack, args.repeat)
-    emit("g1_sum.unpack", "host: to affine, 64-bit multiply in the C core", first, best)
+    if got is not None:
+        def unpack():
+            return [p.mul(k) for p, k in zip(g1_msm._jacobian_to_points(*got), scalars)]
 
-    # ---- signatures, messages
+        terms, first, best = timed(unpack, args.repeat)
+        emit("g1_sum.unpack", "host: to affine, 64-bit multiply in the C core", first, best)
+    else:
+        from eth_consensus_specs_tpu.crypto.curve import B1, Point
+        from eth_consensus_specs_tpu.crypto.fields import Fq
+
+        terms = [Point(Fq(w[0]), Fq(w[1]), B1).mul(k) for w, k in zip(want, scalars)]
+
+    # ---- the C core's legs over the flush's items: one call, then sliced
+    # over the host's cores as served
+    cores = nb._cpus()
+
+    def both_ways(leg: str, op: str, fn, n: int, **more):
+        nb._cpus = lambda: 1
+        try:
+            want, first, best = timed(fn, args.repeat)
+        finally:
+            nb._cpus = lambda: cores
+        emit(leg, "C core, one call", first, best, **more)
+        got, first, best = timed(fn, args.repeat)
+        emit(leg, f"C core, {len(nb._slices(op, n))} slices", first, best, threads=cores,
+             equal=got == want, **more)
+        return got
+
     g2 = g2_generator()
     sig_bytes = [g2_to_bytes(g2.mul(secrets.randbits(250) | 1)) for _ in range(args.items)]
-    sigs, first, best = timed(lambda: [g2_from_bytes(b) for b in sig_bytes], args.repeat)
-    emit("sig_decompress", "C core", first, best)
-    _, first, best = timed(lambda: multi_exp(sigs, scalars), args.repeat)
-    emit("g2_fold", "C core (Pippenger); the device has no G2 MSM", first, best)
+    sigs = both_ways("sig_decompress", "g2_decompress", lambda: g2_from_bytes_many(sig_bytes),
+                     args.items)
+    fold = both_ways("g2_fold", "g2_msm", lambda: multi_exp(sigs, scalars), args.items)
     msgs = [secrets.token_bytes(32) for _ in range(args.items)]
-    hashed, first, best = timed(lambda: [hash_to_g2(m) for m in msgs], args.repeat)
-    emit("h2c", "C core", first, best)
+    hashed = both_ways("h2c", "g2_map", lambda: hash_to_g2_many(msgs), args.items)
     if "h2c" not in skip and in_budget("h2c device"):
         from eth_consensus_specs_tpu.ops.h2c_device import hash_to_g2_device
 
@@ -154,9 +179,9 @@ def main(argv: list[str] | None = None) -> int:
         emit("h2c", "device", first, best, equal=got_h == hashed)
 
     # ---- the RLC pairing: items + 1 pairs
-    pairs = [(t, h) for t, h in zip(terms, hashed)] + [(-g, multi_exp(sigs, scalars))]
-    want_ok, first, best = timed(lambda: pairing_check(pairs), args.repeat)
-    emit("pairing", "C core", first, best, pairs=len(pairs))
+    pairs = [(t, h) for t, h in zip(terms, hashed)] + [(-g, fold)]
+    want_ok = both_ways("pairing", "pairing", lambda: pairing_check(pairs), len(pairs),
+                        pairs=len(pairs))
     if "pairing" not in skip and in_budget("pairing device"):
         from eth_consensus_specs_tpu.ops.pairing_device import pairing_check_device
 
