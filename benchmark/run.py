@@ -43,6 +43,8 @@ HERE = os.path.join(ROOT, "benchmark")
 # through, the device path did not
 FALLBACK_COUNTERS = ("serve.degraded_items", "slot.forest_rebuilds", "fault.degraded")
 TRACE_DIR = os.path.join(ROOT, ".bench_trace")
+# profiler sessions a traced run may take before it gives up on the device
+TRACE_ATTEMPTS = 3
 
 
 class BenchError(Exception):
@@ -112,6 +114,7 @@ class Window:
     hist_after: dict = field(default_factory=dict)
     compiles: dict = field(default_factory=dict)
     trace: xplane.Trace | None = None
+    traced_from: int = 0  # the first request the trace holds
 
     @property
     def completed(self) -> int:
@@ -131,15 +134,38 @@ def _fallbacks(counters: dict) -> int:
     ))
 
 
-def stop_trace(window: Window, t0: float) -> tuple[float, int]:
-    """(seconds, requests) of the traced part of the window, the trace written."""
+def traces_a_device() -> bool:
+    """Whether the profiler's trace has a device plane at all: the CPU's has
+    none, so there a second session could find nothing the first missed."""
     import jax
 
-    traced = (window.seconds, window.completed)
+    return jax.default_backend() != "cpu"
+
+
+def start_trace() -> None:
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    # the limb programs' HLO is tens of MB: copied into the trace it makes
+    # stop_trace take minutes, and no reader here needs it
+    options.enable_hlo_proto = False
+    shutil.rmtree(TRACE_DIR, ignore_errors=True)  # one trace on disk, at a fixed path
+    jax.profiler.start_trace(TRACE_DIR, profiler_options=options)
+
+
+def stop_trace(window: Window, since: tuple[float, int]) -> xplane.Trace:
+    """The trace of the window's requests from `since` (seconds, requests
+    completed) on, written and read back."""
+    import jax
+
+    t_stop = time.perf_counter()
     jax.profiler.stop_trace()
-    print(f"trace: stopped and written in {time.perf_counter() - t0 - window.seconds:.1f} s",
-          file=sys.stderr)
-    return traced
+    t_read = time.perf_counter()
+    trace = xplane.read_xplane(TRACE_DIR, window.seconds - since[0], window.completed - since[1])
+    print(f"trace: stopped and written in {t_read - t_stop:.1f} s, "
+          f"read in {time.perf_counter() - t_read:.1f} s", file=sys.stderr)
+    return trace
 
 
 def measure(svc, traffic, window: Window, seconds: float, log: CompileLog,
@@ -147,24 +173,20 @@ def measure(svc, traffic, window: Window, seconds: float, log: CompileLog,
     """The closed loop: one client, the next request when the last one's
     verdict is back. The window closes when the last request begun inside
     `seconds` resolves. With `trace_requests` the profiler runs over the
-    first so many requests."""
-    import jax
-
+    first so many requests; the seconds it takes to stop and to be read are
+    not counted. A trace in which no device ran an operation (the profiler
+    now and then misses a short request's device) is taken again over the
+    next so many requests, up to TRACE_ATTEMPTS times."""
     from eth_consensus_specs_tpu import obs
 
     before = obs.snapshot()
     window.hist_before = before.get("histograms", {})
     compiled_before = log.mark()
-    tracing = trace_requests > 0
+    attempts = (TRACE_ATTEMPTS if traces_a_device() else 1) if trace_requests > 0 else 0
+    tracing = attempts > 0
     if tracing:
-        options = jax.profiler.ProfileOptions()
-        options.python_tracer_level = 0
-        # the limb programs' HLO is tens of MB: copied into the trace it makes
-        # stop_trace take minutes, and no reader here needs it
-        options.enable_hlo_proto = False
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)  # one trace on disk, at a fixed path
-        jax.profiler.start_trace(TRACE_DIR, profiler_options=options)
-    traced = None  # (seconds, requests) of the traced part
+        start_trace()
+    since = (0.0, 0)  # (seconds, requests) of the window where the trace began
     prepared = getattr(traffic, "prepared", None)  # requests a driver can make at all
     t0 = time.perf_counter()
     while True:
@@ -180,14 +202,21 @@ def measure(svc, traffic, window: Window, seconds: float, log: CompileLog,
         else:
             window.latencies_ms.append((time.perf_counter() - t1) * 1e3)
         window.seconds = time.perf_counter() - t0
-        if tracing and window.completed >= trace_requests:
-            traced, tracing = stop_trace(window, t0), False
+        if tracing and window.completed - since[1] >= trace_requests:
+            t_stop = time.perf_counter()
+            window.trace, window.traced_from = stop_trace(window, since), since[1]
+            attempts -= 1
+            tracing = not window.trace.modules and attempts > 0
+            if tracing:
+                print(f"trace: no device ran an operation in requests {since[1]} to "
+                      f"{window.completed - 1}; tracing the next {trace_requests}", file=sys.stderr)
+                start_trace()
+                since = (window.seconds, window.completed)
+            # writing and reading the trace is no request's time: the window
+            # goes on as long as an untraced one, over the same requests
+            t0 += time.perf_counter() - t_stop
     if tracing:  # the window closed before that many requests
-        traced = stop_trace(window, t0)
-    if traced is not None:
-        t_read = time.perf_counter()
-        window.trace = xplane.read_xplane(TRACE_DIR, *traced)
-        print(f"trace: read in {time.perf_counter() - t_read:.1f} s", file=sys.stderr)
+        window.trace, window.traced_from = stop_trace(window, since), since[1]
     after = obs.snapshot()
     window.hist_after = after.get("histograms", {})
     window.compiles = CompileLog.since(compiled_before, log.mark())
@@ -245,6 +274,7 @@ def result_line(window: Window, traced: bool, compared: dict, device: dict) -> d
         }
     line["window"] = {"seconds": window.seconds, "completed": window.completed,
                       **window.compiles,
+                      **({"traced_from": window.traced_from} if window.trace is not None else {}),
                       "request_ms": [round(ms, 1) for ms in window.latencies_ms]}
     line["compared"] = {k: {"value": v, "limit": lim} for k, (v, lim) in compared.items()}
     return line

@@ -9,10 +9,13 @@ disjoint committees. Block `b` carries the committees of slots `b` and
 in `slots` blocks. Each member signs with probability `participation`, the
 first always; a committee's message is sha256 of (seed, draw, slot,
 committee); its aggregate signature is (sum of the signers' secret keys) *
-H(message). Block `invalid_first` of the window, and no other, carries one
-wrong aggregate in EACH half of its flush, the place from the seed and
-never a half's first (every seed then costs the bisection the same count
-of checks): in the first half the signature of the other wrong place's
+H(message). Block `invalid_first` of the window (and, where the traffic
+file gives `invalid_every`, every so-many-th after it: the same mix of
+one bisecting block in so many over a longer window) carries one wrong
+aggregate in EACH half of its flush, the places drawn from the seed and
+the block's number and never a half's first (every seed and every such
+block then costs the bisection the same count of checks): in the first
+half the signature of the other wrong place's
 aggregate (a well-formed point that signs something else), in the second
 a signer's key left out (the signature is right for a set
 one larger: the committee sum has to notice). Without them every right
@@ -35,6 +38,7 @@ import time
 import numpy as np
 
 from benchmark.reference import bls_ref as ref
+from benchmark.traffic import bisecting
 
 
 class Traffic:
@@ -48,6 +52,7 @@ class Traffic:
         self.warmup_keys = [tuple(key) for key in config.get("warmup_keys", [])]
         self.participation = float(params["participation"])
         self.invalid_first = int(params["invalid_first"])
+        self.invalid_every = int(params.get("invalid_every", 0))  # 0: that one block alone
         self.prepared = int(params["blocks_prepared"])  # the window's blocks: run.py stops there
         self.timeout = float(params.get("timeout_s", 600))
         self.seed = seed
@@ -90,7 +95,7 @@ class Traffic:
             self.aggregate(int(warmup), (number + s) % self.slots, c)
             for s in range(self.spans) for c in range(self.committees)
         ]
-        if number == self.invalid_first:
+        if self.carries_wrong_aggregates(number):
             first, second = self.wrong_places(number)
             signers, message, _ = items[first]
             other = self.aggregate(int(warmup), (number + self.spans) % self.slots, 0)
@@ -99,6 +104,9 @@ class Traffic:
                 signers, message, signature = items[second]
                 items[second] = (signers[:-1], message, signature)
         return items
+
+    def carries_wrong_aggregates(self, number: int) -> bool:
+        return bisecting(number, self.invalid_first, self.invalid_every)
 
     def wrong_places(self, number: int) -> tuple[int, int]:
         """One place in each half of the flush, from the seed, not a half's first."""
@@ -153,12 +161,16 @@ class Traffic:
         print(f"a block's submits: median {took[len(took) // 2]:.3f} ms, "
               f"longest {took[-1]:.3f} ms; flushes of the window's {len(took)} blocks, by reason: {self.flushes()}",
               file=sys.stderr)
+        # an aggregate recurs every `slots` blocks: its keys are looked up once
+        memo: dict[tuple, tuple[bool, bool | None]] = {}
         wrong = 0
         for number, verdicts in self.answers:
             for (signers, message, signature), verdict in zip(self.block(number), verdicts):
-                pubkeys = [self.known.pubkeys[v] for v in signers]
-                want = ref.fast_aggregate_verify(self.known, pubkeys, message, signature)
-                if judge is not None:
-                    verdict = judge(self.known, pubkeys, message, signature)
-                wrong += verdict != want
+                key = (signers.tobytes(), message, signature)
+                if key not in memo:
+                    pubkeys = [self.known.pubkeys[v] for v in signers]
+                    memo[key] = (ref.fast_aggregate_verify(self.known, pubkeys, message, signature),
+                                 None if judge is None else judge(self.known, pubkeys, message, signature))
+                want, lazy = memo[key]
+                wrong += (verdict if judge is None else lazy) != want
         return {"verdicts_wrong": (wrong, 0)}

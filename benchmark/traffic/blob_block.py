@@ -21,6 +21,7 @@ from __future__ import annotations
 import numpy as np
 
 from benchmark.reference import kzg_ref as ref
+from benchmark.traffic import bisecting
 
 
 class Traffic:
@@ -52,8 +53,7 @@ class Traffic:
         return sidecars
 
     def carries_wrong_proofs(self, number: int) -> bool:
-        since = number - self.invalid_first
-        return since == 0 or (since > 0 and self.invalid_every > 0 and since % self.invalid_every == 0)
+        return bisecting(number, self.invalid_first, self.invalid_every)
 
     def wrong_places(self, number: int) -> tuple[int, int]:
         """One place in each half of the flush, from the seed, not a half's first."""

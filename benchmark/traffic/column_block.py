@@ -11,14 +11,16 @@ one a column (all 128 for a supernode, in an order the seed shuffles, as
 gossip does not sort them; fewer are the first so many of that order):
 sidecar `c` is the block's cells and proofs of column `c` and its
 commitments, which all of a block's sidecars share. Block
-`invalid_first` of the window, and no other, carries ONE cell with a
+`invalid_first` of the window (and, where the traffic file gives
+`invalid_every`, every so-many-th after it: the same mix of one
+bisecting block in so many over a longer window) carries ONE cell with a
 wrong proof in one sidecar of EACH half of its flush: the same blob's
 proof for the next column, a well-formed point that opens another cell.
 Without them every right answer is True, and a verifier that checks
 nothing, or nothing in one half of a flush, would compare as correct.
-The seed draws the sidecar in each half, never the half's first, and the
-cell in it: every seed then costs the bisection the same count of checks
-(27 at 128 sidecars).
+The seed and the block's number draw the sidecar in each half, never
+the half's first, and the cell in it: every seed and every such block
+then costs the bisection the same count of checks (27 at 128 sidecars).
 
 The window is a FIXED count of blocks (`blocks_prepared`), so every run
 of a commit measures the same blocks. Set-up has the service compile the
@@ -35,6 +37,7 @@ import time
 import numpy as np
 
 from benchmark.reference import das_ref as ref
+from benchmark.traffic import bisecting
 
 
 class Traffic:
@@ -44,6 +47,7 @@ class Traffic:
         self.warmup_keys = [tuple(key) for key in config.get("warmup_keys", [])]
         self.pool_size = int(params["blob_pool"])
         self.invalid_first = int(params["invalid_first"])
+        self.invalid_every = int(params.get("invalid_every", 0))  # 0: that one block alone
         self.prepared = int(params["blocks_prepared"])  # the window's blocks: run.py stops there
         self.timeout = float(params.get("timeout_s", 600))
         self.seed = seed
@@ -73,13 +77,16 @@ class Traffic:
             )
             for col in order
         ]
-        if number == self.invalid_first:
+        if self.carries_wrong_proofs(number):
             for at, row in self.wrong_places(number):
                 col, column, _, proofs = sidecars[at]
                 other = blobs[row][2][(col + 1) % ref.NUMBER_OF_COLUMNS]
                 sidecars[at] = (col, column, commitments,
                                 proofs[:row] + (other,) + proofs[row + 1 :])
         return sidecars
+
+    def carries_wrong_proofs(self, number: int) -> bool:
+        return bisecting(number, self.invalid_first, self.invalid_every)
 
     def wrong_places(self, number: int) -> list[tuple[int, int]]:
         """(sidecar, cell) of one wrong proof in each half of the flush,

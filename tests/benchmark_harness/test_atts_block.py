@@ -4,18 +4,23 @@ answers compare as correct, the control and every planted fault as not."""
 
 from __future__ import annotations
 
+import hashlib
 import os
 import time
 from types import SimpleNamespace
 
+import numpy as np
 import pytest
 
 from benchmark import faults_bls, needed_bls, run
 from benchmark.compile_log import CompileLog
 from benchmark.reducers import needed_roofline_pct
+from benchmark.reference import bls_ref
+from benchmark.traffic import atts_block
 
 CELL = "block_atts_128.verify"
 BLOCKS = 3
+EVERY = 2  # a bisecting block every so many: block 0, EVERY and 2 x EVERY of 2 x EVERY + 1
 
 
 def tiny_cell() -> run.Cell:
@@ -71,6 +76,60 @@ def test_the_same_seed_makes_the_same_blocks_and_another_seed_others(driven):
     # half a block's committees are the block before's, the registry cycles
     assert same(traffic.block(3)[:2], traffic.block(2)[2:])
     assert traffic.slots == 8 and same(traffic.block(8), traffic.block(0))
+
+
+@pytest.fixture(scope="module")
+def driven_every():
+    cell = tiny_cell()
+    cell.traffic["params"].update(blocks_prepared=2 * EVERY + 1, invalid_first=0, invalid_every=EVERY)
+    return run.drive(cell, 2147483905, 60.0, False, "cpu", CompileLog().install(),
+                     time.perf_counter())
+
+
+def test_invalid_every_repeats_the_bisecting_block_and_each_wrong_aggregate_is_refused_alone(
+        driven_every):
+    window, traffic, _ = driven_every
+    blocks = 2 * EVERY + 1
+    assert (window.attempted, window.completed, window.failed) == (blocks, blocks, 0)
+    bisecting = [n for n in range(blocks) if traffic.carries_wrong_aggregates(n)]
+    assert bisecting == [0, EVERY, 2 * EVERY]
+    for number, verdicts in traffic.answers:
+        wrong = traffic.wrong_places(number) if number in bisecting else ()
+        assert verdicts == tuple(i not in wrong for i in range(4))
+        for i, (signers, message, signature) in enumerate(traffic.block(number)):
+            keys = [traffic.known.pubkeys[v] for v in signers]
+            assert bls_ref.fast_aggregate_verify(traffic.known, keys, message, signature) == (i not in wrong)
+    assert run.compare(window, traffic) == {"verdicts_wrong": (0, 0), "unanswered": (0, 0)}
+    # the control accepts all six wrong aggregates
+    compared = run.compare(window, traffic, control=True)
+    assert compared["verdicts_wrong"] == (6, 0) and not run.is_correct(compared)
+
+
+def digest(traffic, blocks: int) -> str:
+    h = hashlib.sha256()
+    for warmup in (False, True):
+        for number in range(blocks):
+            for signers, message, signature in traffic.block(number, warmup=warmup):
+                h.update(signers.astype("<i4").tobytes() + message + signature)
+    return h.hexdigest()
+
+
+def test_without_invalid_every_the_blocks_are_those_of_one_bisecting_block():
+    cell = tiny_cell()
+    params = dict(cell.traffic["params"], blocks_prepared=10)
+    params.pop("invalid_every", None)
+    alone = atts_block.Traffic(cell.config, params, 2147483904)
+    alone.make_inputs()
+    # blocks 0-9 and their warm-up draws as the driver made them before `invalid_every`
+    assert digest(alone, 10) == "439eb6b4e1544edda9d8204fbfbc60ac109500c94eb45e174c239de51cbfbeb7"
+    assert [n for n in range(10) if alone.carries_wrong_aggregates(n)] == [1]
+    # with it, the blocks between the bisecting ones are the same blocks
+    every = atts_block.Traffic(cell.config, dict(params, invalid_every=4), 2147483904)
+    every.make_inputs()
+    for number in range(10):
+        same = all(np.array_equal(x[0], y[0]) and x[1:] == y[1:]
+                   for x, y in zip(every.block(number), alone.block(number)))
+        assert same == (number not in (5, 9))
 
 
 @pytest.mark.parametrize("fault", sorted(faults_bls.FAULTS))

@@ -4,6 +4,7 @@ as correct, the control and every planted fault as not."""
 
 from __future__ import annotations
 
+import hashlib
 import time
 from types import SimpleNamespace
 
@@ -12,10 +13,12 @@ import pytest
 from benchmark import faults_das, needed_das, run
 from benchmark.compile_log import CompileLog
 from benchmark.reducers import needed_roofline_pct
+from benchmark.reference import das_ref as ref
 from benchmark.traffic import column_block
 
 CELL = "peerdas_block_21.verify"
 BLOCKS = 3
+EVERY = 2  # a bisecting block every so many: block 0, EVERY and 2 x EVERY of 2 x EVERY + 1
 LEGS = ["das_fold_ms", "das_fft_pack_ms", "das_fft_call_ms", "das_fft_unpack_ms",
         "das_interp_fold_ms", "das_msm_pack_ms", "das_msm_call_ms", "das_msm_unpack_ms",
         "das_check_ms", "dispatch_other_ms"]
@@ -78,6 +81,57 @@ def test_the_same_seed_makes_the_same_blocks_and_another_seed_others(driven):
     col = traffic.block(1)[at][0]
     blobs = {commitment: proofs for commitment, _, proofs in traffic.pool}
     assert traffic.block(1)[at][3][row] == blobs[traffic.block(1)[at][2][row]][(col + 1) % 128]
+
+
+@pytest.fixture(scope="module")
+def driven_every():
+    cell = tiny_cell()
+    cell.traffic["params"].update(blocks_prepared=2 * EVERY + 1, invalid_first=0, invalid_every=EVERY)
+    return run.drive(cell, 2147484905, 120.0, False, "cpu", CompileLog().install(),
+                     time.perf_counter())
+
+
+def test_invalid_every_repeats_the_bisecting_block_and_each_wrong_sidecar_is_refused_alone(
+        driven_every):
+    window, traffic, _ = driven_every
+    blocks = 2 * EVERY + 1
+    assert (window.attempted, window.completed, window.failed) == (blocks, blocks, 0)
+    bisecting = [n for n in range(blocks) if traffic.carries_wrong_proofs(n)]
+    assert bisecting == [0, EVERY, 2 * EVERY]
+    judge = ref.Judge()
+    for number, verdicts in traffic.answers:
+        wrong = [at for at, _ in traffic.wrong_places(number)] if number in bisecting else []
+        assert verdicts == tuple(i not in wrong for i in range(4))
+        assert tuple(map(judge.verify_sidecar, traffic.block(number))) == verdicts
+    assert run.compare(window, traffic) == {"verdicts_wrong": (0, 0), "unanswered": (0, 0)}
+    # the control accepts all six wrong sidecars
+    compared = run.compare(window, traffic, control=True)
+    assert compared["verdicts_wrong"] == (6, 0) and not run.is_correct(compared)
+
+
+def digest(traffic, blocks: int) -> str:
+    h = hashlib.sha256()
+    for warmup in (False, True):
+        for number in range(blocks):
+            for col, cells, commitments, proofs in traffic.block(number, warmup=warmup):
+                h.update(col.to_bytes(2, "little") + b"".join(cells) + b"".join(commitments)
+                         + b"".join(proofs))
+    return h.hexdigest()
+
+
+def test_without_invalid_every_the_blocks_are_those_of_one_bisecting_block():
+    cell = tiny_cell()
+    params = dict(cell.traffic["params"], blocks_prepared=4)
+    params.pop("invalid_every", None)
+    alone = column_block.Traffic(cell.config, params, 2147484904)
+    alone.make_inputs()
+    # blocks 0-3 and their warm-up draws as the driver made them before `invalid_every`
+    assert digest(alone, 4) == "70f08857d5a9c39f948cd7973c586284d2940b237b515f50bed3e54dc632745e"
+    assert [n for n in range(4) if alone.carries_wrong_proofs(n)] == [1]
+    # with it, the blocks between the bisecting ones are the same blocks
+    every = column_block.Traffic(cell.config, dict(params, invalid_every=2), 2147484904)
+    every.pool = alone.pool
+    assert [every.block(n) == alone.block(n) for n in range(4)] == [True, True, True, False]
 
 
 @pytest.mark.parametrize("fault", sorted(faults_das.FAULTS))

@@ -144,8 +144,11 @@ def test_every_new_metric_has_its_file_its_reader_and_cells_that_report_what_it_
     manifest = run.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     entries = {m["name"]: m for m in manifest["per_layer"]}
     assert NEW <= set(entries)
-    assert [m["name"] for m in manifest["per_layer"]][-len(NEW):] == [
-        m["name"] for m in manifest["per_layer"] if m["name"] in NEW]  # appended, nothing moved
+    # appended as one run behind the metrics before them, nothing moved in
+    # between; later cells' metrics follow them
+    names = [m["name"] for m in manifest["per_layer"]]
+    at = [i for i, name in enumerate(names) if name in NEW]
+    assert at == list(range(at[0], at[0] + len(NEW))) and names[at[0] - 1] == "device_idle_pct"
     for name in NEW:
         spec = run.load_metric(name)
         assert callable(importlib.import_module(f"benchmark.reducers.{spec['reducer']}").read)

@@ -112,3 +112,25 @@ def test_a_new_cell_is_files_and_an_entry_and_no_edit(manifest, tmp_path):
     assert "device_idle_pct" in {m["name"] for m in cell.per_layer}
     with pytest.raises(run.BenchError):
         run.load_cell("no.such.cell")
+
+
+def test_a_fixed_count_of_blocks_holds_the_invalid_mix_and_the_registry_cycle_whole(manifest):
+    """A window of `blocks_prepared` blocks with a bisecting block every
+    `invalid_every` holds whole periods of that mix, and an attestation
+    window whole cycles of its registry, so that no count weighs a bisecting
+    block or a fresh slot more than another."""
+    folder = os.path.join(ROOT, "benchmark", "workloads")
+    files = {c["name"]: c["file"] for c in manifest["configs"]}
+    checked = []
+    for name in sorted(os.listdir(folder)):
+        traffic = run.load_json(os.path.join(folder, name))
+        params = traffic.get("params", {})
+        if not params.get("invalid_every") or "blocks_prepared" not in params:
+            continue
+        assert params["blocks_prepared"] % params["invalid_every"] == 0, name
+        if traffic["driver"] == "atts_block":
+            config = run.load_json(os.path.join(ROOT, files[traffic["config"]]))
+            driver = importlib.import_module("benchmark.traffic.atts_block").Traffic(config, params, 0)
+            assert driver.slots == 32 and params["blocks_prepared"] % driver.slots == 0, name
+        checked.append(name)
+    assert {"block_atts_128.verify.json", "peerdas_block_21.verify.json"} <= set(checked)

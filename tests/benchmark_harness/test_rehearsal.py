@@ -9,10 +9,12 @@ import json
 import os
 import subprocess
 import sys
+import time
 
 import pytest
 
 from benchmark import faults, run
+from benchmark.compile_log import CompileLog
 
 from .tiny import ROOT, drive
 
@@ -138,3 +140,57 @@ def test_the_command_refuses_a_machine_without_a_tpu():
     )
     assert proc.returncode != 0 and proc.stdout.strip() == ""
     assert "no accelerator" in proc.stderr
+
+
+def test_the_seconds_the_profiler_takes_to_stop_are_not_in_the_window(monkeypatch):
+    """A trace that takes longer to write than the whole window: the traced
+    run still drives every request an untraced one would, and its window is
+    the requests' time alone."""
+    import jax
+
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda *a, **k: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: time.sleep(0.6))
+    monkeypatch.setattr(run.xplane, "read_xplane", lambda *a: run.xplane.Trace(*a))
+
+    window = run.Window(run.Cell("c", 1, {}, {}, [], []), "cpu", setup_seconds=1.0)
+    run.measure(None, SleepyTraffic(), window, 0.3, CompileLog(), trace_requests=1)
+    assert (window.attempted, window.completed, window.failed) == (5, 5, 0)
+    assert 0.1 <= window.seconds < 0.3
+
+
+class SleepyTraffic:
+    prepared = 5
+
+    def request(self, svc, i):
+        time.sleep(0.02)
+
+
+@pytest.mark.parametrize("device_seen_by", [1, 2, 3, None])
+def test_a_trace_that_missed_the_device_is_taken_again_over_the_next_requests(
+        monkeypatch, device_seen_by):
+    """The profiler now and then writes no device plane for a short request:
+    the next request is traced in its place, up to three sessions, each
+    trace over its own requests and seconds; the window keeps every request."""
+    import jax
+
+    sessions = []
+    monkeypatch.setattr(run, "traces_a_device", lambda: True)
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda *a, **k: sessions.append("start"))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: sessions.append("stop"))
+
+    def read_xplane(trace_dir, window_s, requests):
+        device = len(sessions) // 2 == device_seen_by
+        return run.xplane.Trace(ops=[[]] if device else [],
+                                modules=[[("jit_run", 0.0, 0.001)]] if device else [],
+                                window_s=window_s, requests=requests)
+
+    monkeypatch.setattr(run.xplane, "read_xplane", read_xplane)
+    window = run.Window(run.Cell("c", 1, {}, {}, [], []), "tpu", setup_seconds=1.0)
+    run.measure(None, SleepyTraffic(), window, 5.0, CompileLog(), trace_requests=1)
+    taken = min(device_seen_by or run.TRACE_ATTEMPTS, run.TRACE_ATTEMPTS)
+    assert sessions == ["start", "stop"] * taken
+    assert (window.attempted, window.completed, window.failed) == (5, 5, 0)
+    assert window.traced_from == taken - 1 and window.trace.requests == 1
+    assert 0.01 <= window.trace.window_s < 0.1
+    assert bool(window.trace.modules) == (device_seen_by is not None)
+    assert run.xplane.busy_seconds(window.trace) == (0.001 if device_seen_by else None)
